@@ -20,6 +20,10 @@ Two tiers (ISSUE: warm replicas in seconds, not compile-minutes):
 The store is disabled by default; set ``MXNET_COMP_CACHE_DIR`` (cap via
 ``MXNET_COMP_CACHE_CAP`` bytes) or call :func:`configure`. Snapshots are
 explicit artifacts and work regardless of the store.
+
+Beneath both sits jax's own persistent compilation cache, which the entry
+points (``chip_smoke.py``, ``bench.py``, ``serve.worker``) turn on through
+:func:`enable_compile_cache`.
 """
 from __future__ import annotations
 
@@ -29,7 +33,26 @@ from .aot import AotFn  # noqa: F401  (re-export)
 from .store import CompCacheStore, fingerprint  # noqa: F401
 
 __all__ = ["AotFn", "CompCacheStore", "configure", "active_store",
-           "enabled", "disable", "fingerprint", "stats", "traceable"]
+           "enabled", "disable", "enable_compile_cache", "fingerprint",
+           "stats", "traceable"]
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache():
+    """Point jax's persistent compilation cache at a place that stays put,
+    and return it. Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already
+    uses that directory and none is set in code; otherwise it is the fixed
+    ``<checkout>/.jax_cache``. The path is part of the cache's key, so it is
+    never a temporary name, a pid or a time."""
+    import jax
+
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        d = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", d)
+    return d
 
 _STORE = None
 _ENV_CHECKED = False

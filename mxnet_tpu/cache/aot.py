@@ -80,7 +80,7 @@ class AotFn:
 
     # ------------------------------------------------------------ dispatch
     def __call__(self, *args, **kwargs):
-        if not jax.core.trace_state_clean():
+        if not jax.core.trace_ctx.is_top_level():
             # under an outer trace (vjp/grad over a compiled block): a
             # Compiled can't be inlined, the jit wrapper can
             return self._jit(*args, **kwargs)

@@ -65,11 +65,9 @@ def _write_exec(prefix, key, compiled):
         _warn("executable %r could not be serialized on this backend — "
               "snapshot will recompile it on load" % key)
         return None
-    payload, in_tree, out_tree = packed
     fname = key.replace("@", "_") + ".mxc"
     path = os.path.join(_exec_dir(prefix), fname)
-    CompCacheStore.atomic_write(
-        path, pack_entry(key, payload, in_tree, out_tree))
+    CompCacheStore.atomic_write(path, pack_entry(key, *packed))
     return {"file": os.path.join(os.path.basename(_exec_dir(prefix)),
                                  fname),
             "bytes": os.path.getsize(path)}

@@ -46,7 +46,7 @@ import tempfile
 import threading
 import warnings
 
-SCHEMA = "mxc1"
+SCHEMA = "mxc2"
 ENTRY_MAGIC = "mxcexec1"
 ENTRY_SUFFIX = ".mxc"
 
@@ -81,11 +81,14 @@ def fingerprint():
                      "jaxlib=" + jaxlib.__version__, backend))
 
 
-def pack_entry(key, payload, in_tree, out_tree, fp=None):
+def pack_entry(key, payload, in_tree, out_tree, devices, fp=None):
     """Serialize one executable entry to bytes. ``key`` is the entry's
     logical identity (HLO digest for store entries, the manifest key for
     snapshot entries) — verified on read BEFORE the fingerprint so a
-    wrong-key file is reported as wrong-key, not as stale."""
+    wrong-key file is reported as wrong-key, not as stale. ``devices`` are
+    the ids of the devices the program was compiled for: the loader hands
+    the executable back to exactly those (jax would otherwise load it over
+    every local device)."""
     return pickle.dumps({
         "magic": ENTRY_MAGIC,
         "key": key,
@@ -93,13 +96,14 @@ def pack_entry(key, payload, in_tree, out_tree, fp=None):
         "payload": payload,
         "in_tree": in_tree,
         "out_tree": out_tree,
+        "devices": list(devices),
     }, protocol=pickle.HIGHEST_PROTOCOL)
 
 
 def unpack_entry(data, expect_key, origin="compilation cache"):
     """Validate + unpickle one entry; returns the dict or None (with ONE
-    warning) on any corruption, key mismatch, or version skew. The error
-    taxonomy feeds the store counters: 'corrupt' (unreadable), 'wrong_key',
+    warning) on any corruption, key mismatch, or version skew. The failure
+    kind feeds the store counters: 'corrupt' (unreadable), 'wrong_key',
     'stale' (fingerprint skew)."""
     try:
         blob = pickle.loads(data)
@@ -137,10 +141,13 @@ def load_compiled_entry(path, expect_key, origin="compilation cache"):
     if blob is None:
         return None, fail
     try:
+        import jax
         from jax.experimental import serialize_executable as se
 
+        by_id = {d.id: d for d in jax.local_devices()}
         compiled = se.deserialize_and_load(
-            blob["payload"], blob["in_tree"], blob["out_tree"])
+            blob["payload"], blob["in_tree"], blob["out_tree"],
+            execution_devices=[by_id[i] for i in blob["devices"]])
     except Exception as e:
         _warn("%s entry failed to deserialize (%s: %s) — recompiling"
               % (origin, type(e).__name__, e))
@@ -150,14 +157,17 @@ def load_compiled_entry(path, expect_key, origin="compilation cache"):
 
 
 def serialize_compiled(compiled):
-    """(payload, in_tree, out_tree) for a ``jax.stages.Compiled``, or None
-    when this backend's PJRT client does not support executable
-    serialization (the caller then falls back to jax's own persistent
-    compilation cache, which caches at the HLO level instead)."""
+    """(payload, in_tree, out_tree, device ids) for a
+    ``jax.stages.Compiled``, or None when this backend's PJRT client does
+    not support executable serialization (the caller then falls back to
+    jax's own persistent compilation cache, which caches at the HLO level
+    instead)."""
     try:
         from jax.experimental import serialize_executable as se
 
-        return se.serialize(compiled)
+        devices = [d.id for d in
+                   compiled.runtime_executable().local_devices()]
+        return se.serialize(compiled) + (devices,)
     except Exception:
         return None
 
@@ -252,11 +262,10 @@ class CompCacheStore:
             self._serialization_broken = True
             self._enable_xla_fallback()
             return False
-        payload, in_tree, out_tree = packed
         digest = self.digest(lowered.as_text())
         path = self.entry_path(tier, digest)
         try:
-            data = pack_entry(digest, payload, in_tree, out_tree)
+            data = pack_entry(digest, *packed)
             self.atomic_write(path, data)
         except Exception as e:
             _warn("compilation cache write failed (%s: %s) — continuing "
@@ -288,23 +297,15 @@ class CompCacheStore:
             raise
 
     def _enable_xla_fallback(self):
-        try:
-            import jax
+        from . import enable_compile_cache
 
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.join(self.directory, "xla"))
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.5)
-            _warn("executable serialization unsupported on this backend — "
-                  "falling back to jax's persistent compilation cache under "
-                  "%s/xla" % self.directory)
-        except Exception:
-            pass
+        _warn("executable serialization unsupported on this backend — "
+              "falling back to jax's persistent compilation cache under %s"
+              % enable_compile_cache())
 
     # ---------------------------------------------------------------- GC
     def _entries(self):
-        """[(path, mtime, size)] across all tiers (xla fallback dir is
-        jax's to manage — excluded)."""
+        """[(path, mtime, size)] across all tiers."""
         out = []
         for tier in TIERS:
             d = os.path.join(self.directory, tier)

@@ -101,7 +101,10 @@ class DistHandle:
             return
         import jax
 
-        home = jax.devices()[0]
+        from ..context import current_context
+
+        # the default context's device: where the eager forward runs
+        home = current_context().jax_device()
         for p in self.trainer._params:
             if p._data is None:
                 continue

@@ -39,7 +39,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..base import _jit_backed
-from ..parallel.mesh import get_shard_map
+from ..parallel.mesh import shard_map
 
 
 def _make_codec(compression):
@@ -199,15 +199,14 @@ class HierarchicalAllreduce:
         return body
 
     def _wrap(self, body, stacked, with_residual, n_outs=2):
-        sm = get_shard_map()
         in_vec = P((self.dcn_axis, self.ici_axis)
                    if self.dcn_axis else self.ici_axis, None) \
             if stacked else P()
         specs = [in_vec] + ([self._residual_spec()] if with_residual else [])
         r_spec = self._residual_spec()
         outs = tuple([P()] + [r_spec] * (n_outs - 1)) if n_outs > 1 else P()
-        return sm(body, mesh=self.mesh, in_specs=tuple(specs),
-                  out_specs=outs)
+        return shard_map(body, mesh=self.mesh, in_specs=tuple(specs),
+                         out_specs=outs)
 
     # ---------------------------------------------------- standalone API
     def reduce(self, vec, residual=None, stacked=False):
@@ -279,7 +278,6 @@ class HierarchicalAllreduce:
                     new_res = jnp.zeros((1, 1, 1), jnp.float32)
                 return payload[None, None], new_res
 
-            sm1 = get_shard_map()
             in_vec = P((self.dcn_axis, self.ici_axis)
                        if self.dcn_axis else self.ici_axis, None) \
                 if stacked else P()
@@ -287,8 +285,8 @@ class HierarchicalAllreduce:
             # BOTH outputs carry the per-device shard layout: the payload
             # is the sharded thing that crosses the wire
             prog1 = self._progs[key1] = _jit_backed(
-                sm1(stage1, mesh=self.mesh, in_specs=(in_vec, r_spec),
-                    out_specs=(r_spec, r_spec)),
+                shard_map(stage1, mesh=self.mesh, in_specs=(in_vec, r_spec),
+                          out_specs=(r_spec, r_spec)),
                 tier="jit", hint="dist_kv_stage1")
         key2 = ("kv2", n_pad, bool(stacked))
         prog2 = self._progs.get(key2)
@@ -303,10 +301,9 @@ class HierarchicalAllreduce:
                     out = out / self.world
                 return out
 
-            sm = get_shard_map()
             prog2 = self._progs[key2] = _jit_backed(
-                sm(stage2, mesh=self.mesh,
-                   in_specs=(self._residual_spec(),), out_specs=P()),
+                shard_map(stage2, mesh=self.mesh,
+                          in_specs=(self._residual_spec(),), out_specs=P()),
                 tier="jit", hint="dist_kv_stage2")
         if residual is None:
             residual = jnp.zeros(
@@ -376,9 +373,8 @@ class FlatAllreduce:
         return body
 
     def _wrap(self, body, stacked, with_residual, n_outs=2):
-        sm = get_shard_map()
         in_vec = P(self.axes if len(self.axes) > 1 else self.axes[0],
                    None) if stacked else P()
         outs = (P(), P()) if n_outs > 1 else P()
         specs = (in_vec, P()) if with_residual else (in_vec,)
-        return sm(body, mesh=self.mesh, in_specs=specs, out_specs=outs)
+        return shard_map(body, mesh=self.mesh, in_specs=specs, out_specs=outs)

@@ -25,6 +25,7 @@ from __future__ import annotations
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..context import current_context
 from .bucketer import default_bucket_mb, _nbytes
 
 
@@ -73,7 +74,10 @@ class Zero3ParamManager:
         self.mesh = mesh
         self.shard_axis = shard_axis
         self.nshard = int(mesh.shape[shard_axis])
-        self.home = jax.devices()[0]  # eager-forward residency target
+        # eager-forward residency target: the default context's device
+        # (this process's first chip; meant, also on four chips — the eager
+        # forward runs on one device, the mesh holds the shards)
+        self.home = current_context().jax_device()
         self.params = [p for p in params
                        if getattr(p, "_data", None) is not None]
         self.gathers = 0

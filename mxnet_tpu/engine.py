@@ -7,8 +7,8 @@ streams). Two host-side responsibilities remain:
 * the *host-side* pipeline — decode, augment, batching, file IO — on the
   native C++ dependency engine (src/engine_cc/dep_engine.cc) with
   per-variable RW dependency tracking, mirroring ThreadedEngine's
-  Push(fn, const_vars, mutable_vars) API, with a Python thread-pool fallback
-  when the .so isn't built;
+  Push(fn, const_vars, mutable_vars) API, built from source on first use,
+  with a Python thread-pool fallback where it cannot be built;
 * the *bulk window* — the TPU-native equivalent of ThreadedEngine's op
   bulking (MXNET_ENGINE_BULK_SIZE, ref: src/engine/threaded_engine.cc:
   BulkAppend). Imperative invocations of fusible ops defer into a lazy
@@ -242,39 +242,44 @@ def _lib_location():
     return d, os.path.join(d, "libmxtpu.so")
 
 
-_make_attempted = False
+_build_attempted = False
 
 
 def native_lib_path():
-    """Path to libmxtpu.so, building it with make on first use if possible.
-    The same make also produces libmxtpu_im.so (image pipeline), so rebuild
-    when either is missing — but attempt the build at most ONCE per process:
-    on hosts where a target can never build (no libjpeg), re-forking the
-    compiler for every ImageRecordIter would add seconds of latency each."""
-    global _make_attempted
+    """Path to libmxtpu.so. The libraries are build products, not committed
+    files: the first use in a process builds whatever is missing from the
+    .cc files beside the Makefile (the same make also produces
+    libmxtpu_im.so, the image pipeline). At most ONE attempt per process, and
+    one at a time per checkout (flock). A build that fails says so once, with
+    the compiler's last words, and the callers take their Python paths."""
+    global _build_attempted
     d, so = _lib_location()
-    missing = (not os.path.exists(so)
-               or not os.path.exists(os.path.join(d, "libmxtpu_im.so")))
-    if not missing:
-        # stale .so = ABI drift against the Python bindings; let make's own
-        # dependency rules decide (a no-op make is ~10ms)
-        try:
-            import glob
-            so_m = min(os.path.getmtime(so),
-                       os.path.getmtime(os.path.join(d, "libmxtpu_im.so")))
-            missing = any(os.path.getmtime(src) > so_m
-                          for src in glob.glob(os.path.join(d, "*.cc")))
-        except OSError:
-            missing = True
-    if missing and not _make_attempted and os.path.exists(
-            os.path.join(d, "Makefile")):
-        _make_attempted = True
-        import subprocess
+    targets = [so, os.path.join(d, "libmxtpu_im.so")]
+    if _build_attempted or all(os.path.exists(t) for t in targets):
+        return so
+    _build_attempted = True
+    import fcntl
+    import subprocess
+    import warnings
 
-        try:
-            subprocess.run(["make", "-C", d], capture_output=True, timeout=120)
-        except Exception:
-            pass
+    why = ""
+    try:
+        with open(os.path.join(d, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            # re-check under the lock: another process may have just built
+            if not all(os.path.exists(t) for t in targets):
+                r = subprocess.run(["make", "-k", "-C", d],
+                                   capture_output=True, text=True,
+                                   timeout=300)
+                why = r.stderr.strip()[-400:]
+    except (OSError, subprocess.TimeoutExpired) as e:  # no make, read-only
+        why = "%s: %s" % (type(e).__name__, e)
+    missing = [os.path.basename(t) for t in targets if not os.path.exists(t)]
+    if missing:
+        warnings.warn(
+            "native host helpers %s could not be built in %s — taking the "
+            "Python path for what they serve. Build output: %s"
+            % (", ".join(missing), d, why or "(none)"), RuntimeWarning)
     return so
 
 
@@ -303,6 +308,12 @@ def _native():
         except OSError:
             _lib = None
     return _lib
+
+
+def host_engine_kind():
+    """'native' when the C++ dependency engine loaded, 'python' when the
+    thread-pool stand-in serves (chip_smoke.py prints it)."""
+    return "native" if _native() else "python"
 
 
 _CALLBACK = ctypes.CFUNCTYPE(None, ctypes.c_void_p)

@@ -85,8 +85,8 @@ def _monitored_value(estimator, monitor, who):
     one-time warning when `monitor` names no train/val metric, because a
     typo must not silently disable best-tracking/early-stopping."""
     # default monitor prefers VALIDATION metrics: best-checkpoint /
-    # early-stop against a train metric would happily save an overfit model
-    # (ADVICE r3). A NaN (never-updated) metric is skipped, so before the
+    # early-stop against a train metric would happily save an overfit
+    # model. A NaN (never-updated) metric is skipped, so before the
     # first validation pass the train metric stands in — with a one-time
     # warning, since silently tracking train for a whole run is the exact
     # failure mode this ordering exists to prevent.

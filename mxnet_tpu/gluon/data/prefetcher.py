@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import jax
 
+from ...context import current_context
 from ...ndarray import NDArray
 
 __all__ = ["DevicePrefetcher"]
@@ -63,7 +64,10 @@ class DevicePrefetcher:
     def __init__(self, loader, ctx=None):
         self._loader = loader
         if ctx is None:
-            self._target = jax.devices()[0]
+            # where eager arrays live: the default context's device (this
+            # process's first chip), not jax.devices()[0], which under
+            # multi-controller jax is host 0's
+            self._target = current_context().jax_device()
         elif isinstance(ctx, jax.sharding.Sharding):
             self._target = ctx
         elif isinstance(ctx, (list, tuple)):

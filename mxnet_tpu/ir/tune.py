@@ -15,7 +15,7 @@ already trusts:
   measures only plausibly-winning configs (μ-cuDNN's decompose-to-fit
   parameters are workload-dependent, arXiv 1804.04806 — but most of a
   grid is dominated and never worth a stopwatch);
-* **paired-step timing** (PERF.md methodology): run-level A/B on a
+* **paired-step timing** (PERF.md §2): run-level A/B on a
   shared box swings ±50%, so the objective interleaves ONE step per arm
   and takes the median of per-pair deltas — contention hits both sides
   of every pair.

@@ -9,12 +9,13 @@ __version__ = "1.9.0.tpu"  # API-parity line: MXNet 1.9 surface, TPU backend
 
 
 def find_lib_path():
-    """Paths of the native helper libraries that exist on this host
-    (ref: libinfo.py:find_lib_path)."""
+    """Paths of the native helper libraries on this host, built from
+    source on first use (ref: libinfo.py:find_lib_path)."""
     import os
 
-    from .engine import _lib_location
+    from .engine import _lib_location, native_lib_path
 
+    native_lib_path()
     d, so = _lib_location()
     return [p for p in (so, os.path.join(d, "libmxtpu_im.so"))
             if os.path.exists(p)]

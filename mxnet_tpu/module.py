@@ -456,7 +456,7 @@ class Module:
         if not known:
             # pre-bind there is nothing to validate names against, so a
             # typo'd name cannot be caught and would become a dead dict
-            # entry — warn LOUDLY (ADVICE r4) while keeping the documented
+            # entry — warn LOUDLY while keeping the documented
             # pre-bind flow (values apply at bind time)
             import warnings
 

@@ -116,8 +116,6 @@ def _analyze(compiled):
         ca = compiled.cost_analysis()
     except Exception:
         ca = None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if isinstance(ca, dict):
         cols["flops"] = float(ca.get("flops", 0.0) or 0.0)
         cols["bytes_accessed"] = float(ca.get("bytes accessed", 0.0) or 0.0)
@@ -215,7 +213,7 @@ class _TrackedJit:
 
     def _note(self, args, kwargs):
         global _dropped, _errors
-        if not _enabled or not jax.core.trace_state_clean():
+        if not _enabled:
             return
         try:
             # lower() reads avals only — safe even when the call just

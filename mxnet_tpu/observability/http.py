@@ -72,9 +72,7 @@ class MetricsHTTPServer:
 
             def do_GET(self):  # noqa: N802 (stdlib API name)
                 # device=True: a live server's backend is already
-                # initialized, so the HBM gauges are a cached read — the
-                # downed-relay hang risk diagnose --no-device guards
-                # against doesn't apply here
+                # initialized, so the HBM gauges are a cached read
                 path, _, query = self.path.partition("?")
                 if path == "/metrics":
                     body = prometheus(device=True).encode("utf-8")

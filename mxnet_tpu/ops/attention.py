@@ -10,11 +10,13 @@ ref: gluonnlp attention_cell.py:DotProductAttentionCell).
 from __future__ import annotations
 
 import functools
+import threading
 
 import jax
 import jax.numpy as jnp
 
 from ..base import is_tpu_backend, register_op
+from .pallas import under_mesh
 
 _FLASH_MIN_LEN = 256  # static GUESS, used only until a hardware sweep lands
 
@@ -24,16 +26,10 @@ def _flash_min_len():
     exists (flash_blocks.json "min_len", written by flash_sweep --apply),
     else the static guess. The headline bert runs at seq 128 — whether it
     takes the flash kernel is hardware's call, not a constant's."""
-    try:
-        from .pallas import flash_attention as _fa
+    from .pallas import flash_attention as _fa
 
-        if _fa.MIN_LEN is not None:
-            return _fa.MIN_LEN
-    except Exception:  # pragma: no cover - pallas import unavailable
-        pass
-    return _FLASH_MIN_LEN
+    return _fa.MIN_LEN if _fa.MIN_LEN is not None else _FLASH_MIN_LEN
 
-import threading
 
 _SP_SCOPE = threading.local()
 
@@ -208,19 +204,14 @@ def scaled_dot_attention(q, k, v, mask=None, *, causal=False, scale=None,
                  scale=scale)
         return jax.device_put(out, orig if orig is not None
                               else mesh.devices.flat[0])
-    if (is_tpu_backend() and q.shape[2] >= _flash_min_len()
+    if (is_tpu_backend() and not under_mesh()
+            and q.shape[2] >= _flash_min_len()
             and (mask is None or prefix_mask)):
-        try:
-            from .pallas.flash_attention import flash_attention
+        from .pallas.flash_attention import flash_attention
 
-            vl = None if mask is None else _prefix_mask_to_valid_len(mask)
-            return flash_attention(q, k, v, causal=causal, scale=scale,
-                                   kv_valid_len=vl)
-        except Exception as e:  # pragma: no cover - depends on backend
-            import warnings
-
-            warnings.warn("flash attention unavailable, using dense "
-                          "reference: %s" % e)
+        vl = None if mask is None else _prefix_mask_to_valid_len(mask)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               kv_valid_len=vl)
     return _reference_attention(q, k, v, mask, causal=causal, scale=scale)
 
 

@@ -82,7 +82,7 @@ def gradientmultiplier(data, *, scalar=1.0):
     gradient scaled by `scalar` (the GRL trick at scalar < 0). custom_vjp,
     not the ``x*s + stop_gradient(x - x*s)`` algebra: upstream applies the
     scale only in backward, and the algebraic form drifts by a rounding ulp
-    (a + (b - a) != b in floating point, ADVICE r4)."""
+    (a + (b - a) != b in floating point)."""
     return _grad_multiply(data, jnp.asarray(scalar, data.dtype))
 
 

@@ -19,6 +19,9 @@ from jax import lax
 from jax.scipy import special as jsp
 
 from ..base import is_tpu_backend, register_op, resolve_dtype
+from .pallas import layernorm as _ln
+from .pallas import softmax_xent as _sx
+from .pallas import under_mesh
 
 # ---------------------------------------------------------------- unary
 
@@ -688,16 +691,14 @@ def LayerNorm(x, gamma, beta, *, axis=-1, eps=1e-5):
     recipe); last-axis LN at MXU-aligned widths takes the fused pallas kernel
     (ops/pallas/layernorm.py), one VMEM pass per row block."""
     last = axis in (-1, x.ndim - 1)
-    if (is_tpu_backend() and last and x.ndim >= 2
-            and x.shape[-1] % 128 == 0 and gamma.ndim == 1):
-        try:
-            from .pallas.layernorm import layernorm as _fused
-
-            lead = x.shape[:-1]
-            y = _fused(x.reshape(-1, x.shape[-1]), gamma, beta, eps)
-            return y.reshape(lead + (x.shape[-1],))
-        except Exception:
-            pass
+    # gate decided at trace time from static shapes, like softmax_xent_rows
+    # below: a kernel that fails raises (a Mosaic failure surfaces at compile
+    # time)
+    if (is_tpu_backend() and not under_mesh() and last and x.ndim >= 2
+            and gamma.ndim == 1 and _ln.tiles(math.prod(x.shape[:-1]), x.shape[-1])):
+        lead = x.shape[:-1]
+        y = _ln.layernorm(x.reshape(-1, x.shape[-1]), gamma, beta, eps)
+        return y.reshape(lead + (x.shape[-1],))
     # fp32 stats with ONE cast boundary back to x.dtype (same recipe as
     # BatchNorm above): `y.astype * gamma` would re-promote bf16 activations
     # to f32 through the affine and poison every downstream matmul
@@ -811,19 +812,17 @@ def softmax_xent_rows(logits, labels, *, axis=-1):
 
     Gate is deterministic at trace time (a try/except cannot catch Mosaic
     compile failures, which surface at jit-compile time): the fused kernel
-    runs on TPU for any V — it lane-aligns internally — while non-TPU
-    backends take the jnp path (interpret-mode kernel parity is pinned by
-    tests/test_kernels.py)."""
+    runs on TPU for any V — it lane-aligns internally — and any row count
+    that tiles, while non-TPU backends take the jnp path (interpret-mode
+    kernel parity is pinned by tests/test_kernels.py)."""
     axis = axis % logits.ndim
     if axis != logits.ndim - 1:
         logits = jnp.moveaxis(logits, axis, -1)
     rows_shape = logits.shape[:-1]
     flat = logits.reshape((-1, logits.shape[-1]))
     lab = labels.astype(jnp.int32).reshape((-1,))
-    if is_tpu_backend():
-        from .pallas.softmax_xent import softmax_xent as _fused
-
-        nll = _fused(flat, lab)
+    if is_tpu_backend() and not under_mesh() and _sx.tiles(*flat.shape):
+        nll = _sx.softmax_xent(flat, lab)
     else:
         # fp32 like the kernel (which does fp32 math and returns fp32
         # regardless of logits dtype) — backends must agree in precision

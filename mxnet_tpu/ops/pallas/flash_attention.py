@@ -23,10 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-if not hasattr(pltpu, "CompilerParams"):
-    # jax < 0.5 exposes the same dataclass as TPUCompilerParams
-    pltpu.CompilerParams = pltpu.TPUCompilerParams
-
 NEG_INF = -1e30
 LANES = 128
 
@@ -147,6 +143,7 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
                           emit_lse=return_lse, masked=masked),
+        name="flash_fwd",
         interpret=interpret,
         grid=grid,
         in_specs=in_specs,
@@ -288,6 +285,7 @@ def _flash_bwd(q, k, v, o, lse, do, kv_valid_len, scale, causal, bq, bk,
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal, bq=bq,
                           bk=bk, masked=masked),
+        name="flash_dq",
         interpret=interpret,
         grid=(B * H, Tq // bq, Tk // bk),
         in_specs=dq_in_specs,
@@ -311,6 +309,7 @@ def _flash_bwd(q, k, v, o, lse, do, kv_valid_len, scale, causal, bq, bk,
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal, bq=bq,
                           bk=bk, masked=masked),
+        name="flash_dkv",
         interpret=interpret,
         grid=(B * H, Tk // bk, Tq // bq),
         in_specs=dkv_in_specs,
@@ -447,9 +446,9 @@ def _load_block_artifact(path=None):
     leaves the fallback table untouched silently (tuning must never break
     import); a PRESENT-but-malformed file warns — a corrupted
     ``flash_sweep --apply`` output silently reverting every bench to the
-    untuned table is exactly the failure that must not be quiet (ADVICE
-    r4). An explicit ``path`` argument raises on any failure: the caller
-    asked for that file specifically."""
+    untuned table is exactly the failure that must not be quiet. An
+    explicit ``path`` argument raises on any failure: the caller asked for
+    that file specifically."""
     global BLOCK_DEFAULTS, MIN_LEN, _ARTIFACT_META, _INTERIM_WARNED
     explicit = path is not None
     path = path or _BLOCKS_ARTIFACT

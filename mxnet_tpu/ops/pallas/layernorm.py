@@ -57,15 +57,28 @@ def _ln_bwd(eps, interpret, res, dy):
 layernorm.defvjp(_ln_fwd, _ln_bwd)
 
 
-def fused_layernorm(x, gamma, beta, eps=1e-5, block_rows=256, interpret=False):
-    """x: (R, C); gamma/beta: (C,). C should be a multiple of 128."""
-    R, C = x.shape
+def _block_rows(R, block_rows=256):
     br = min(block_rows, R)
     while R % br:
         br //= 2
-    br = max(br, 1)
+    return max(br, 1)
+
+
+def tiles(R, C):
+    """Whether an (R, C) input maps onto blocks Mosaic accepts: lanes a
+    multiple of 128, and a row block that is a multiple of 8 or the whole
+    array. The gate in ops/functional.py asks at trace time."""
+    br = _block_rows(R)
+    return C % 128 == 0 and (br % 8 == 0 or br == R)
+
+
+def fused_layernorm(x, gamma, beta, eps=1e-5, block_rows=256, interpret=False):
+    """x: (R, C); gamma/beta: (C,). See :func:`tiles` for what compiles."""
+    R, C = x.shape
+    br = _block_rows(R, block_rows)
     return pl.pallas_call(
         functools.partial(_ln_kernel, eps=eps),
+        name="layernorm_fwd",
         interpret=interpret,
         grid=(R // br,),
         in_specs=[

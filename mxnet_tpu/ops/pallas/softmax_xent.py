@@ -51,6 +51,15 @@ def _block_rows(R, V, want=128, vmem_budget=2 << 20):
     return max(br, 1)
 
 
+def tiles(R, V):
+    """Whether (R, V) logits map onto blocks Mosaic accepts: a row block
+    that is a multiple of 8 or the whole array (V is lane-aligned by
+    :func:`_pad_lanes`). The gate in ops/functional.py asks at trace
+    time."""
+    br = _block_rows(R, V + (-V) % 128)
+    return br % 8 == 0 or br == R
+
+
 _PAD_NEG = -1e30  # finite: exp(_PAD_NEG - m) underflows to 0, no inf-inf NaN
 
 
@@ -74,6 +83,7 @@ def _run_fwd(logits, labels, interpret=False):
     lab2 = labels.astype(jnp.int32).reshape(R, 1)
     loss, lse = pl.pallas_call(
         _fwd_kernel,
+        name="softmax_xent_fwd",
         interpret=interpret,
         grid=(R // br,),
         in_specs=[pl.BlockSpec((br, V), lambda i: (i, 0)),
@@ -93,6 +103,7 @@ def _run_bwd(logits, labels, lse, dy, interpret=False):
     dy2 = dy.astype(jnp.float32).reshape(R, 1)
     return pl.pallas_call(
         _bwd_kernel,
+        name="softmax_xent_bwd",
         interpret=interpret,
         grid=(R // br,),
         in_specs=[pl.BlockSpec((br, V), lambda i: (i, 0)),

@@ -1,6 +1,6 @@
 """Distributed training over device meshes (see SURVEY.md §3.5)."""
 from .mesh import (make_mesh, named_sharding, replicated, use_mesh,  # noqa: F401
-                   current_mesh, shard_array, get_shard_map, P, AXES)
+                   current_mesh, shard_array, shard_map, P, AXES)
 from .data_parallel import (build_train_step, tree_optimizer_step,  # noqa: F401
                             replicate_params, shard_batch, block_loss_fn,
                             weight_update_spec)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from .mesh import make_mesh
+from .mesh import make_mesh, use_mesh
 
 
 def tree_optimizer_step(optimizer):
@@ -119,6 +119,15 @@ def build_train_step(loss_fn, optimizer, mesh=None, param_spec=None,
 
     if mesh is None:
         return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+
+    single_device_step = step
+
+    def step(params, states, t, key, batch):
+        # traced under the mesh: ops that consult it (tensor_parallel.
+        # constrain, the pallas gates — a Mosaic kernel cannot be
+        # partitioned) see what the program is compiled for
+        with use_mesh(mesh):
+            return single_device_step(params, states, t, key, batch)
 
     bspec = batch_spec if batch_spec is not None else P("dp")
     pspec = param_spec if param_spec is not None else P()

@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .mesh import get_shard_map
+from .mesh import shard_map
 
 
 def _moe_local(x, router_w, w1, w2, *, axis_name, capacity, mean_axes):
@@ -95,12 +95,11 @@ def moe_ffn(x, router_w, w1, w2, mesh, axis_name="ep", capacity_factor=2.0,
     token_spec = (P((batch_axis, axis_name), None) if batch_axis
                   else P(axis_name, None))
     mean_axes = (batch_axis, axis_name) if batch_axis else (axis_name,)
-    sm = get_shard_map()
-    f = sm(functools.partial(_moe_local, axis_name=axis_name,
-                             capacity=capacity, mean_axes=mean_axes),
-           mesh=mesh,
-           in_specs=(token_spec, P(), P(axis_name, None, None),
-                     P(axis_name, None, None)),
-           out_specs=(token_spec, P()))
+    f = shard_map(functools.partial(_moe_local, axis_name=axis_name,
+                                    capacity=capacity, mean_axes=mean_axes),
+                  mesh=mesh,
+                  in_specs=(token_spec, P(), P(axis_name, None, None),
+                            P(axis_name, None, None)),
+                  out_specs=(token_spec, P()))
     y, aux = f(x, router_w, w1, w2)
     return y, jnp.mean(aux)

@@ -76,20 +76,8 @@ def shard_array(x, mesh, *spec):
     return jax.device_put(x, NamedSharding(mesh, P(*spec)))
 
 
-def get_shard_map():
-    """shard_map across jax versions (kwarg name for the replication check
-    changed over releases; disable it either way — ring collectives violate
-    per-device replication invariants by design)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm  # noqa: F811
-
-    def wrapped(f, mesh, in_specs, out_specs):
-        for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-            try:
-                return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-        raise RuntimeError("no compatible shard_map signature")
-
-    return wrapped
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication check off: ring collectives
+    violate per-device replication invariants by design."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .mesh import get_shard_map
+from .mesh import shard_map
 
 
 def pipeline_apply(stage_fn, stage_params, microbatches, mesh, axis_name="pp"):
@@ -28,7 +28,6 @@ def pipeline_apply(stage_fn, stage_params, microbatches, mesh, axis_name="pp"):
     microbatches: (n_micro, mb, ...) replicated input.
     Returns (n_micro, mb, ...) outputs (replicated).
     """
-    sm = get_shard_map()
 
     def local(params, xs):
         # params leaves: (1, ...) local stage slice; xs: full (n_micro, ...)
@@ -67,7 +66,7 @@ def pipeline_apply(stage_fn, stage_params, microbatches, mesh, axis_name="pp"):
 
     pspec = jax.tree_util.tree_map(lambda _: P(axis_name), stage_params,
                                    is_leaf=lambda a: hasattr(a, "shape"))
-    f = sm(local, mesh, in_specs=(pspec, P()), out_specs=P())
+    f = shard_map(local, mesh, in_specs=(pspec, P()), out_specs=P())
     return f(stage_params, microbatches)
 
 
@@ -106,7 +105,6 @@ def pipeline_apply_interleaved(stage_fn, stage_params, microbatches, mesh,
     microbatches (n_micro, mb, ...) replicated; returns (n_micro, ...) after
     ALL S*v stages.
     """
-    sm = get_shard_map()
     v = int(n_virtual)
     S = int(mesh.shape[axis_name])
     G = S * v
@@ -168,7 +166,7 @@ def pipeline_apply_interleaved(stage_fn, stage_params, microbatches, mesh,
 
     pspec = jax.tree_util.tree_map(lambda _: P(axis_name), stage_params,
                                    is_leaf=lambda a: hasattr(a, "shape"))
-    f = sm(local, mesh, in_specs=(pspec, P()), out_specs=P())
+    f = shard_map(local, mesh, in_specs=(pspec, P()), out_specs=P())
     return f(stage_params, microbatches)
 
 
@@ -206,7 +204,6 @@ def pipeline_train_step_1f1b(stage_fn, loss_fn, stage_params, microbatches,
     axis; stage_fn then closes the tp math with its own lax.psum("tp"),
     exactly like a non-pipelined tp layer.
     """
-    sm = get_shard_map()
     n_micro = microbatches.shape[0]
 
     def local(params, xs, tgts):
@@ -304,6 +301,6 @@ def pipeline_train_step_1f1b(stage_fn, loss_fn, stage_params, microbatches,
         jax.tree_util.tree_map(lambda _: P(axis_name), stage_params,
                                is_leaf=lambda a: hasattr(a, "shape"))
     bspec = P(None, batch_axis) if batch_axis is not None else P()
-    f = sm(local, mesh, in_specs=(pspec, bspec, bspec),
-           out_specs=(P(), pspec))
+    f = shard_map(local, mesh, in_specs=(pspec, bspec, bspec),
+                  out_specs=(P(), pspec))
     return f(stage_params, microbatches, targets)

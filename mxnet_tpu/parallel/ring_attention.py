@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .mesh import get_shard_map
+from .mesh import shard_map
 
 
 def _ring_attn_local(q, k, v, axis_name, n, causal, scale):
@@ -85,12 +85,11 @@ def ring_attention(q, k, v, mesh, axis_name="sp", causal=False, scale=None,
     """
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    sm = get_shard_map()
     spec = P(batch_axis, None, axis_name, None)
     n = int(mesh.shape[axis_name])
-    f = sm(functools.partial(_ring_attn_local, axis_name=axis_name, n=n,
-                             causal=causal, scale=scale),
-           mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    f = shard_map(functools.partial(_ring_attn_local, axis_name=axis_name,
+                                    n=n, causal=causal, scale=scale),
+                  mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return f(q, k, v)
 
 

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .mesh import get_shard_map
+from .mesh import shard_map
 from .ring_attention import full_attention
 
 
@@ -84,9 +84,8 @@ def ulysses_attention(q, k, v, mesh, axis_name="sp", causal=False,
                 "the %r mesh axis (%d) — use ring_attention when the axis "
                 "does not divide the head count"
                 % (name, t.shape[1], name, axis_name, n))
-    sm = get_shard_map()
     spec = P(batch_axis, None, axis_name, None)
-    f = sm(functools.partial(_ulysses_local, axis_name=axis_name, n=n,
-                             causal=causal, scale=scale),
-           mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
+    f = shard_map(functools.partial(_ulysses_local, axis_name=axis_name,
+                                    n=n, causal=causal, scale=scale),
+                  mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)
     return f(q, k, v)

@@ -217,8 +217,8 @@ class BucketedExecutor:
                 outs = self._dispatch(prepped, replica)
         else:
             outs = self._dispatch(prepped, replica)
-        # host gather = the only completion signal the relay honors; also
-        # what the caller (a serving response) needs anyway
+        # host gather: what the caller (a serving response) needs, and
+        # what closes the dispatch span
         outs = [np.asarray(o) for o in outs]
         if traces:
             t_done = _time.perf_counter()

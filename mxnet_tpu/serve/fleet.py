@@ -128,7 +128,10 @@ class WorkerHandle:
         """Launch ``python -m mxnet_tpu.serve.worker`` and block until its
         READY line (JSON on stdout) reports the bound port. The child
         inherits the parent's environment (JAX_PLATFORMS et al.) plus
-        ``spec.env`` overrides."""
+        ``spec.env`` overrides. A worker is the process that takes the
+        chip — every chip its environment shows it: a router that has not
+        touched the accelerator can start ONE such worker per host unless
+        ``spec.env`` gives each a chip of its own."""
         env = dict(os.environ)
         env.update(spec.env)
         if debug is None:

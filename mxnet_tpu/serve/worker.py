@@ -260,6 +260,8 @@ def main(argv=None):
     args = p.parse_args(argv)
     if (args.snapshot is None) == (args.factory is None):
         p.error("exactly one of --snapshot / --factory")
+    from ..cache import enable_compile_cache
+    enable_compile_cache()
     if args.factory is not None:
         server = _resolve(args.factory)()
     else:

@@ -20,17 +20,13 @@ def set_default_context(ctx):
 
 
 def list_gpus():
-    """(ref: test_utils.py:list_gpus) — REAL accelerator ordinals (the cpu
-    fallback device does not count). mx.gpu() is the accelerator alias
-    here, so the standard upstream gate ``mx.gpu() if list_gpus() else
-    mx.cpu()`` keeps selecting the TPU on TPU hosts and cpu elsewhere."""
+    """(ref: test_utils.py:list_gpus) — TPU ordinals of this process.
+    mx.gpu() is the accelerator alias here, so the standard upstream gate
+    ``mx.gpu() if list_gpus() else mx.cpu()`` keeps selecting the TPU on TPU
+    hosts and cpu elsewhere."""
     from .context import _accel_devices
 
-    try:
-        devs = _accel_devices()
-    except RuntimeError:
-        return []
-    return [d.id for d in devs if d.platform != "cpu"]
+    return list(range(len(_accel_devices())))
 
 
 def _np(x):

@@ -292,8 +292,8 @@ def test_logging_epoch_only(capsys):
     assert "samples/s" not in out and "epoch 0 done" in out
 
 def test_default_monitor_prefers_validation_metric():
-    """monitor=None must track a VALIDATION metric when one has a value
-    (ADVICE r3): save-best/early-stop on a train metric rewards overfitting."""
+    """monitor=None must track a VALIDATION metric when one has a value:
+    save-best/early-stop on a train metric rewards overfitting."""
     from mxnet_tpu.gluon.contrib.estimator import _monitored_value
 
     est, _ = _estimator()

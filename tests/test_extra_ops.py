@@ -187,7 +187,7 @@ def test_im2col_col2im():
                                rtol=1e-4)
 
 def test_digamma_polygamma_scipy_oracle():
-    """(ref: special_functions-inl.h digamma/trigamma) — VERDICT r3 nub."""
+    """(ref: special_functions-inl.h digamma/trigamma)."""
     import scipy.special as ss
     from mxnet_tpu import nd
 
@@ -251,7 +251,7 @@ def test_contrib_long_tail_utility_ops():
     np.testing.assert_allclose(y.asnumpy(), [3.0])      # identity forward
     np.testing.assert_allclose(a.grad.asnumpy(), [-0.5])  # scaled backward
 
-    # BIT-exact identity (ADVICE r4): the x*s + stop_grad(x - x*s) algebra
+    # BIT-exact identity: the x*s + stop_grad(x - x*s) algebra
     # drifts an ulp at awkward value/scale pairs; custom_vjp must not
     v = np.float32(0.1)
     b = nd.array(np.array([v], np.float32))

@@ -566,7 +566,7 @@ def test_gnb_alpha_zero_is_poisson():
 
 
 def test_multi_sgd_family_matches_sequential_kernels():
-    """The multi_/preloaded_multi_ SGD family (VERDICT r4 op-nub sweep) is
+    """The multi_/preloaded_multi_ SGD family is
     numerically the per-tensor kernels applied per group, with host
     (multi_*) or device (preloaded_*) lr/wd vectors."""
     rng = np.random.default_rng(5)

@@ -338,7 +338,7 @@ def test_np_host_side_delegation():
 
 
 def test_dist_async_is_loud_na():
-    """dist_async must not silently alias to sync semantics (VERDICT r2)."""
+    """dist_async must not silently alias to sync semantics."""
     import pytest as _pytest
 
     import mxnet_tpu as mx

@@ -85,7 +85,7 @@ def test_bert_train_step_has_no_f32_matmuls():
         % f32_dots[:5])
 
 def test_loss_scaler_dynamic_fp16():
-    """Upstream loss_scaler.py semantics (VERDICT r3 #6): halve on overflow,
+    """Upstream loss_scaler.py semantics: halve on overflow,
     double after scale_window clean steps, clamp at min/max."""
     from mxnet_tpu.amp import LossScaler
 

@@ -426,7 +426,7 @@ def test_module_checkpoint_aux_split(tmp_path):
 
 def test_set_params_before_bind_warns():
     """Pre-bind there are no known names to validate against, so set_params
-    must warn loudly that typo'd names cannot be caught (ADVICE r4) while
+    must warn loudly that typo'd names cannot be caught while
     keeping the documented apply-at-bind flow."""
     import pytest
 
@@ -442,9 +442,9 @@ def test_set_params_before_bind_warns():
 
 
 def test_set_params_after_bind_takes_effect():
-    """set_params on a BOUND module must write through to the executor
-    (ADVICE r3): forward reads the bound arg NDArrays, so post-bind
-    set_params has to update values in place, not swap dict entries."""
+    """set_params on a BOUND module must write through to the executor:
+    forward reads the bound arg NDArrays, so post-bind set_params has to
+    update values in place, not swap dict entries."""
     data = sym.var("data")
     fw = sym.var("fc_weight")
     fb = sym.var("fc_bias")

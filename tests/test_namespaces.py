@@ -365,7 +365,7 @@ def test_metric_nll_and_check_label_shapes():
     with pytest.raises(ValueError, match="does not match"):
         mx.metric.check_label_shapes(nd.zeros((2,)), nd.zeros((3,)),
                                      shape=True)
-    # upstream semantics (ADVICE r4): bare-array batch mismatch raises via
+    # upstream semantics: bare-array batch mismatch raises via
     # len() even without shape=True, and the pair is ALWAYS returned —
     # unwrapped when wrap=False
     with pytest.raises(ValueError, match="does not match"):

@@ -1,4 +1,4 @@
-"""ONNX validation against EXTERNAL artifacts (VERDICT r2 item 4):
+"""ONNX validation against EXTERNAL artifacts:
 
 1. a .onnx file produced by torch's TorchScript exporter (C++ graph builder
    + protobuf serializer — a genuinely third-party producer), imported and
@@ -96,7 +96,7 @@ def test_loop_import_respects_initial_condition(tmp_path):
 def test_checker_passes_own_exports_and_torch_file(tmp_path):
     """P.check_model structural validation over (a) the torch-produced
     fixture and (b) this repo's own exports — the spec-conformance gate
-    VERDICT r2 asked for (onnx.checker itself is not in the image)."""
+    the review asked for (onnx.checker itself is not in the image)."""
     import mxnet_tpu as mx
     from mxnet_tpu import gluon
 

@@ -52,8 +52,7 @@ def test_quantized_conv_grouped_strided():
 
 
 def test_quantize_zoo_model_end_to_end():
-    """Model-level: int8-quantize a real zoo net and keep top-1 agreement
-    (VERDICT r1 weak #8 — quantization depth beyond single layers)."""
+    """Model-level: int8-quantize a real zoo net and keep top-1 agreement."""
     from mxnet_tpu.gluon.model_zoo.vision import get_resnet
 
     net = get_resnet(1, 18, classes=10, thumbnail=True)

@@ -1,4 +1,4 @@
-"""tools/roofline.py — the no-hardware roofline report (VERDICT r4 #4)."""
+"""tools/roofline.py — the no-hardware roofline report."""
 import json
 import os
 import subprocess

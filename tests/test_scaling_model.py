@@ -1,4 +1,4 @@
-"""Scaling-efficiency model (VERDICT r3 next-round #5): analytic ICI curve
+"""Scaling-efficiency model: analytic ICI curve
 asserts the BASELINE.md 0.90 row; the HLO collective parser is unit-tested;
 the committed artifact must exist and be self-consistent with the model."""
 import json
@@ -64,7 +64,7 @@ def test_committed_artifact_consistent():
         art = json.load(f)
     base = art["baseline_row"]
     assert base["model_prediction_overlap0.9"] >= 0.90
-    # r5 hardening (VERDICT r4 #7): the model must state its worst case and
+    # r5 hardening: the model must state its worst case and
     # where it CAN fail, not only validate
     assert "met_under_worst_case" in base
     assert "structural_note" in base

@@ -1,5 +1,4 @@
-"""Key+shape manifests lock the converter oracles to reality (VERDICT r4
-next #5).
+"""Key+shape manifests lock the converter oracles to reality.
 
 The offline torchvision reimplementations (tools/torch_*_ref.py) claim
 byte-identical state_dict keys to torchvision; the committed manifests under

@@ -1,0 +1,163 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e at their
+real widths — no chip attached, nothing runs. Interpret mode
+(tests/test_kernels.py) proves the math; this proves that Mosaic accepts the
+blocks, the tiling and the fast-memory use, which interpret mode cannot see.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU's library, and every xdist worker imports
+every test file. All of these tests stay in this one file for the same
+reason, and compile in the test's own process.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    had_log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    cc.reset_cache()
+    if had_log_dir is None:
+        os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text()
+
+
+def _sum32(x):
+    return jnp.sum(x.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("rows,dtype", [(8192, jnp.bfloat16),
+                                        (8, jnp.bfloat16),
+                                        (600, jnp.float32)])
+def test_layernorm_768_compiles_fwd_bwd(one_chip, rows, dtype):
+    """LayerNorm at width 768: the BERT/GPT activations (8192 rows), the
+    8-slot decode step, and a 600-token eager prompt (row block 8)."""
+    from mxnet_tpu.ops.pallas import layernorm as ln
+
+    assert ln.tiles(rows, 768)
+    x = jax.ShapeDtypeStruct((rows, 768), dtype, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((768,), dtype, sharding=one_chip)
+    text = _compile(jax.value_and_grad(
+        lambda x, g, b: _sum32(ln.layernorm(x, g, b, 1e-5)),
+        argnums=(0, 1, 2)), x, g, g)
+    assert "tpu_custom_call" in text and "layernorm_fwd" in text
+
+
+def test_gates_refuse_rows_that_do_not_tile():
+    """Row counts with no block that is a multiple of 8 take the jnp branch
+    (601 rows of LayerNorm: Mosaic refuses a 1-row block)."""
+    from mxnet_tpu.ops.pallas import layernorm as ln
+    from mxnet_tpu.ops.pallas import softmax_xent as sx
+
+    assert not ln.tiles(601, 768) and not ln.tiles(8192, 100)
+    assert ln.tiles(1, 768) and ln.tiles(256, 768)
+    assert sx.tiles(1280, 30522) and sx.tiles(8, 2)
+    assert not sx.tiles(1281, 30522)
+
+
+@pytest.mark.parametrize("rows,vocab,dtype", [(1280, 30522, jnp.bfloat16),
+                                              (8192, 50257, jnp.float32)])
+def test_softmax_xent_compiles_fwd_bwd(one_chip, rows, vocab, dtype):
+    """The MLM loss of the BERT step (64 x 20 rows, vocab 30522) and an LM
+    loss at GPT-2's vocab."""
+    from mxnet_tpu.ops.pallas import softmax_xent as sx
+
+    assert sx.tiles(rows, vocab)
+    logits = jax.ShapeDtypeStruct((rows, vocab), dtype, sharding=one_chip)
+    labels = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    text = _compile(lambda l, y: jax.value_and_grad(
+        lambda l: jnp.sum(sx.softmax_xent(l, y)))(l), logits, labels)
+    assert "softmax_xent_fwd" in text and "softmax_xent_bwd" in text
+
+
+@pytest.mark.parametrize("seq,dim,dtype", [(1024, 64, jnp.bfloat16),
+                                           (2048, 128, jnp.float32)])
+def test_flash_causal_compiles_fwd_bwd(one_chip, seq, dim, dtype):
+    """Causal flash attention at GPT-2's head width (the server's T=1024
+    prefill) and at head width 128, forward and both backward kernels."""
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((8, 12, seq, dim), dtype, sharding=one_chip)
+    text = _compile(jax.value_and_grad(
+        lambda q, k, v: _sum32(flash_attention(q, k, v, causal=True)),
+        argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert all(n in text for n in ("flash_fwd", "flash_dq", "flash_dkv"))
+
+
+def test_flash_valid_len_compiles_fwd_bwd(one_chip):
+    """The key-padding path (BERT-style valid lengths) at (8, 12, 1024, 64)."""
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+
+    qkv = jax.ShapeDtypeStruct((8, 12, 1024, 64), jnp.bfloat16,
+                               sharding=one_chip)
+    vl = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    text = _compile(lambda q, k, v, vl: jax.value_and_grad(
+        lambda q, k, v: _sum32(flash_attention(q, k, v, kv_valid_len=vl)),
+        argnums=(0, 1, 2))(q, k, v), qkv, qkv, qkv, vl)
+    assert all(n in text for n in ("flash_fwd", "flash_dq", "flash_dkv"))
+
+
+def test_train_step_over_a_mesh_compiles_without_mosaic(topo, monkeypatch):
+    """A Mosaic kernel cannot be partitioned by the SPMD partitioner. With
+    the TPU branch of the gates forced open, ``build_train_step`` over a
+    four-chip ``dp`` mesh must still compile: traced under the mesh, the ops
+    take their XLA formulations and the partitioner adds the all-reduce."""
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    import chip_smoke
+    from mxnet_tpu.models.bert import BERTModel
+    from mxnet_tpu.ops import functional as OF
+
+    monkeypatch.setattr(OF, "is_tpu_backend", lambda: True)
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("dp",))
+
+    def tiny():
+        return BERTModel(vocab_size=512, units=128, hidden_size=256,
+                         num_layers=1, num_heads=2, max_length=32)
+
+    _net, _plist, step, params, states = chip_smoke.build_bert_step(
+        tiny, seed=0, mesh=mesh)
+    batch = chip_smoke.make_bert_batch(0, 512, 8, 32, 4)
+
+    def on(tree, spec):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, spec)), tree)
+
+    text = step.lower(
+        on(params, P()), on(states, P()), on(jnp.int32(1), P()),
+        on(jax.random.PRNGKey(0), P()), on(batch, P("dp"))
+    ).compile().as_text()
+    assert "tpu_custom_call" not in text and "all-reduce" in text

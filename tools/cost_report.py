@@ -10,7 +10,7 @@ table, the per-server/trainer HBM ledger, and a step-time decomposition
 (compute vs dispatch-gap vs comm-overlap) from the existing tracing
 spans — replacing the old hand-run join of ``roofline.py --save-hlo``
 with ``profile_hlo_map.py`` for the "which op is the sink" question
-(PERF.md "named sinks").
+(PERF.md §3).
 
 ``--quick`` runs the four PINNED programs (the same builders the
 counter baseline replays): the 160-tensor fused optimizer step, the

@@ -29,10 +29,19 @@ Wall-clock columns are host-dependent context; the COUNTER columns
 (failed, sheds after scale-out, mixed outputs, warm compiles, migrated
 hits) are deterministic and gated by tests/test_counter_baseline.py.
 
-Run: python tools/fleet_bench.py [--quick] [--json PATH]
---quick pins the CPU backend and keeps waves small (the CI mode; wired as
-``python bench.py fleet --smoke`` and committed to
+Run: python tools/fleet_bench.py --quick [--json PATH]
+--quick puts the workers on the CPU backend and keeps waves small (the CI
+mode; wired as ``python bench.py fleet --cpu`` and committed to
 tools/fleet_bench_quick.json).
+
+One process for each chip: the router process (this one) pins ITSELF to the
+CPU backend — its reference outputs are computed there — by its own config,
+which the workers do not inherit; the workers are the processes that take
+chips. A worker takes every chip its host shows it and nothing assigns it one
+yet (ROADMAP D7), so an accelerator host serves ONE worker however many chips
+it has, and every scenario here needs two at once: without ``--quick`` the
+bench refuses, and says so, rather than let the second worker die before
+READY.
 """
 import argparse
 import json
@@ -334,9 +343,23 @@ def main(argv=None):
     ap.add_argument("--requests", type=int, default=None)
     ap.add_argument("--json", default=None, help="write artifact here")
     args = ap.parse_args(argv)
-    if args.quick:
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    n = args.requests or (60 if args.quick else 200)
+    if not args.quick:
+        raise SystemExit(
+            "fleet_bench needs two workers at once, and its workers would "
+            "land on the accelerator: a chip belongs to one process at a "
+            "time, a worker takes every chip its host shows it, and nothing "
+            "gives each worker a chip of its own yet (WorkerSpec.env; "
+            "ROADMAP D7) — so on this host a fleet is one worker and the "
+            "second would die before READY. Run it on the CPU backend: "
+            "`python bench.py fleet --cpu` (tools/fleet_bench.py --quick).")
+    # the workers inherit the environment: the CPU backend for them
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # the router stays off the accelerator by its OWN choice, whatever the
+    # environment says: it only routes and computes reference outputs
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+    n = args.requests or 60
     rows = []
     t0 = time.perf_counter()
     rows.append(run_kill9(requests=n))
@@ -356,8 +379,8 @@ def main(argv=None):
     rows.append(run_affinity())
     print("session_affinity: migrated=%(migrated_entries)d "
           "hit_after=%(hit_on_migrated_prefix)d" % rows[-1])
-    out = {"config": {"quick": bool(args.quick),
-                      "platform": os.environ.get("JAX_PLATFORMS", "default"),
+    out = {"config": {"quick": True,
+                      "platform": os.environ["JAX_PLATFORMS"],
                       "timing": "end-to-end over real subprocess workers; "
                                 "counter columns are the gate, wall-clock "
                                 "is context (1-core CI box)",
@@ -365,13 +388,11 @@ def main(argv=None):
                                                    time.gmtime()),
                       "wall_s": round(time.perf_counter() - t0, 1)},
            "rows": rows}
-    path = args.json or (os.path.join(TOOLS, "fleet_bench_quick.json")
-                         if args.quick else None)
-    if path:
-        with open(path, "w") as fh:
-            json.dump(out, fh, indent=1)
-            fh.write("\n")
-        print("wrote", path)
+    path = args.json or os.path.join(TOOLS, "fleet_bench_quick.json")
+    with open(path, "w") as fh:
+        json.dump(out, fh, indent=1)
+        fh.write("\n")
+    print("wrote", path)
     return 0
 
 

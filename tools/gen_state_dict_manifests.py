@@ -1,5 +1,4 @@
-"""Generate state_dict key+shape manifests locking the converter oracles
-(VERDICT r4 next #5).
+"""Generate state_dict key+shape manifests locking the converter oracles.
 
 Two sources:
 - the offline torchvision reimplementations (tools/torch_*_ref.py): their

@@ -2,9 +2,8 @@
 """Generate THIRD-PARTY ONNX fixtures with torch's TorchScript exporter.
 
 The exporter's graph construction and protobuf serialization are torch C++
-code — a genuinely external producer for validating our importer (VERDICT r2
-item 4). The only part skipped is `_add_onnxscript_fn`, an optional
-post-processing step that needs the `onnx` pip package (not in this image)
+code — a genuinely external producer for validating our importer. The
+only part skipped is `_add_onnxscript_fn`, an optional post-processing step that needs the `onnx` pip package (not in this image)
 and is a no-op for models without onnxscript custom functions.
 
 Writes tests/fixtures/torch_cnn.onnx (+ .npz with the exact input and

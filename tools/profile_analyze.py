@@ -5,11 +5,11 @@ Parses the Chrome-trace json (``*.trace.json.gz`` under
 
 - total busy time vs wall span (device utilization of the captured window)
 - the top-K ops by cumulative self duration (the concrete "attack this
-  sink next" list the MFU hunt needs — VERDICT r4 next #2's profile step)
+  sink next" list the MFU hunt needs)
 - collective ops split out (all-reduce / all-gather / ...): on a multi-chip
   run their busy time vs the lane's compute busy time bounds the dp
   all-reduce OVERLAP the scaling model assumes (tools/scaling_model.py) —
-  the measured-overlap input VERDICT r4 next #7 asks for once multi-chip
+  the measured-overlap input the scaling model asks for once multi-chip
   hardware exists.
 
 Usage: python tools/profile_analyze.py /tmp/profile_r5/bert [--top 15]

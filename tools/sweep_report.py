@@ -1,12 +1,12 @@
 #!/usr/bin/env python
 """Summarize the hardware sweep artifacts into tuning recommendations.
 
-Reads the newest round's sweep artifacts (tools/flash_sweep_r*.json for
+Reads the newest sweep artifacts (tools/flash_sweep_r*.json for
 flash-attention block sizes, tools/batch_sweep_r*.jsonl for bench
---batch/--remat configs) once the tpu_bench_loop has produced them, and
-prints:
+--batch/--remat configs) and prints:
   - best (block_q, block_k) per sequence length vs the current defaults
-  - samples/s and MFU per bench config vs the persisted default-config runs
+  - samples/s and MFU per bench config vs the 2026-08-01 chip record
+    (BENCH_RESULTS.json — older than PRs 1-18; bench.py no longer writes it)
 Run: python tools/sweep_report.py  (host-only; no TPU access needed)
 """
 import json
@@ -27,8 +27,8 @@ def flash_report(path):
     print("== flash sweep (%s, measured %s) ==" %
           (data["config"].get("platform"), data["config"].get("measured_at")))
     if data["config"].get("timing") != "slope-chained-v2":
-        print("   WARNING: artifact predates the relay-safe slope timer "
-              "(r5) — these timings are dispatch-dominated noise; rerun "
+        print("   WARNING: artifact predates the slope timer — these "
+              "timings are dispatch-dominated noise; rerun "
               "tools/flash_sweep.py")
     for seq in sorted({r["seq"] for r in rows}):
         dense = [r for r in rows if r["seq"] == seq and r["kernel"] == "dense"]
@@ -93,7 +93,7 @@ def main():
     try:
         results = json.load(open(os.path.join(HERE, "..",
                                               "BENCH_RESULTS.json")))
-        print("== persisted default-config results ==")
+        print("== 2026-08-01 chip record (BENCH_RESULTS.json) ==")
         for mode, r in sorted(results.items()):
             print("%-10s %10.2f %s  vs_baseline=%.2f  mfu=%s  (%s)"
                   % (mode, r["value"], r["unit"], r["vs_baseline"],

@@ -13,6 +13,16 @@ carrying the full id) — so one Perfetto timeline shows request lifecycle,
 host scopes (``bulk[...]``/``serve[...]``/``decode[...]``), and XLA
 kernels together.
 
+The request spans say what one request waited for; the scheduler's own
+``decode[...]`` spans (``profiler.decode_scope``, which lists the kinds)
+say what the loop thread was doing, and the two meet in
+``decode[join<tp> ...]``: one per admitted request, whose ``args`` carry
+this trace's ``trace_id`` (in the Chrome record and as a stat of the
+``TraceAnnotation`` in the device trace), the prompt's length and whether
+the prompt was prefilled or injected from the prefix store. Its children
+``decode[prefill<tp>]`` and ``decode[readout<tp>]`` split the ``dispatch``
+span of the request below into the forward and the page read-out.
+
 Cost discipline: a trace is a uuid + a handful of (name, t0, t1) tuples
 per REQUEST (never per token — decode steps accumulate into one float).
 ``set_tracing(False)`` (or ``MXNET_REQUEST_TRACING=0``) makes

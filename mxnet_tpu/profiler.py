@@ -273,21 +273,52 @@ def serve_scope(bucket, n_real):
 
 
 @contextlib.contextmanager
-def decode_scope(kind, slots, n_active):
-    """Instruments one generative-decode dispatch (called from
-    serve.decoder when the profiler runs): ``decode[step fill=0.75 b8]``
-    for a fused token step of the whole in-flight batch, or
-    ``decode[prefill16 fill=...]`` for a whole-prompt cache fill at a
-    prompt-length bucket — batch-fill efficiency of the continuous-batching
-    scheduler reads directly off the trace next to the XLA kernels."""
+def decode_scope(kind, slots, n_active, args=None):
+    """Instruments one stretch of the generative-decode scheduler's tick
+    (called from serve.decoder when the profiler runs), named
+    ``decode[<kind> fill=<active/slots> b<slots>]`` — batch-fill efficiency
+    of the continuous-batching scheduler reads directly off the trace next
+    to the XLA kernels, and between them the kinds cover the loop thread's
+    time end to end, so every idle gap of the device lies under the span
+    of what the host was doing:
+
+    ``tick``         one scheduler tick that had something to do: the
+                     parent of all the kinds below but ``idle``. Its self
+                     time is the glue between them (the admission look, the
+                     slot mask, the parameter list of the next dispatch)
+    ``join<tp>``     the whole admission of one request at prompt bucket
+                     ``tp`` (``args``: ``trace_id``, ``prompt_len``,
+                     ``kind`` = prefill | inject); parent of the next two.
+                     Its self time is key making, pad, prefix lookup, the
+                     first-token read and bookkeeping.
+    ``prefill<tp>``  the prefill (or prefix-inject) dispatch
+    ``readout<tp>``  the page read-out into the prefix store: the extract
+                     dispatch and the device-to-host copies (``mb``)
+    ``chunk<tc>``    one chunked-prefill dispatch
+    ``ctl``          upload of the per-slot sampling controls after a
+                     join or retire
+    ``step``         one fused token step of the whole in-flight batch, from
+                     the dispatch to the host's read of the tokens
+                     (``verify<k>``: the speculative round likewise)
+    ``deliver``      bookkeeping after the read: the walk over the active
+                     slots handing tokens to streams, retiring requests
+    ``idle``         one span for a whole stretch in which no tick
+                     progressed anything
+
+    ``args`` goes to the TraceAnnotation (stats of the event in the
+    ``.xplane.pb``) and to the Chrome record; the dict yielded is the
+    record's ``args``, to which the caller may add what it learns inside."""
     name = "decode[%s fill=%.2f b%d]" % (kind, n_active / max(slots, 1),
                                          slots)
+    rec_args = {"slots": slots, "active": n_active}
+    if args:
+        rec_args.update(args)
     t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(name):
-        yield
+    with jax.profiler.TraceAnnotation(name, **(args or {})):
+        yield rec_args
     t1 = time.perf_counter()
     _record(name, (t0 - _epoch) * 1e6, (t1 - t0) * 1e3, cat="serve",
-            args={"slots": slots, "active": n_active})
+            args=rec_args)
 
 
 def backward_scope(op_names):

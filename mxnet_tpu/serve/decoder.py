@@ -34,8 +34,10 @@ iterators (``GenerationStream``).
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import queue
+import re
 import threading
 import time
 from collections import deque
@@ -52,6 +54,7 @@ from .kv_cache import CacheError, PagedKVCache, PrefixCache
 from .metrics import GenerativeMetrics
 
 _DONE = object()
+_NO_SPAN = contextlib.nullcontext()   # what a tick enters with the profiler off
 
 
 def sample_tokens(logits, keys, positions, temps, top_k):
@@ -353,6 +356,7 @@ class GenerativeServer:
             max_queue=max_queue, num_dispatchers=1, metrics=self.metrics)
         self._loop_thread = None
         self._stop_flag = False
+        self._idle = None   # the open decode[idle] span (profiler running)
         # opt-in /metrics scrape endpoint (observability.http); None = off
         self._metrics_port = metrics_port
         self.metrics_http = None
@@ -547,17 +551,47 @@ class GenerativeServer:
         Returns the number of slots progressed (0 = idle). The background
         loop calls this continuously; tests call it directly for
         counter-exact assertions."""
-        self._admit_pending()
-        chunked = self._chunk_once()
-        return self._decode_once() + chunked
+        # the one read of the profiler's switch a tick makes: while it runs
+        # the loop's time lies under decode[...] spans end to end
+        # (profiler.decode_scope lists the kinds), off it enters none
+        tracing = profiler.is_running()
+        # a tick with something to do is one decode[tick], the parent of
+        # all it does; the others are gathered into decode[idle]
+        busy = tracing and bool(self._join_q or self.cache.num_active)
+        if self._idle is not None and (busy or not tracing):
+            self._end_idle()
+        with self._span(busy, "tick", self.cache.num_active):
+            self._admit_pending(tracing)
+            chunked = self._chunk_once(tracing)
+            n = self._decode_once(tracing) + chunked
+        if n:
+            self._end_idle()   # work that arrived after the look above
+        elif tracing and self._idle is None:
+            # ONE span from the first empty tick to the next tick that
+            # works: one a sleep would be 1,000 records a second
+            self._idle = profiler.decode_scope("idle", self.slots, 0)
+            self._idle.__enter__()
+        return n
+
+    def _end_idle(self):
+        idle, self._idle = self._idle, None
+        if idle is not None:
+            idle.__exit__(None, None, None)
+
+    def _span(self, tracing, kind, n_active, args=None):
+        return (profiler.decode_scope(kind, self.slots, n_active, args)
+                if tracing else _NO_SPAN)
 
     def _loop(self):
-        while not self._stop_flag:
-            if self.step() == 0:
-                time.sleep(0.001)
+        try:
+            while not self._stop_flag:
+                if self.step() == 0:
+                    time.sleep(0.001)
+        finally:
+            self._end_idle()   # on the thread that opened it
 
     # ------------------------------------------------------------- joining
-    def _admit_pending(self):
+    def _admit_pending(self, tracing=False):
         while self.cache._free:
             with self._join_cond:
                 req = self._join_q.popleft() if self._join_q else None
@@ -576,15 +610,31 @@ class GenerativeServer:
                     self.metrics.record_timeout()
                 continue
             try:
-                self._join(req, stream)
+                self._join(req, stream, tracing)
             except Exception as e:   # cache exhaustion, model error
                 self.metrics.record_error()
                 if req.finish(error=e):
                     stream._finish(e)
 
-    def _join(self, req, stream):
-        tr = stream.trace
+    def _join(self, req, stream, tracing=False):
+        """Admit one request into a slot, under its ``decode[join<tp>]``
+        span while the profiler runs: how long the join holds every
+        stream."""
         t_join = time.perf_counter()
+        t0_len = int(stream.prompt.size)
+        # the prompt's bucket (the capacity ensured below is never under it)
+        tp = min(next_pow2(t0_len), self.cache.max_capacity)
+        args = None
+        if tracing:
+            args = {"prompt_len": t0_len}
+            if stream.trace is not None:
+                args["trace_id"] = stream.trace.trace_id
+        with self._span(tracing, "join%d" % tp, self.cache.num_active,
+                        args) as span:
+            self._join_slot(req, stream, t_join, tp, tracing, span)
+
+    def _join_slot(self, req, stream, t_join, tp, tracing, span):
+        tr = stream.trace
         if tr is not None:
             # queue phase for a generation request spans admission →
             # slot assignment (batcher queue + join handover)
@@ -608,9 +658,10 @@ class GenerativeServer:
                 "req": req, "stream": stream, "pos": 0, "key": key,
                 "t_join": t_join}
             self._ctl_dirty = True
+            if span is not None:
+                span["kind"] = "chunked"
             return
         slot = self.cache.acquire(stream)
-        tp = min(next_pow2(t0_len), self.cache.capacity)
         padded = np.zeros((1, tp), np.int32)
         padded[0, :t0_len] = stream.prompt
         hit = self.prefix.get(stream.prompt) if self.prefix is not None \
@@ -620,10 +671,12 @@ class GenerativeServer:
             # host-side prompt pad-to-bucket (the decode analogue of the
             # pool's pad span)
             tr.add_span("pad", t_join, t_disp0, bucket=tp)
+        if span is not None:
+            span["kind"] = "inject" if hit is not None else "prefill"
         engine.dispatch_counter.bump()
         scope = (profiler.decode_scope("prefill%d" % tp, self.slots,
                                        self.cache.num_active)
-                 if profiler.is_running() else None)
+                 if tracing else None)
         try:
             if scope is not None:
                 scope.__enter__()
@@ -673,17 +726,28 @@ class GenerativeServer:
             self.metrics.record_prefill()
             if self.prefix is not None:
                 # one page read-out per UNIQUE prompt; repeats skip the
-                # whole forward from then on
+                # whole forward from then on. The store keeps host copies:
+                # put() waits for the prefill and copies the page off the
+                # device on this, the loop's own, thread
                 engine.dispatch_counter.bump()
-                if self._quantize:
-                    ks, vs = self._extract_fn(tp, self.cache.capacity)(
-                        self.cache.k, self.cache.k_scale, self.cache.v,
-                        self.cache.v_scale, jnp.int32(slot))
-                else:
-                    ks, vs = self._extract_fn(tp, self.cache.capacity)(
-                        self.cache.k, self.cache.v, jnp.int32(slot))
-                self.prefix.put(stream.prompt, ks, vs, t0_len,
-                                np.asarray(last))
+                c = self.cache
+                copied = None
+                if tracing:
+                    # the K and V page as the store keeps them (a quantized
+                    # cache reads out in float32)
+                    copied = {"mb": round(
+                        2e-6 * c.layers * c.heads * tp * c.head_dim
+                        * (4 if self._quantize else c.dtype.itemsize), 3)}
+                with self._span(tracing, "readout%d" % tp, c.num_active,
+                                copied):
+                    if self._quantize:
+                        ks, vs = self._extract_fn(tp, c.capacity)(
+                            c.k, c.k_scale, c.v, c.v_scale, jnp.int32(slot))
+                    else:
+                        ks, vs = self._extract_fn(tp, c.capacity)(
+                            c.k, c.v, jnp.int32(slot))
+                    self.prefix.put(stream.prompt, ks, vs, t0_len,
+                                    np.asarray(last))
         first = int(np.asarray(self._tok)[slot])
         now = time.perf_counter()
         if tr is not None:
@@ -710,7 +774,7 @@ class GenerativeServer:
         self._deliver(slot, first)
 
     # ------------------------------------------------------------- decoding
-    def _decode_once(self):
+    def _decode_once(self, tracing=False):
         # slots mid-chunked-prefill are owned (admission can't reuse them)
         # but not decodable yet — masked out until their final chunk
         active = self.cache.active_mask(exclude=self._chunk_jobs)
@@ -718,12 +782,13 @@ class GenerativeServer:
         if n_active == 0:
             return 0
         if self._ctl_dirty:
-            self._dev_keys = jnp.asarray(self._keys)
-            self._dev_temps = jnp.asarray(self._temps)
-            self._dev_active = jnp.asarray(active)
+            with self._span(tracing, "ctl", n_active):
+                self._dev_keys = jnp.asarray(self._keys)
+                self._dev_temps = jnp.asarray(self._temps)
+                self._dev_active = jnp.asarray(active)
             self._ctl_dirty = False
         if self._draft is not None:
-            return self._speculate_once(active, n_active)
+            return self._speculate_once(active, n_active, tracing)
         fn = self._decode_fn(self.cache.capacity)
         params = self._params()
         if self._quantize:
@@ -736,28 +801,27 @@ class GenerativeServer:
                     self._dev_temps)
         engine.dispatch_counter.bump()
         t0 = time.perf_counter()
-        if profiler.is_running():
-            with profiler.decode_scope("step", self.slots, n_active):
-                out = fn(*args)
-        else:
+        # the step as the scheduler sees it: dispatch to the tokens' arrival
+        with self._span(tracing, "step", n_active):
             out = fn(*args)
-        kss = vss = None
-        if self._quantize:
-            kcs, kss, vcs, vss, valid, nxt = out
-        else:
-            kcs, vcs, valid, nxt = out
-        nxt_host = np.asarray(nxt)   # ONE host gather per step — the tokens
-        self.cache.update(kcs, vcs, valid, kss, vss)
-        self._tok = nxt
-        dt = time.perf_counter() - t0
-        self.metrics.record_step(dt, n_active, n_active, self.slots,
-                                 under_prefill=bool(self._chunk_jobs))
-        now = time.perf_counter()
-        for slot in np.nonzero(active)[0]:
-            self._deliver(int(slot), int(nxt_host[slot]), now, step_s=dt)
+            kss = vss = None
+            if self._quantize:
+                kcs, kss, vcs, vss, valid, nxt = out
+            else:
+                kcs, vcs, valid, nxt = out
+            nxt_host = np.asarray(nxt)   # ONE host gather per step: tokens
+        with self._span(tracing, "deliver", n_active):
+            self.cache.update(kcs, vcs, valid, kss, vss)
+            self._tok = nxt
+            dt = time.perf_counter() - t0
+            self.metrics.record_step(dt, n_active, n_active, self.slots,
+                                     under_prefill=bool(self._chunk_jobs))
+            now = time.perf_counter()
+            for slot in np.nonzero(active)[0]:
+                self._deliver(int(slot), int(nxt_host[slot]), now, step_s=dt)
         return n_active
 
-    def _speculate_once(self, active, n_active):
+    def _speculate_once(self, active, n_active, tracing=False):
         """One speculation round: draft proposes spec_k-1 tokens per slot
         (0 or 1 dispatch), the target scores the whole window in ONE wide
         verify dispatch, and each live slot receives its accepted prefix
@@ -794,38 +858,36 @@ class GenerativeServer:
         engine.dispatch_counter.bump()
         engine.verify_dispatch_counter.bump()
         t0 = time.perf_counter()
-        if profiler.is_running():
-            with profiler.decode_scope("verify%d" % k, self.slots, n_active):
-                out = fn(*args)
-        else:
+        with self._span(tracing, "verify%d" % k, n_active):
             out = fn(*args)
-        kss = vss = None
-        if self._quantize:
-            kcs, kss, vcs, vss, valid, nxt, emit, n_emit = out
-        else:
-            kcs, vcs, valid, nxt, emit, n_emit = out
-        # ONE batched host gather for both outputs (two np.asarray calls
-        # would sync the device twice per round)
-        emit_h, n_emit_h = jax.device_get((emit, n_emit))
-        self.cache.update(kcs, vcs, valid, kss, vss)
-        self._tok = nxt
-        dt = time.perf_counter() - t0
-        emitted = int(n_emit_h.sum())
-        self.metrics.record_step(dt, emitted, n_active, self.slots,
-                                 under_prefill=bool(self._chunk_jobs))
-        self.metrics.record_spec_round(n_active * (k - 1),
-                                       emitted - n_active)
-        now = time.perf_counter()
-        for slot in np.nonzero(active)[0]:
-            slot = int(slot)
-            stream = self.cache.owner(slot)
-            for tok in emit_h[slot, :n_emit_h[slot]]:
-                if self.cache.owner(slot) is not stream:
-                    break   # retired mid-window (EOS / budget / deadline)
-                self._deliver(slot, int(tok), now, step_s=dt)
+            kss = vss = None
+            if self._quantize:
+                kcs, kss, vcs, vss, valid, nxt, emit, n_emit = out
+            else:
+                kcs, vcs, valid, nxt, emit, n_emit = out
+            # ONE batched host gather for both outputs (two np.asarray
+            # calls would sync the device twice per round)
+            emit_h, n_emit_h = jax.device_get((emit, n_emit))
+        with self._span(tracing, "deliver", n_active):
+            self.cache.update(kcs, vcs, valid, kss, vss)
+            self._tok = nxt
+            dt = time.perf_counter() - t0
+            emitted = int(n_emit_h.sum())
+            self.metrics.record_step(dt, emitted, n_active, self.slots,
+                                     under_prefill=bool(self._chunk_jobs))
+            self.metrics.record_spec_round(n_active * (k - 1),
+                                           emitted - n_active)
+            now = time.perf_counter()
+            for slot in np.nonzero(active)[0]:
+                slot = int(slot)
+                stream = self.cache.owner(slot)
+                for tok in emit_h[slot, :n_emit_h[slot]]:
+                    if self.cache.owner(slot) is not stream:
+                        break   # retired mid-window (EOS/budget/deadline)
+                    self._deliver(slot, int(tok), now, step_s=dt)
         return n_active
 
-    def _chunk_once(self):
+    def _chunk_once(self, tracing=False):
         """Run AT MOST one prefill chunk (FIFO across jobs): extract the
         slot's page, run ``prefill_chunk`` prompt positions through the
         wide-window step at offset ``pos``, write the page back — one
@@ -860,7 +922,7 @@ class GenerativeServer:
         engine.dispatch_counter.bump()
         scope = (profiler.decode_scope("chunk%d" % tc, self.slots,
                                        self.cache.num_active)
-                 if profiler.is_running() else None)
+                 if tracing else None)
         try:
             if scope is not None:
                 scope.__enter__()
@@ -971,10 +1033,15 @@ class GenerativeServer:
         Tier B snapshots plus the Tier A disk store underneath."""
         from ..cache import AotFn
 
+        hint = hint or "decode"
+        # one name per kind of program where all were ``jit_pure``: the
+        # device trace's "XLA Modules" line reads jit_pure_step_c1024,
+        # jit_pure_prefill_t256c1024, ... and a reader finds each by name
+        fn.__name__ = "pure_" + re.sub(r"\W", "_", hint)
         return AotFn(fn,
                      donate_argnums=donate if (self._donate and donate)
                      else (),
-                     tier="decode", hint=hint or "decode",
+                     tier="decode", hint=hint,
                      single_signature=True)
 
     def _decode_fn(self, capacity):

@@ -75,8 +75,9 @@ class _CausalSelfAttention(HybridBlock):
         """Decode against the fixed-capacity cache: ``x`` (B, T, C) holds
         the next T tokens (T=1 in steady-state decode), whose K/V are
         written IN PLACE at time offset ``start`` via ``F.cache_write``
-        (lax.dynamic_update_slice underneath); attention masks to the live
-        prefix ``pos <= start + row``. ``start`` is a python int (uniform
+        (its docstring says what each kind of ``start`` lowers to);
+        attention masks to the live prefix ``pos <= start + row``.
+        ``start`` is a python int (uniform
         imperative decode) or a (B,) per-slot position vector (continuous
         batching). Cache shapes never change across steps — the whole point.
         Returns (out (B, T, C), k_cache', v_cache')."""

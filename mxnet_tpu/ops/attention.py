@@ -239,16 +239,31 @@ def cache_write(cache, update, index):
 
     ``index`` is a scalar (whole-batch write at one offset: prefill, the
     uniform imperative decode loop) or a per-row ``(B,)`` vector (continuous
-    batching: each slot is at its own position). Lowers to
-    ``lax.dynamic_update_slice`` — with the cache buffer donated, XLA
-    updates it in place. Writes past the capacity are the caller's bug;
-    like dynamic_update_slice, the start index clamps to ``C - T``."""
+    batching: each slot is at its own position). With the cache buffer
+    donated, every path updates it in place. What each lowers to:
+
+    - scalar ``index``: one ``lax.dynamic_update_slice``;
+    - per-row ``index``, one token a row, on a TPU, where the shapes tile
+      (``kv_write.tiles``: ``C % 128 == 0``, head width under 128) and no
+      device mesh is being traced: the Pallas kernel ``kv_cache_write``,
+      one pass over the 128-position blocks that hold the rows' positions;
+    - per-row ``index`` otherwise: ``vmap(dynamic_update_slice)``, which
+      is a ``scatter``, which XLA on the TPU expands into a serial
+      ``while`` loop of B column updates.
+
+    All three give the same bits. Writes past the capacity are the caller's
+    bug; like dynamic_update_slice, the start index clamps to ``C - T``."""
     index = jnp.asarray(index, jnp.int32)
     update = update.astype(cache.dtype)
     zero = jnp.int32(0)
     if index.ndim == 0:
         return jax.lax.dynamic_update_slice(cache, update,
                                             (zero, zero, index, zero))
+    if is_tpu_backend() and not under_mesh():
+        from .pallas import kv_write
+
+        if kv_write.tiles(cache.shape, update.shape, cache.dtype):
+            return kv_write.kv_cache_write(cache, update, index)
     return jax.vmap(
         lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (zero, i, zero))
     )(cache, update, index)

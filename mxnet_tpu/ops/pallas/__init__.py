@@ -1,5 +1,5 @@
 """Pallas TPU kernels for the hot ops (flash attention, fused layernorm,
-fused softmax cross-entropy).
+fused softmax cross-entropy, the decode step's K/V write).
 
 The ops that own a kernel gate into it at trace time, from what they can
 see then: the default backend is a TPU, the static shapes tile, and the

@@ -6,9 +6,15 @@ instead of a per-request cache tensor whose time axis grows every token —
 a new aval per step, so every compiled consumer retraces (graphlint GL007)
 — all in-flight requests share per-layer ``(slots, heads, capacity,
 head_dim)`` buffers. Each request owns one SLOT page; its tokens are
-written in place at its own ``valid_len`` position via
-``lax.dynamic_update_slice`` (the ``cache_write`` op) and attention masks
-to the live prefix, so **no shape ever changes across decode steps**.
+written in place at its own ``valid_len`` position by the ``cache_write``
+op and attention masks to the live prefix, so **no shape ever changes
+across decode steps**. What that write lowers to depends on the call
+(``ops/attention.py: cache_write``): a prefill or an inject writes a whole
+window at a scalar offset, one ``lax.dynamic_update_slice``; the decode
+step writes one token per slot at per-slot positions, on a TPU the Pallas
+kernel ``kv_cache_write`` (one pass over the 128-position blocks the
+positions fall in), elsewhere ``vmap(dynamic_update_slice)``, a
+``scatter`` that XLA runs as a serial loop over the slots.
 
 Capacity is bucketed in powers of two: when an admitted request needs more
 room than the current bucket, the buffers are zero-padded up to the next

@@ -4,6 +4,7 @@ import itertools
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from mxnet_tpu import gluon, nd
 from mxnet_tpu.ops.pallas.flash_attention import _flash_fwd
@@ -382,3 +383,158 @@ def test_flash_attention_bf16_fwd_and_grads_match_oracle():
                                         - b.astype(jnp.float32)))
                         / (float(jnp.max(jnp.abs(b))) + 1e-9))
             assert rel < 0.08, (nm, causal, kv is not None, rel)
+
+
+# ------------------------------------------------ kv_cache_write (PR 29)
+def _vmap_dus(cache, update, index):
+    """Today's per-slot write, the kernel's reference: bit for bit."""
+    zero = jnp.int32(0)
+    return jax.vmap(
+        lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (zero, i, zero))
+    )(cache, update, jnp.asarray(index, jnp.int32))
+
+
+# the first slot's position; the other three slots stay at 5, C - 2 and 64
+_KV_POSITIONS = {
+    "first": lambda C: 0,
+    "last_lane_of_a_block": lambda C: 127,
+    "first_lane_of_the_next_block": lambda C: 128,   # == C at C = 128
+    "last": lambda C: C - 1,
+    "at_capacity_clamps": lambda C: C,
+    "far_past_capacity_clamps": lambda C: 3 * C + 7,
+    "negative_counts_from_the_end": lambda C: -3,
+    "far_negative_clamps_to_zero": lambda C: -2 * C,
+}
+
+
+@pytest.mark.parametrize("where", list(_KV_POSITIONS) + [
+    "all_slots_equal", "more_slots_than_lanes"])
+@pytest.mark.parametrize("C", [128, 1024])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_kv_cache_write_interpret_matches_dynamic_update_slice(dtype, C,
+                                                               where):
+    """The Pallas K/V column write in interpret mode against
+    ``vmap(dynamic_update_slice)``: the same bits at block edges, with the
+    start clamped as ``dynamic_update_slice`` clamps it, with several slots
+    at one position, and every other lane and slot untouched."""
+    from mxnet_tpu.ops.pallas import kv_write
+
+    S, H = (130, 1) if where == "more_slots_than_lanes" else (4, 3)
+    D = 16 if dtype == jnp.bfloat16 else 8      # one sublane tile
+    ks = jax.random.split(jax.random.PRNGKey(C), 2)
+    cache = jax.random.normal(ks[0], (S, H, C, D), dtype)
+    update = jax.random.normal(ks[1], (S, H, 1, D), dtype)
+    if where == "all_slots_equal":
+        index = [77] * S
+    elif where == "more_slots_than_lanes":
+        index = [(37 * i) % C for i in range(S)]
+    else:
+        index = [_KV_POSITIONS[where](C), 5, C - 2, 64]
+    assert kv_write.tiles(cache.shape, update.shape, dtype)
+    got = kv_write.kv_cache_write(cache, update, jnp.asarray(index, jnp.int32),
+                                  interpret=True)
+    want = _vmap_dus(cache, update, index)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    as_bits = lambda a: np.asarray(a.astype(jnp.float32)).view(np.uint32)
+    np.testing.assert_array_equal(as_bits(got), as_bits(want))
+    # and stated on its own: one column a slot changed, nothing else did
+    landed = np.clip([i + C if i < 0 else i for i in index], 0, C - 1)
+    kept = np.ones((S, H, C, D), bool)
+    kept[np.arange(S), :, landed, :] = False
+    np.testing.assert_array_equal(as_bits(got)[kept], as_bits(cache)[kept])
+    np.testing.assert_array_equal(
+        as_bits(got)[np.arange(S), :, landed, :], as_bits(update)[:, :, 0, :])
+
+
+def _kv_gate_case(case):
+    """(cache shape, update shape, index) of one way past the kernel."""
+    S, H = 4, 2
+    C, D, T = {"window_of_4": (256, 16, 4), "capacity_64": (64, 16, 1),
+               "head_dim_128": (256, 128, 1),
+               "head_dim_off_the_sublane_tile": (256, 12, 1),
+               }.get(case, (256, 16, 1))
+    index = (jnp.int32(9) if case == "scalar_index"
+             else jnp.arange(S, dtype=jnp.int32) * 15)
+    return (S, H, C, D), (S, H, T, D), index
+
+
+@pytest.mark.parametrize("case", [
+    "scalar_index", "window_of_4", "capacity_64", "head_dim_128",
+    "head_dim_off_the_sublane_tile", "under_a_mesh", "on_the_cpu",
+    "tpu_and_tiles"])
+def test_cache_write_gate(monkeypatch, case):
+    """``cache_write`` decides at trace time, from what it can see: only a
+    per-slot index with one token a slot, shapes that tile, a TPU and no
+    device mesh reach the kernel; every other call keeps today's path."""
+    from mxnet_tpu import parallel
+    from mxnet_tpu.ops import attention as A
+    from mxnet_tpu.ops.pallas import kv_write
+
+    calls = []
+
+    def kernel(cache, update, index):
+        calls.append(cache.shape)
+        if case != "tpu_and_tiles":
+            raise AssertionError("%s reached the kernel" % case)
+        return kv_write_orig(cache, update, index, interpret=True)
+
+    kv_write_orig = kv_write.kv_cache_write
+    monkeypatch.setattr(kv_write, "kv_cache_write", kernel)
+    if case != "on_the_cpu":
+        monkeypatch.setattr(A, "is_tpu_backend", lambda: True)
+    cshape, ushape, index = _kv_gate_case(case)
+    ks = jax.random.split(jax.random.PRNGKey(3), 2)
+    cache = jax.random.normal(ks[0], cshape, jnp.float32)
+    update = jax.random.normal(ks[1], ushape, jnp.float32)
+    if case == "under_a_mesh":
+        with parallel.use_mesh(parallel.make_mesh({"dp": -1})):
+            got = A.cache_write(cache, update, index)
+    else:
+        got = A.cache_write(cache, update, index)
+    if index.ndim == 0:
+        want = jax.lax.dynamic_update_slice(cache, update, (0, 0, index, 0))
+    else:
+        want = _vmap_dus(cache, update, index)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert len(calls) == (1 if case == "tpu_and_tiles" else 0)
+
+
+def test_server_tokens_same_with_the_kv_write_kernel(monkeypatch):
+    """``GenerativeServer``'s greedy tokens with the kernel forced on (the
+    TPU gate open, interpret mode standing in for the chip) are the tokens
+    it serves without it: gpt_nano's widths over a 128-token capacity."""
+    import mxnet_tpu as mx
+    from mxnet_tpu.models.gpt import GPTModel
+    from mxnet_tpu.ops import attention as A
+    from mxnet_tpu.ops.pallas import kv_write
+
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(0, 256, (n,)).astype(np.int32) for n in (5, 11, 3)]
+
+    def serve():
+        mx.random.seed(11)
+        model = GPTModel(vocab_size=256, units=64, num_layers=2, num_heads=2,
+                         max_length=128, dropout=0.0)
+        model.initialize()
+        with mx.serve.GenerativeServer(model, slots=3,
+                                       timeout_ms=120000.0) as srv:
+            streams = [srv.submit(p, max_new_tokens=70 + 3 * i)
+                       for i, p in enumerate(prompts)]
+            tokens = [s.result(120) for s in streams]
+            assert srv.cache.capacity == 128
+        return tokens
+
+    plain = serve()
+    calls = []
+    orig = kv_write.kv_cache_write
+
+    def forced(cache, update, index):
+        calls.append(cache.shape)
+        return orig(cache, update, index, interpret=True)
+
+    monkeypatch.setattr(A, "is_tpu_backend", lambda: True)
+    monkeypatch.setattr(kv_write, "kv_cache_write", forced)
+    with_kernel = serve()
+    # K and V of two layers, in every decode program traced
+    assert calls and len(calls) % 4 == 0 and set(calls) == {(3, 2, 128, 32)}
+    assert with_kernel == plain

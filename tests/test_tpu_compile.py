@@ -127,6 +127,82 @@ def test_flash_valid_len_compiles_fwd_bwd(one_chip):
     assert all(n in text for n in ("flash_fwd", "flash_dq", "flash_dkv"))
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 12, 1024, 64), jnp.bfloat16),     # GPT-2 small, the smoke's server
+    ((32, 25, 1024, 64), jnp.float32),     # GPT-2 XL's heads, fp32 pages
+    ((160, 4, 128, 16), jnp.bfloat16)])    # more slots than lanes
+def test_kv_cache_write_compiles_in_place(one_chip, shape, dtype):
+    """The K/V column write alone: Mosaic takes the blocks and the lane
+    rotate (of 16-bit values too), and the donated buffer is the result."""
+    from mxnet_tpu.ops.pallas import kv_write
+
+    S, H, C, D = shape
+    assert kv_write.tiles(shape, (S, H, 1, D), dtype)
+    compiled = jax.jit(kv_write.kv_cache_write, donate_argnums=(0,)).lower(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((S, H, 1, D), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip)).compile()
+    assert "kv_cache_write" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == S * H * C * D * jnp.dtype(dtype).itemsize
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+def test_decode_step_writes_kv_in_place_with_the_kernel(one_chip,
+                                                        monkeypatch):
+    """A decode step in small (two layers: QKV projection, ``cache_write``
+    of K and V at per-slot positions, masked attention) over the serving
+    cell's buffers, ``bf16[32,20,1024,64]``, donated. What interpret mode
+    cannot see: the kernel's ``(S,H,D,C)`` view of a buffer is a bitcast of
+    the layout the device holds it in, so no cache-shaped array is copied,
+    transposed or carried through a ``while`` (the scatter's serial loop),
+    every buffer is updated where it lies, and nothing cache-sized is
+    allocated beside them."""
+    import re
+
+    from mxnet_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "is_tpu_backend", lambda: True)
+    S, H, C, D, layers = 32, 20, 1024, 64, 2
+    U = H * D
+
+    def step(x, ws, caches, pos):
+        live = (jnp.arange(C, dtype=jnp.int32).reshape(1, 1, 1, C)
+                <= pos.reshape(-1, 1, 1, 1))
+        out = []
+        for w, (kc, vc) in zip(ws, caches):
+            qkv = jnp.dot(x, w).reshape(S, 1, 3, H, D)
+            q, k, v = (jnp.transpose(qkv[:, :, i], (0, 2, 1, 3))
+                       for i in range(3))
+            kc = A.cache_write(kc, k, pos)
+            vc = A.cache_write(vc, v, pos)
+            o = A.scaled_dot_attention(q, kc, vc, live)
+            x = x + jnp.transpose(o, (0, 2, 1, 3)).reshape(S, U)
+            out.append((kc, vc))
+        return x, out
+
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = on((S, H, C, D), jnp.bfloat16)
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        on((S, U), jnp.bfloat16), [on((U, 3 * U), jnp.bfloat16)] * layers,
+        [(cache, cache)] * layers, on((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r" custom-call\([^\n]*kv_cache_write", text)) \
+        == 2 * layers
+    assert not re.search(r" while\(", text)
+    # async copy-start/-done pairs are the compiler's own prefetch of a
+    # buffer into fast memory; the parent's program has them too
+    made = re.findall(
+        r"= bf16\[32,20,(?:1024,64|64,1024)\]\S* ([\w-]+)\(", text)
+    assert made and set(made) <= {"parameter", "bitcast", "custom-call",
+                                  "copy-done"}, sorted(set(made))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * layers * S * H * C * D * 2
+    assert mem.temp_size_in_bytes < 8 << 20
+
+
 def test_train_step_over_a_mesh_compiles_without_mosaic(topo, monkeypatch):
     """A Mosaic kernel cannot be partitioned by the SPMD partitioner. With
     the TPU branch of the gates forced open, ``build_train_step`` over a

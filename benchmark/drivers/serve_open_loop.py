@@ -209,6 +209,14 @@ class Run:
             note("%d token gaps in the window, ms at p%s: %s" % (
                 len(itl), "/".join(map(str, qs)), " ".join(
                     "%.2f" % v for v in np.percentile(itl, qs))))
+        if ttft:
+            # a queue that grows shows as first tokens that come later in
+            # the window's second half (read when a rate is chosen)
+            half = len(ttft) // 2
+            note("first tokens, ms from due: p50 %.1f p90 %.1f p95 %.1f; "
+                 "p50 of the earlier half %.1f, of the later %.1f" % (
+                     *np.percentile(ttft, (50, 90, 95)),
+                     np.median(ttft[:half] or ttft), np.median(ttft[half:])))
         record = {
             "attempted": attempted, "failed": failed,
             "scalars": {"setup_s": t_open - self.t0,

@@ -1,8 +1,8 @@
 """Driver of a training window: the fused whole-step program.
 
 The entry is ``parallel.build_train_step(loss_fn, opt)`` on the
-configuration's model, built the way ``chip_smoke.build_bert_step`` builds it
-(bf16 parameters through ``amp.convert_hybrid_block``, fp32 masters in the
+configuration's model, built the way ``chip_smoke.py`` builds its training
+step (bf16 parameters through ``amp.convert_hybrid_block``, fp32 masters in the
 optimizer state, MLM through ``softmax_xent_rows`` + NSP). Set-up builds that
 one object, drives its first four steps through the window's own feed (a pool
 of distinct seeded host batches, one ``device_put`` a step), and hands the

@@ -11,7 +11,6 @@ The traced slice is what lies between the two marker spans the drivers write
 (``MARK_START``, ``MARK_END``); all times are seconds on the trace's clock,
 relative to the start marker.
 """
-import bisect
 import glob
 import os
 import re
@@ -94,6 +93,17 @@ class Trace:
         return (sum(sum(x) for x in per) / len(per),
                 sum(len(x) for x in per) // len(per))
 
+    def op_time_within(self, pattern, runs):
+        """Seconds (a device's mean) of the device ops whose name matches
+        ``pattern`` and that start between the first of ``runs`` starting
+        and the last of them ending (``runs`` as :meth:`module_runs` gives
+        them)."""
+        lo, hi = min(s for s, _d in runs), max(s + d for s, d in runs)
+        rx = re.compile(pattern)
+        per = [sum(d for n, s, d in evs if rx.search(n) and lo <= s < hi)
+               for evs in self.ops.values()]
+        return sum(per) / len(per)
+
     def module_runs(self, pattern):
         """(start_s, duration_s) of every whole run inside the slice of the
         programs whose name matches ``pattern``, on the first device."""
@@ -101,34 +111,6 @@ class Trace:
         first = self.modules[min(self.modules)] if self.modules else []
         return [(s, d) for n, s, d in first
                 if rx.search(n) and s >= 0.0 and s + d <= self.window_s]
-
-    def runs_launched_by(self, span_pattern, module_pattern):
-        """Every whole run, on the first device, of the program that the
-        host spans matching ``span_pattern`` launched. The serving programs
-        all carry one name, ``jit_pure``, and differ only in the fingerprint
-        behind it; the host's ``decode[...]`` spans tell a decode step from
-        a prefill. The device's clock is laid onto the host's to within a
-        millisecond or so, too loosely to pair a span with the run it
-        started. So each span votes for the program whose run starts
-        nearest to it, and the program with most votes is taken, with all
-        of its runs. Returns (start_s, duration_s)."""
-        rx = re.compile(module_pattern)
-        first = self.modules[min(self.modules)] if self.modules else []
-        runs = sorted((s, d, n) for n, s, d in first
-                      if rx.search(n) and s >= 0.0
-                      and s + d <= self.window_s)
-        spans = self.spans_named(span_pattern)
-        if not runs or not spans:
-            return []
-        starts = [r[0] for r in runs]
-        votes = {}
-        for _n, s, _d in spans:
-            k = bisect.bisect_left(starts, s)
-            near = min((c for c in (k - 1, k) if 0 <= c < len(runs)),
-                       key=lambda c: abs(starts[c] - s))
-            votes[runs[near][2]] = votes.get(runs[near][2], 0) + 1
-        program = max(votes, key=votes.get)
-        return [(s, d) for s, d, n in runs if n == program]
 
     def spans_named(self, pattern):
         rx = re.compile(pattern)

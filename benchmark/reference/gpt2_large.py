@@ -100,18 +100,22 @@ def _block(x, lp, heads, precision):
     return x + f
 
 
-@functools.partial(jax.jit, static_argnames=("precision",))
-def _head(x, gamma, beta, table, precision):
+@functools.partial(jax.jit, static_argnames=("count", "precision"))
+def _head(x, start, gamma, beta, table, count, precision):
+    # the rows are cut here, at a traced start, so that one program serves
+    # every request with ``count`` served tokens wherever its prompt ends
+    x = jax.lax.dynamic_slice_in_dim(x, start, count)
     x = layer_norm(x, gamma.astype(jnp.float32), beta.astype(jnp.float32))
     return jnp.einsum("ti,vi->tv", _operand(x, precision),
                       _operand(table.astype(jnp.float32), precision),
                       precision=HI)
 
 
-def logits(cfg, params, tokens, first, precision="float32"):
-    """Logits (len(tokens) - first, vocab) that predict tokens[first:], i.e.
-    at positions first-1 .. len-2, from one full forward over ``tokens``.
-    ``tokens`` are padded by the caller to a length the jit has seen."""
+def logits(cfg, params, tokens, first, count, precision="float32"):
+    """Logits (count, vocab) that predict tokens[first:first+count], i.e. at
+    positions first-1 .. first+count-2, from one full forward over
+    ``tokens``. ``tokens`` are padded by the caller to a length the jit has
+    seen."""
     tokens = jnp.asarray(tokens, jnp.int32)
     t = tokens.shape[0]
     x = params["word_embed_weight"][tokens].astype(jnp.float32) \
@@ -120,8 +124,8 @@ def logits(cfg, params, tokens, first, precision="float32"):
         pre = "layer%d_" % i
         lp = {k[len(pre):]: v for k, v in params.items() if k.startswith(pre)}
         x = _block(x, lp, cfg["num_heads"], precision)
-    return _head(x[first - 1:t - 1], params["ln_f_gamma"],
-                 params["ln_f_beta"], params["word_embed_weight"], precision)
+    return _head(x, first - 1, params["ln_f_gamma"], params["ln_f_beta"],
+                 params["word_embed_weight"], count, precision)
 
 
 def served_logits(cfg, params, prompt, served, precision="float32",
@@ -136,4 +140,4 @@ def served_logits(cfg, params, prompt, served, precision="float32",
     toks = np.zeros(-(-(n0 + n) // pad_to) * pad_to, np.int32)
     toks[:n0] = prompt
     toks[n0:n0 + n] = served
-    return logits(cfg, params, toks, n0, precision)[:n]
+    return logits(cfg, params, toks, n0, n, precision)

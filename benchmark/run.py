@@ -5,9 +5,10 @@
 
 Knows no model, cell or metric by name. ``--workload x`` resolves to
 ``workloads/x.json``, which names a configuration (``configs/<c>.json``, with
-its plain reference ``reference/<module>.py``), a driver
-(``drivers/<d>.py``, one per kind of measured window) and the metrics the
-cell reports (``metrics/<m>.json``, each naming a reader
+its plain reference ``reference/<module>.py`` and its operation and byte
+counts ``counts/<module>.py``) and a driver (``drivers/<d>.py``, one per kind
+of measured window). The metrics the cell reports are those that
+``BENCHMARK.json`` gives it (``metrics/<m>.json``, each naming a reader
 ``readers/<r>.py``). A name that resolves to no file is an error that names
 the missing path.
 
@@ -19,6 +20,7 @@ and whose ``device`` says ``rehearsal``: it can never be read as a
 measurement.
 """
 import argparse
+import gc
 import importlib.util
 import json
 import os
@@ -68,17 +70,38 @@ def merged(base, over):
     return out
 
 
-def resolve(cell_name, rehearsal):
-    """The cell, its configuration and its metrics, each from its own file."""
+def cell_metrics(cell_name, benchmark):
+    """The names of the metrics ``BENCHMARK.json`` gives a cell: every
+    metric whose entry lists the cell under ``workloads``; an end-to-end
+    metric without the list; a per-layer metric without it that moves an
+    end-to-end metric the cell reports."""
+    listed = lambda m: cell_name in m.get("workloads", [cell_name])
+    e2e = [m["name"] for m in benchmark["end_to_end"] if listed(m)]
+    per = [m["name"] for m in benchmark["per_layer"]
+           if listed(m) and ("workloads" in m or m["moves"] in e2e)]
+    return {"end_to_end": e2e, "per_layer": per}
+
+
+def resolve(cell_name, rehearsal, benchmark=None):
+    """The cell and its configuration, each from its own file; the cell's
+    metrics as ``BENCHMARK.json`` (``benchmark``, read from the root of the
+    checkout where not given) lists them, each with its own file; and the
+    configuration's module of operation and byte counts."""
     cell = load_json("workloads", cell_name)
     config = load_json("configs", cell["config"])
     if rehearsal:
         config = merged(config, {"sizes": config.get("tiny", {})})
         cell = merged(cell, cell.get("tiny", {}))
-    metrics = {}
-    for group in ("end_to_end", "per_layer"):
-        metrics[group] = [(m, load_json("metrics", m)) for m in cell[group]]
-    return cell, config, metrics
+    if benchmark is None:
+        with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+            benchmark = json.load(f)
+    metrics = {group: [(m, load_json("metrics", m)) for m in names]
+               for group, names in cell_metrics(cell_name, benchmark).items()}
+    if "counts" not in config:
+        raise SystemExit("benchmark: configuration %r names no module of "
+                         "counts (\"counts\": a file under benchmark/counts/)"
+                         % cell["config"])
+    return cell, config, metrics, load_module("counts", config["counts"])
 
 
 def override(cell, config, items):
@@ -129,7 +152,7 @@ def main(argv=None):
     if not os.path.isdir(os.path.join(ROOT, "mxnet_tpu")):
         raise SystemExit("benchmark: the system under test (mxnet_tpu/) is "
                          "not in %s" % ROOT)
-    cell, config, metrics = resolve(args.workload, args.cpu_rehearsal)
+    cell, config, metrics, counts = resolve(args.workload, args.cpu_rehearsal)
     override(cell, config, args.set)
     chips = int(cell["chips"])
 
@@ -181,6 +204,12 @@ def main(argv=None):
         record["memory_peak_bytes"] = max(
             int(s.get("peak_bytes_in_use", 0)) for s in stats)
         run.free()                 # the program's state leaves the device
+        # a model is a cycle of blocks and parameters: only a collection
+        # lets go of its arrays, and the reference needs their room
+        gc.collect()
+        note("the program's state is freed: %.2f GB in use on the device"
+             % (max(int((d.memory_stats() or {}).get("bytes_in_use", 0))
+                    for d in devices) / 1e9))
         checks = run.check()       # the plain reference, after the window
         note("reference compared")
     finally:
@@ -196,6 +225,9 @@ def main(argv=None):
         from lib import peaks
         record["device_kind"] = next(iter(peaks.PEAKS))
     record["chips"] = len(devices)
+    # what the readers count with: the configuration's own module, beside
+    # the driver's sizes and traffic
+    record["counts"] = counts
     group = "per_layer" if args.trace else "end_to_end"
     if args.trace:
         device["busy_s"] = record["trace"].busy_s
@@ -204,7 +236,8 @@ def main(argv=None):
     if args.cpu_rehearsal:
         # the readers are walked, their CPU readings are withheld
         print("rehearsal: read and withheld %s; found nothing for %s"
-              % (sorted(values), sorted(set(cell[group]) - set(values))),
+              % (sorted(values),
+                 sorted({m for m, _spec in metrics[group]} - set(values))),
               file=sys.stderr)
         device["platform"] = "rehearsal"
         values = {}

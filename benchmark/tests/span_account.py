@@ -7,12 +7,8 @@ readers read, writes what the result line has no room for: the device's idle
 seconds by the kind of the program's span that covers each gap (all kinds,
 not the ten longest), every kind's count and mean length, what a tick and a
 join spend outside the spans they are parents of, the device time of each
-named program, the names of all the server's programs, whether the vote of
-the ``decode[step]`` spans (``Trace.runs_launched_by``) elects the runs that
-the name ``jit_pure_step_*`` finds, and how long the profiler took to stop.
-It also reads, from the same record and through ``run.read_metrics``, the
-five metrics of ``SPAN_METRICS``: their files and readers are there, but no
-cell's file lists them yet, so the result line does not carry them.
+named program, the names of all the server's programs, and how long the
+profiler took to stop. (The spans' own metrics are in the result line.)
 For the builder's sessions on the chip; the driver's check never calls it.
 """
 import json
@@ -26,11 +22,6 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 import run                                   # noqa: E402
 from lib import trace_reduce                 # noqa: E402
-
-# metrics/<name>.json of the spans' and names' readers, listed by no cell yet
-SPAN_METRICS = ("idle_named_share.serve", "join_stall_ms.serve",
-                "readout_ms.serve", "deliver_ms.serve",
-                "decode_step_device_ms.serve")
 
 # a parent kind and the kinds of its own children (profiler.decode_scope)
 PARENTS = {"decode[tick": re.compile(
@@ -64,8 +55,6 @@ def account(trace):
             name = re.sub(r"\(\d+\)$", "", name)
             n, total = programs.get(name, (0, 0.0))
             programs[name] = (n + 1, total + d)
-    voted = sorted(trace.runs_launched_by(r"^decode\[step ", r"^jit_pure"))
-    named = sorted(trace.module_runs(r"^jit_pure_step_"))
     mean = lambda n, total: {"n": n, "mean_ms": 1e3 * total / n,
                              "total_s": total}
     return {
@@ -77,8 +66,6 @@ def account(trace):
                     for k, (n, total, self_s) in sorted(parents.items())},
         "programs_in_slice": {k: mean(*v)
                               for k, v in sorted(programs.items())},
-        "vote": {"runs_voted": len(voted), "runs_named": len(named),
-                 "agree": bool(named) and voted == named},
     }
 
 
@@ -100,7 +87,6 @@ def main(argv):
 
     found = {}
     reduce_dir, stop = trace_reduce.reduce_dir, profiler.stop
-    read_metrics = run.read_metrics
 
     def reduce_and_account(trace_dir):
         trace = reduce_dir(trace_dir)
@@ -115,18 +101,11 @@ def main(argv):
         finally:
             found["profiler_stop_s"] = time.perf_counter() - t0
 
-    def read_span_metrics_too(specs, record):
-        found["span_metrics"] = read_metrics(
-            [(m, run.load_json("metrics", m)) for m in SPAN_METRICS], record)
-        return read_metrics(specs, record)
-
     trace_reduce.reduce_dir, profiler.stop = reduce_and_account, timed_stop
-    run.read_metrics = read_span_metrics_too
     try:
         rc = run.main(args)
     finally:
         trace_reduce.reduce_dir, profiler.stop = reduce_dir, stop
-        run.read_metrics = read_metrics
     os.makedirs(os.path.dirname(os.path.abspath(out_path)), exist_ok=True)
     with open(out_path, "w") as f:
         json.dump(found, f, indent=1)

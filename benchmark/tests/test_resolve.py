@@ -1,5 +1,6 @@
-"""Every name resolves to a file, and ``BENCHMARK.json`` says what the files
-say."""
+"""Every name resolves to a file, ``BENCHMARK.json`` says what the files
+say, and a cell's metrics are those ``BENCHMARK.json`` gives it."""
+import copy
 import glob
 import json
 import os
@@ -21,20 +22,67 @@ def names(kind):
                   for p in glob.glob(os.path.join(BENCH, kind, "*.json")))
 
 
+def benchmark_json():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
 @pytest.mark.parametrize("cell", names("workloads"))
 def test_cell_resolves(cell):
-    spec, config, metrics = run.resolve(cell, rehearsal=False)
+    spec, config, metrics, counts = run.resolve(cell, rehearsal=False)
     assert spec["name"] == cell and NAME.match(cell)
+    assert "end_to_end" not in spec and "per_layer" not in spec
     run.load_module("drivers", spec["driver"])
     ref = run.load_module("reference", config["reference"])
     assert ref.param_specs(config["sizes"])
+    assert counts.__file__.endswith(
+        os.path.join("counts", config["counts"] + ".py"))
     for group in ("end_to_end", "per_layer"):
         assert metrics[group]
         for name, m in metrics[group]:
             assert m["name"] == name
             run.load_module("readers", m["reader"])
-    assert "setup_s" in spec["end_to_end"] and len(spec["end_to_end"]) >= 2
+    e2e = [name for name, _m in metrics["end_to_end"]]
+    assert "setup_s" in e2e and len(e2e) >= 2
     run.resolve(cell, rehearsal=True)
+
+
+def test_a_metric_reaches_a_cell_through_benchmark_json_alone():
+    """An entry that lists the cell, or that has no list and moves an
+    end-to-end metric the cell reports, is all a metric with a file needs;
+    no file under ``workloads/`` knows a metric."""
+    cell, other = "gpt2-large.chat-decode", "bert-large.pretrain-seq128"
+    b = benchmark_json()
+    reported = lambda bench, c=cell: [
+        name for name, _m in run.resolve(c, False, bench)[2]["per_layer"]]
+    per = {m["name"]: m for m in b["per_layer"]}
+    # listed: reported; struck from the list: gone, and from no other cell
+    assert "readout_ms.serve" in reported(b)
+    less = copy.deepcopy(b)
+    next(m for m in less["per_layer"] if m["name"] == "readout_ms.serve")[
+        "workloads"].remove(cell)
+    assert "readout_ms.serve" not in reported(less)
+    assert reported(less) == [n for n in reported(b)
+                              if n != "readout_ms.serve"]
+    # a new entry for a metric file that is there, listing the cell
+    more = copy.deepcopy(b)
+    more["per_layer"] = [m for m in more["per_layer"]
+                         if m["name"] != "deliver_ms.serve"]
+    assert "deliver_ms.serve" not in reported(more)
+    more["per_layer"].append(dict(per["deliver_ms.serve"],
+                                  workloads=[cell]))
+    assert reported(more)[-1] == "deliver_ms.serve"
+    # without a list: due wherever the metric it moves is reported
+    assert "workloads" not in per["step_mfu.serve"]
+    assert "step_mfu.serve" in reported(b)
+    assert "step_mfu.serve" not in reported(b, other)
+    assert "step_mfu.train" in reported(b, other)
+    # an entry whose metric has no file names the missing path
+    more["per_layer"].append(dict(per["deliver_ms.serve"],
+                                  name="no_such_metric.serve"))
+    with pytest.raises(SystemExit) as e:
+        run.resolve(cell, False, more)
+    assert "benchmark/metrics/no_such_metric.serve.json" in str(e.value)
 
 
 @pytest.mark.parametrize("metric", names("metrics"))
@@ -62,8 +110,30 @@ def test_a_missing_name_names_the_missing_path():
     assert "benchmark/readers/no_such_reader.py" in str(e.value)
 
 
+def test_a_missing_counts_module_names_the_missing_path(monkeypatch):
+    real = run.load_json
+
+    def with_counts(value):
+        def load(kind, name):
+            found = dict(real(kind, name))
+            if kind == "configs":
+                found.pop("counts")
+                found.update(value)
+            return found
+        return load
+
+    monkeypatch.setattr(run, "load_json", with_counts({"counts": "no_such"}))
+    with pytest.raises(SystemExit) as e:
+        run.resolve("gpt2-large.chat-decode", rehearsal=False)
+    assert "benchmark/counts/no_such.py" in str(e.value)
+    monkeypatch.setattr(run, "load_json", with_counts({}))
+    with pytest.raises(SystemExit) as e:
+        run.resolve("gpt2-large.chat-decode", rehearsal=False)
+    assert "names no module of counts" in str(e.value)
+
+
 def test_benchmark_json_agrees_with_the_files():
-    b = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
+    b = benchmark_json()
     assert sorted(b) == sorted(["command", "paths", "run_seconds", "configs",
                                 "workloads", "end_to_end", "per_layer"])
     assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) < 64 * 1024
@@ -89,13 +159,13 @@ def test_benchmark_json_agrees_with_the_files():
         assert (spec["config"], spec["chips"], spec["why"]) == (
             w["config"], w["chips"], w["why"])
         assert w["config"] in configs and len(w["why"]) <= 200
-        for m in spec["end_to_end"]:
-            assert name in e2e[m].get("workloads", [name])
-        for m in spec["per_layer"]:
-            # a metric without the key is due in every cell that reports
-            # the end-to-end metric it moves
-            assert name in per[m].get("workloads", [name])
-            assert per[m]["moves"] in spec["end_to_end"]
+        due = run.cell_metrics(name, b)
+        # every cell reports the set-up time, another end-to-end metric and
+        # a per-layer metric; what a per-layer metric moves, the cell reports
+        assert "setup_s" in due["end_to_end"] and len(due["end_to_end"]) >= 2
+        assert due["per_layer"]
+        for m in due["per_layer"]:
+            assert per[m]["moves"] in due["end_to_end"]
     for group, table in (("end_to_end", e2e), ("per_layer", per)):
         for name, m in table.items():
             f = json.load(open(os.path.join(BENCH, "metrics",
@@ -108,10 +178,7 @@ def test_benchmark_json_agrees_with_the_files():
             else:
                 assert 0.01 <= m["bound"] <= 0.1
                 assert m["source"] in ("host_clock", "device_trace")
-            for w in m.get("workloads", []):
-                spec = json.load(open(os.path.join(BENCH, "workloads",
-                                                   w + ".json")))
-                assert name in spec[group]
+            assert all(w in cells for w in m.get("workloads", []))
     # a whole-step share of the peak beside the kernels' rooflines
     for moved in {m["moves"] for n, m in per.items() if "roofline" in n}:
         assert any("mfu" in n and m["moves"] == moved

@@ -10,6 +10,8 @@ from lib import trace_reduce
 
 SAMPLE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                       "sample.xplane.pb")
+DECODE_STEP = r"^jit_pure\(2996622927838797104\)"
+LONG_PREFILL = r"^jit_pure\(7752204452423745631\)"
 
 
 @pytest.fixture(scope="module")
@@ -54,11 +56,13 @@ def test_program_runs(trace):
     steps = trace.module_runs(r"^jit_step")
     assert len(steps) == 3
     assert all(0.40e-3 < d < 0.42e-3 for _s, d in steps)
-    decode = trace.runs_launched_by(r"^decode\[step ", r"^jit_pure")
+    # the sample's serving programs are all ``jit_pure`` and differ in the
+    # fingerprint behind the name: the decode step's ten runs, and the two
+    # prefills of the 1024 bucket
+    decode = trace.module_runs(DECODE_STEP)
     assert len(decode) == 10
     assert all(88e-6 < d < 90e-6 for _s, d in decode)
-    long_prefill = trace.runs_launched_by(r"^decode\[prefill1024 ",
-                                          r"^jit_pure")
+    long_prefill = trace.module_runs(LONG_PREFILL)
     assert [round(d * 1e6) for _s, d in long_prefill] == [135, 135]
 
 
@@ -77,6 +81,7 @@ def test_readers_on_the_sample(trace):
     import run
 
     record = {"trace": trace, "device_kind": "TPU v5 lite", "chips": 1,
+              "counts": run.load_module("counts", "bert"), "samples": {},
               "sizes": {"num_layers": 2, "units": 256, "hidden_size": 1024,
                         "vocab_size": 1024},
               "traffic": {"batch": 8, "seq": 128, "masked": 8}}
@@ -90,10 +95,10 @@ def test_readers_on_the_sample(trace):
     # 3 calls of 0.289 us on 64 rows x 1024 bf16 logits: 131 KB at 819 GB/s
     # would take 0.160 us
     roof = read("kernel_roofline", kernel="softmax_xent_fwd",
-                program="^jit_step", bytes_per_step="softmax_xent_fwd_bytes")
+                program="^jit_step", bytes="softmax_xent_fwd_bytes")
     assert roof == pytest.approx(55.4, abs=1.0)
     assert read("kernel_roofline", kernel="flash_fwd", program="^jit_step",
-                bytes_per_step="softmax_xent_fwd_bytes") is None
+                bytes="softmax_xent_fwd_bytes") is None
     # 3 steps x 8 samples x 1.32 GFLOP over the 4.2 ms they span
     mfu = read("train_step_mfu", program="^jit_step")
     assert mfu == pytest.approx(3.8, abs=0.1)
@@ -103,3 +108,40 @@ def test_readers_on_the_sample(trace):
         100.0 * 7 / 9)     # five full steps and four of the five half-full ones
     assert read("device_idle_share") > 0 and run.load_module(
         "readers", "device_idle_share").read({"trace": None}, {}) is None
+
+
+def test_serving_readers_on_the_sample(trace):
+    import run
+
+    # the sample's server: two layers of width 256; six tokens were decoded
+    # in the slice, at these contexts
+    record = {"trace": trace, "device_kind": "TPU v5 lite", "chips": 1,
+              "counts": run.load_module("counts", "gpt"), "traffic": {},
+              "sizes": {"num_layers": 2, "units": 256, "hidden": 1024,
+                        "vocab_size": 1024},
+              "samples": {"slice_prefill_len": [700, 900],
+                          "slice_decode_context": [13, 17, 701, 11, 901, 15]}}
+    read = lambda reader, **params: run.load_module(
+        "readers", reader).read(record, params)
+    # ten decode steps of 88-89 us: the weights (2.1 MB a step) and the K/V
+    # of six tokens at their contexts over 819 GB/s
+    weights = 2 * (2 * (12 * 256 * 256 + 13 * 256) + 2 * 256 + 1024 * 256)
+    need = 10 * weights + 2 * 2 * 256 * 2 * (13 + 17 + 701 + 11 + 901 + 15)
+    assert read("decode_step_roofline", program=DECODE_STEP) \
+        == pytest.approx(100.0 * need / 819e9 / sum(
+            d for _s, d in trace.module_runs(DECODE_STEP)))
+    assert read("decode_step_roofline", program="^jit_pure_step_") is None
+    # a kernel held to bytes that follow the traffic (``each``): one column
+    # of K and V a token decoded, six of them, whatever the ten steps moved
+    # (the sample predates the write kernel: any kernel of the step stands in)
+    column = dict(kernel="layernorm_fwd", program=DECODE_STEP,
+                  bytes="kv_cache_write_bytes")
+    spent = trace.op_time_within("layernorm_fwd",
+                                 trace.module_runs(DECODE_STEP))
+    assert spent > 0
+    assert read("kernel_roofline", each="slice_decode_context", **column) \
+        == pytest.approx(100.0 * 6 * (2 * 2 * 256 * 2) / 819e9 / spent)
+    assert read("kernel_roofline", **column) == pytest.approx(
+        100.0 * 10 * (2 * 2 * 256 * 2) / 819e9 / spent)
+    # silent where the slice decoded nothing
+    assert read("kernel_roofline", each="none_recorded", **column) is None

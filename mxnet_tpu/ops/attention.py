@@ -157,10 +157,73 @@ def _reference_attention(q, k, v, mask=None, *, causal=False, scale=None):
     return _dense_attention_core(q, k, v, bias, scale)
 
 
+def _grouped_attention(q, k, v, mask, causal, scale, window):
+    """Dense attention with ``Hkv`` K/V heads shared by groups of
+    ``H // Hkv`` query heads (query head h reads K/V head ``h // G``) and
+    an optional sliding window (key j visible to query i iff
+    ``0 <= i - j < window``; a window implies causality). bf16 MXU
+    operands, fp32 scores and softmax, like :func:`_dense_attention_fwd`;
+    K and V are read once a group, never repeated to H heads. Inference
+    only (no hand-written VJP)."""
+    B, H, T, D = q.shape
+    Hkv, S = k.shape[1], k.shape[2]
+    G = H // Hkv
+    if scale is None:
+        scale = 1.0 / (D ** 0.5)
+    bias = _mask_bias(mask, causal or window is not None, T, S)
+    if window is not None:
+        near = (jnp.arange(T)[:, None] - jnp.arange(S)[None, :]) < window
+        bias = bias + jnp.where(near, 0.0, -1e30).astype(jnp.float32)
+    s = scale * jnp.einsum("bkgqd,bksd->bkgqs", q.reshape(B, Hkv, G, T, D),
+                           k, preferred_element_type=jnp.float32)
+    if bias is not None:
+        # (B | 1, H | 1, T, S) -> (B | 1, Hkv | 1, G | 1, T, S)
+        b0, b1 = bias.shape[:2]
+        bias = bias.reshape((b0, Hkv, G) + bias.shape[2:]) if b1 == H \
+            else bias[:, :, None]
+        s = s + bias
+    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+    out = jnp.einsum("bkgqs,bksd->bkgqd", p, v,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, H, T, D).astype(q.dtype)
+
+
+@register_op("rotary", nondiff=True)
+def rotary(x, positions, *, theta=10000.0):
+    """Rotary position embedding over the whole head width of ``x``
+    (B, H, T, D), interleaved pairs: ``(x[2i], x[2i+1])`` turn by
+    ``positions * theta ** (-2i / D)``. ``positions`` is (T,) or per row
+    (B, T), any integer type. Angles, sines and the rotation are float32;
+    the result is rounded once to ``x``'s type.
+
+    The pair's other element, signed (``(-x[2i+1], x[2i])``), is ``x``
+    times a fixed D x D matrix of 0 and +-1: exact in any type, one small
+    matmul, and it leaves the lane axis alone (a reshape to pairs or a
+    lane rotation would stand as float32 copies of a whole prompt's q)."""
+    D = x.shape[-1]
+    pos = jnp.asarray(positions).astype(jnp.float32)
+    pos = pos[None, None] if pos.ndim == 1 else pos[:, None]   # (B|1,1,T)
+    inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    ang = jnp.repeat(pos[..., None] * inv, 2, axis=-1)         # (B|1,1,T,D)
+    i = jnp.arange(D)
+    swap = jnp.where(i[:, None] == (i ^ 1)[None, :],
+                     jnp.where(i[:, None] % 2 == 1, -1.0, 1.0), 0.0)
+    other = jnp.einsum("...i,ij->...j", x, swap.astype(x.dtype))
+    return (x.astype(jnp.float32) * jnp.cos(ang)
+            + other.astype(jnp.float32) * jnp.sin(ang)).astype(x.dtype)
+
+
 @register_op("scaled_dot_attention")
 def scaled_dot_attention(q, k, v, mask=None, *, causal=False, scale=None,
-                         prefix_mask=False):
+                         prefix_mask=False, window=None):
     """q,k,v: (B, H, T, D); mask broadcastable to (B, H, Tq, Tk), 1=keep.
+
+    Grouped K/V heads: ``k`` and ``v`` may carry fewer heads than ``q``
+    (``H % Hkv == 0``; query head h reads K/V head ``h // (H // Hkv)``).
+    ``window`` (static int) is a sliding causal window: key j is visible to
+    query i iff ``0 <= i - j < window``. Both are inference paths: on a TPU
+    at flash lengths the flash kernel takes them (blocks wholly outside the
+    window are skipped), elsewhere :func:`_grouped_attention`.
 
     prefix_mask=True is the caller's STATIC declaration that ``mask`` is a
     key-padding prefix (mask[b, ..., t] = t < valid_len[b], BERT-style) —
@@ -204,6 +267,11 @@ def scaled_dot_attention(q, k, v, mask=None, *, causal=False, scale=None,
                  scale=scale)
         return jax.device_put(out, orig if orig is not None
                               else mesh.devices.flat[0])
+    grouped = k.shape[1] != q.shape[1]
+    if grouped and q.shape[1] % k.shape[1]:
+        raise ValueError("scaled_dot_attention: %d query heads do not "
+                         "divide into %d K/V heads"
+                         % (q.shape[1], k.shape[1]))
     if (is_tpu_backend() and not under_mesh()
             and q.shape[2] >= _flash_min_len()
             and (mask is None or prefix_mask)):
@@ -211,7 +279,9 @@ def scaled_dot_attention(q, k, v, mask=None, *, causal=False, scale=None,
 
         vl = None if mask is None else _prefix_mask_to_valid_len(mask)
         return flash_attention(q, k, v, causal=causal, scale=scale,
-                               kv_valid_len=vl)
+                               kv_valid_len=vl, window=window)
+    if grouped or window is not None:
+        return _grouped_attention(q, k, v, mask, causal, scale, window)
     return _reference_attention(q, k, v, mask, causal=causal, scale=scale)
 
 
@@ -244,9 +314,11 @@ def cache_write(cache, update, index):
 
     - scalar ``index``: one ``lax.dynamic_update_slice``;
     - per-row ``index``, one token a row, on a TPU, where the shapes tile
-      (``kv_write.tiles``: ``C % 128 == 0``, head width under 128) and no
-      device mesh is being traced: the Pallas kernel ``kv_cache_write``,
-      one pass over the 128-position blocks that hold the rows' positions;
+      (``kv_write.tiles``) and no device mesh is being traced: the Pallas
+      kernel ``kv_cache_write``, one pass over the blocks that hold the
+      rows' positions (128-position blocks with the capacity on the lanes
+      for head widths under 128; one sublane tile of rows for head widths
+      that are whole lane tiles);
     - per-row ``index`` otherwise: ``vmap(dynamic_update_slice)``, which
       is a ``scatter``, which XLA on the TPU expands into a serial
       ``while`` loop of B column updates.

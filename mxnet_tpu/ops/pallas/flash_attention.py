@@ -27,12 +27,14 @@ NEG_INF = -1e30
 LANES = 128
 
 
-def _scores(q_ref, k_ref, q_idx, kv_idx, *, scale, causal, bq, bk, vl=None):
+def _scores(q_ref, k_ref, q_idx, kv_idx, *, scale, causal, bq, bk, vl=None,
+            window=None):
     """Shared Q·Kᵀ score-block recompute — the ONE definition of scaling,
     causal masking, and key-padding masking used by forward and both backward
     kernels, so their numerics can never desynchronize. ``vl`` is a traced
     per-example valid K length: columns >= vl are masked (BERT-style prefix
-    padding)."""
+    padding). ``window`` (static, forward only) also masks columns more than
+    ``window - 1`` behind the row."""
     # native-dtype (bf16) MXU operands with fp32 accumulation; scale applied
     # to the fp32 scores so no extra bf16 rounding hits the matmul inputs
     q = q_ref[0]                              # (bq, d)
@@ -43,14 +45,26 @@ def _scores(q_ref, k_ref, q_idx, kv_idx, *, scale, causal, bq, bk, vl=None):
         rows = q_idx * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         cols = kv_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(rows >= cols, s, NEG_INF)
+        if window is not None:
+            s = jnp.where(rows - cols < window, s, NEG_INF)
     if vl is not None:
         cols = kv_idx * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
         s = jnp.where(cols < vl, s, NEG_INF)
     return s
 
 
+def _kv_block_range(q_idx, bq, bk, causal, window):
+    """First and last K/V block that a Q block of a causal (windowed)
+    attention reads: the one definition the kernel's skip and the index
+    maps' clamp share."""
+    hi = (q_idx * bq + bq - 1) // bk if causal else None
+    lo = jnp.maximum(q_idx * bq - (window - 1), 0) // bk \
+        if window is not None else None
+    return lo, hi
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk,
-                emit_lse, masked):
+                emit_lse, masked, window=None):
     if masked:
         vl_ref, rest = rest[0], rest[1:]
         vl = vl_ref[0, 0, 0]
@@ -74,7 +88,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk,
     if causal:
         # skip fully-masked K blocks: first query row of this Q block is
         # q_idx*bq; block contributes iff kv_idx*bk <= q_idx*bq + bq - 1
-        run = kv_idx * bk <= q_idx * bq + bq - 1
+        # (and, under a window, iff its last column is still in the first
+        # row's window)
+        lo, hi = _kv_block_range(q_idx, bq, bk, causal, window)
+        run = kv_idx <= hi
+        if lo is not None:
+            run = jnp.logical_and(run, kv_idx >= lo)
     if masked:
         # dynamic skip: K blocks entirely past this example's valid length
         run = jnp.logical_and(run, kv_idx * bk < vl)
@@ -83,7 +102,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk,
     def _compute():
         v = v_ref[0]                            # (bk, d) native dtype
         s = _scores(q_ref, k_ref, q_idx, kv_idx, scale=scale, causal=causal,
-                    bq=bq, bk=bk, vl=vl)
+                    bq=bq, bk=bk, vl=vl, window=window)
         m_prev = m_ref[:]                       # (bq, 128) broadcast lanes
         m_cur = jnp.max(s, axis=1, keepdims=True)  # (bq, 1)
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(m_cur, m_prev.shape))
@@ -115,20 +134,37 @@ def _vl_operand(kv_valid_len, B, H):
 
 
 def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
-               return_lse=False):
+               return_lse=False, window=None):
     B, H, Tq, D = q.shape
-    Tk = k.shape[2]
+    Hkv, Tk = k.shape[1], k.shape[2]
     bq = min(bq, Tq)
     bk = min(bk, Tk)
     qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
+    kr = k.reshape(B * Hkv, Tk, D)
+    vr = v.reshape(B * Hkv, Tk, D)
     masked = kv_valid_len is not None
     grid = (B * H, Tq // bq, Tk // bk)
+    if Hkv == H and window is None:
+        kv_block = lambda b, i, j: (b, j, 0)
+    else:
+        group = H // Hkv
+
+        def kv_block(b, i, j):
+            # grouped heads: grid row b = batch * H + head reads K/V row
+            # batch * Hkv + head // group. A block the kernel skips is
+            # clamped onto the nearest one it reads, so that the pipeline
+            # fetches nothing for it
+            lo, hi = _kv_block_range(i, bq, bk, causal, window)
+            if hi is not None:
+                j = jnp.minimum(j, hi)
+            if lo is not None:
+                j = jnp.maximum(j, lo)
+            return ((b // H) * Hkv + (b % H) // group, j, 0)
+
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
+        pl.BlockSpec((1, bk, D), kv_block),
+        pl.BlockSpec((1, bk, D), kv_block),
     ]
     operands = [qr, kr, vr]
     if masked:
@@ -142,7 +178,7 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
         out_shape.append(jax.ShapeDtypeStruct((B * H, Tq, LANES), jnp.float32))
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-                          emit_lse=return_lse, masked=masked),
+                          emit_lse=return_lse, masked=masked, window=window),
         name="flash_fwd",
         interpret=interpret,
         grid=grid,
@@ -498,9 +534,17 @@ def _default_blocks(seq):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
-                    block_k=None, interpret=False, kv_valid_len=None):
+                    block_k=None, interpret=False, kv_valid_len=None,
+                    window=None):
     """q,k,v: (B, H, T, D). D should be a multiple of 128 lanes ideally;
     T must be divisible by the chosen blocks (callers pad).
+
+    Grouped K/V heads (``k``, ``v`` of ``Hkv`` heads, ``H % Hkv == 0``:
+    query head h reads K/V head ``h // (H // Hkv)`` through the K/V index
+    map, nothing is repeated in memory) and ``window`` (static int: key j
+    visible to query i iff ``0 <= i - j < window``; K/V blocks wholly
+    outside are neither computed nor fetched) run the forward kernel alone:
+    the backward kernels know neither, so these calls carry no gradient.
 
     block_q/block_k default from the seq-bucketed BLOCK_DEFAULTS table
     (where the committed hardware sweep lands its winners).
@@ -521,6 +565,11 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         block_k = _default_blocks(Tk)[1]
     bq = _largest_divisor_block(Tq, block_q)
     bk = _largest_divisor_block(Tk, block_k)
+    if window is not None or k.shape[1] != q.shape[1]:
+        return _flash_fwd(q, k, v, kv_valid_len, float(scale),
+                          bool(causal) or window is not None, bq, bk,
+                          interpret=interpret,
+                          window=None if window is None else int(window))
     return _flash(q, k, v, kv_valid_len, float(scale), bool(causal), bq, bk,
                   interpret)
 

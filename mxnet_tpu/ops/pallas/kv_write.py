@@ -13,6 +13,13 @@ view ``(S, H, D, C)``, which is that same memory read row-major, so XLA makes
 the ``swapaxes`` around the call a bitcast and the aliased buffer is updated
 where it lies. A formulation that shows Mosaic ``(S, H, C, D)`` would force a
 relayout of the whole buffer before and after every call.
+
+For head widths that are whole lane tiles (128, 256) the device keeps D on
+the lanes and the positions on the sublanes: one token is one sublane row
+in each head. There the kernel takes the buffer as it is and moves, a slot,
+the one sublane tile of rows (8 of 32 bits, 16 of bf16) that holds the
+slot's position: ``H`` tiles in and out, where the scatter loop ran one
+iteration a slot.
 """
 from __future__ import annotations
 
@@ -30,14 +37,19 @@ _MAX_BLOCK_BYTES = 2 << 20
 def tiles(cache_shape, update_shape, dtype):
     """Whether ``cache`` (S, H, C, D) and ``update`` (S, H, T, D) map onto
     the kernel's blocks: one token a slot, a capacity of whole lane tiles,
-    a head width under one lane tile (else the device keeps D on the lanes
-    and the transposed view is no bitcast) that fills whole sublane tiles
-    of the dtype (8 rows of 32 bits: 16 for bf16), and a block that fits
-    VMEM. The gate in ops/attention.py asks at trace time."""
+    a head width under one lane tile that fills whole sublane tiles of the
+    dtype (8 rows of 32 bits: 16 for bf16), and a block that fits VMEM; or
+    a head width of whole lane tiles (the device then keeps D on the lanes:
+    the row path) and a capacity of whole sublane tiles. The gate in
+    ops/attention.py asks at trace time."""
     _, H, C, D = cache_shape
     itemsize = jnp.dtype(dtype).itemsize
-    return (update_shape[2] == 1 and C % _LANES == 0 and D < _LANES
-            and itemsize in (2, 4) and D % (32 // itemsize) == 0
+    if update_shape[2] != 1 or itemsize not in (2, 4):
+        return False
+    if D % _LANES == 0:
+        # D on the lanes: blocks of one sublane tile of positions
+        return C % (32 // itemsize) == 0
+    return (C % _LANES == 0 and D < _LANES and D % (32 // itemsize) == 0
             and H * D * _LANES * itemsize <= _MAX_BLOCK_BYTES)
 
 
@@ -61,6 +73,45 @@ def _kv_write_kernel(idx_ref, upd_ref, cache_ref, out_ref):
     out_ref[...] = jnp.where(lanes == lane, upd[None], cache_ref[...])
 
 
+def _kv_write_rows_kernel(idx_ref, upd_ref, cache_ref, out_ref):
+    s = pl.program_id(0)
+    rows = cache_ref.shape[2]
+    at = jax.lax.broadcasted_iota(jnp.int32, cache_ref.shape, 2)
+    # the select runs on 32-bit values: a 16-bit tile packs two rows a
+    # sublane, and a row mask has no such layout
+    old = cache_ref[...].astype(jnp.float32)
+    new = jnp.broadcast_to(upd_ref[...].astype(jnp.float32), old.shape)
+    out_ref[...] = jnp.where(at == idx_ref[s] % rows, new,
+                             old).astype(out_ref.dtype)
+
+
+def _kv_cache_write_rows(cache, update, index, interpret):
+    """The write for head widths of whole lane tiles: grid (slots,), one
+    ``(1, H, rows, D)`` block a slot, ``rows`` one sublane tile."""
+    S, H, C, D = cache.shape
+    rows = 32 // cache.dtype.itemsize
+
+    def block_of(s, idx):
+        return (s, 0, idx[s] // rows, 0)
+
+    return pl.pallas_call(
+        _kv_write_rows_kernel,
+        name="kv_cache_write",
+        interpret=interpret,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S,),
+            in_specs=[
+                pl.BlockSpec((1, H, 1, D), lambda s, idx: (s, 0, 0, 0)),
+                pl.BlockSpec((1, H, rows, D), block_of),
+            ],
+            out_specs=pl.BlockSpec((1, H, rows, D), block_of),
+        ),
+        out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+        input_output_aliases={2: 0},
+    )(index, update, cache)
+
+
 def kv_cache_write(cache, update, index, interpret=False):
     """``cache`` (S, H, C, D) with ``update`` (S, H, 1, D) written at
     ``index`` (S,) along axis 2, bit-identical to
@@ -76,6 +127,8 @@ def kv_cache_write(cache, update, index, interpret=False):
     S, H, C, D = cache.shape
     index = index.astype(jnp.int32)
     index = jnp.clip(jnp.where(index < 0, index + C, index), 0, C - 1)
+    if D % _LANES == 0:
+        return _kv_cache_write_rows(cache, update, index, interpret)
     update = jnp.transpose(update[:, :, 0, :], (1, 2, 0))
     update = jnp.pad(update, ((0, 0), (0, 0), (0, -S % _LANES)))
 

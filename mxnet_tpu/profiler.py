@@ -273,7 +273,7 @@ def serve_scope(bucket, n_real):
 
 
 @contextlib.contextmanager
-def decode_scope(kind, slots, n_active, args=None):
+def decode_scope(kind, slots, n_active, args=None, tag=None):
     """Instruments one stretch of the generative-decode scheduler's tick
     (called from serve.decoder when the profiler runs), named
     ``decode[<kind> fill=<active/slots> b<slots>]`` — batch-fill efficiency
@@ -307,9 +307,14 @@ def decode_scope(kind, slots, n_active, args=None):
 
     ``args`` goes to the TraceAnnotation (stats of the event in the
     ``.xplane.pb``) and to the Chrome record; the dict yielded is the
-    record's ``args``, to which the caller may add what it learns inside."""
-    name = "decode[%s fill=%.2f b%d]" % (kind, n_active / max(slots, 1),
-                                         slots)
+    record's ``args``, to which the caller may add what it learns inside.
+    ``tag`` is more ``key=value`` fields of the name itself, which is what
+    a reader of the trace sees: a routing model's ``step`` carries
+    ``xmax=<the largest expert's load over the mean load>`` and
+    ``xhit=<(layer, expert) pairs that got a pick>`` of the step before it
+    (``decode[step fill=0.41 b32 xmax=2.50 xhit=38]``)."""
+    name = "decode[%s fill=%.2f b%d%s]" % (
+        kind, n_active / max(slots, 1), slots, " " + tag if tag else "")
     rec_args = {"slots": slots, "active": n_active}
     if args:
         rec_args.update(args)

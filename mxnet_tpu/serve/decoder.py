@@ -189,6 +189,15 @@ class GenerativeServer:
         ``decode_step_fixed(F, tokens, k_caches, v_caches, valid_len)``
         (``models.gpt.GPTModel`` is the reference implementation).
         Must be initialized; its parameter dtype decides the cache dtype.
+        The spec may give the cache geometry layer by layer: ``kv_heads``
+        (K/V heads a buffer, under grouped-query attention), ``windows``
+        (a ring length for each sliding-window layer, ``None`` for a full
+        page), and ``routed`` (layers, columns): the model routes tokens to
+        experts, so its ``forward_collect_kv`` takes the prompt's length
+        (pad rows route nowhere; it returns the last live row's logits),
+        its ``decode_step_fixed`` takes the active mask, and both return
+        an int32 load array of that shape after the caches
+        (``models.cohere_moe.CohereMoEModel``).
     slots : int
         In-flight request pages — the padded decode batch. One decode
         dispatch serves all of them; free slots are masked, so join/leave
@@ -280,9 +289,13 @@ class GenerativeServer:
         self._params_lock = threading.Lock()
         self._swap_epoch = 0
         self.cache = PagedKVCache(
-            spec["layers"], spec["heads"], spec["head_dim"], self.slots,
-            spec["max_length"], dtype=spec["dtype"],
-            quantize=self._quantize is not None)
+            spec["layers"], spec.get("kv_heads", spec["heads"]),
+            spec["head_dim"], self.slots, spec["max_length"],
+            dtype=spec["dtype"], quantize=self._quantize is not None,
+            windows=spec.get("windows"))
+        # (layers, columns) of the expert-load array a routing model's
+        # programs return; None for a dense model
+        self._routed = spec.get("routed")
         self.prefix = PrefixCache() if prefix_cache else None
         self.metrics = GenerativeMetrics(self.name)
         if donate is None:
@@ -330,6 +343,11 @@ class GenerativeServer:
         # decode until their final chunk lands
         self._prefill_chunk = None
         if prefill_chunk is not None:
+            if not hasattr(model, "decode_step_speculative"):
+                raise ServeError(
+                    "prefill_chunk: model %s has no decode_step_speculative "
+                    "— chunks run through the wide-window protocol (see "
+                    "models.gpt.GPTModel)" % type(model).__name__)
             self._prefill_chunk = next_pow2(max(8, int(prefill_chunk)))
             if self._draft is not None and self._prefill_chunk < self.spec_k:
                 raise ServeError(
@@ -578,8 +596,8 @@ class GenerativeServer:
         if idle is not None:
             idle.__exit__(None, None, None)
 
-    def _span(self, tracing, kind, n_active, args=None):
-        return (profiler.decode_scope(kind, self.slots, n_active, args)
+    def _span(self, tracing, kind, n_active, args=None, tag=None):
+        return (profiler.decode_scope(kind, self.slots, n_active, args, tag)
                 if tracing else _NO_SPAN)
 
     def _loop(self):
@@ -695,11 +713,12 @@ class GenerativeServer:
                         jnp.int32(plen), jnp.int32(slot), jnp.asarray(last),
                         jnp.asarray(key), jnp.float32(stream.temperature))
                 else:
+                    as_dev = lambda st: jax.tree_util.tree_map(jnp.asarray,
+                                                               st)
                     kcs, vcs, valid, toks = fn(
                         self.cache.k, self.cache.v, self.cache.valid,
-                        self._tok, jnp.asarray(k_stack),
-                        jnp.asarray(v_stack), jnp.int32(plen),
-                        jnp.int32(slot), jnp.asarray(last),
+                        self._tok, as_dev(k_stack), as_dev(v_stack),
+                        jnp.int32(plen), jnp.int32(slot), jnp.asarray(last),
                         jnp.asarray(key), jnp.float32(stream.temperature))
             else:
                 fn = self._prefill_fn(tp, self.cache.capacity)
@@ -712,7 +731,7 @@ class GenerativeServer:
                         jnp.int32(slot), jnp.asarray(key),
                         jnp.float32(stream.temperature))
                 else:
-                    kcs, vcs, valid, toks, last = fn(
+                    kcs, vcs, valid, toks, last, *load = fn(
                         params, self.cache.k, self.cache.v, self.cache.valid,
                         self._tok, jnp.asarray(padded), jnp.int32(t0_len),
                         jnp.int32(slot), jnp.asarray(key),
@@ -735,9 +754,8 @@ class GenerativeServer:
                 if tracing:
                     # the K and V page as the store keeps them (a quantized
                     # cache reads out in float32)
-                    copied = {"mb": round(
-                        2e-6 * c.layers * c.heads * tp * c.head_dim
-                        * (4 if self._quantize else c.dtype.itemsize), 3)}
+                    copied = {"mb": round(1e-6 * c.page_bytes(
+                        tp, 4 if self._quantize else None), 3)}
                 with self._span(tracing, "readout%d" % tp, c.num_active,
                                 copied):
                     if self._quantize:
@@ -750,6 +768,9 @@ class GenerativeServer:
                                     np.asarray(last))
         first = int(np.asarray(self._tok)[slot])
         now = time.perf_counter()
+        if hit is None and self._routed is not None:
+            # read behind the first token: the prefill has finished
+            self.metrics.record_expert_load(np.asarray(load[0]))
         if tr is not None:
             # prefill (or prefix-inject) dispatch, closed by the first-token
             # host readback; the first token is sampled inside this program
@@ -802,20 +823,32 @@ class GenerativeServer:
         engine.dispatch_counter.bump()
         t0 = time.perf_counter()
         # the step as the scheduler sees it: dispatch to the tokens' arrival
-        with self._span(tracing, "step", n_active):
+        # (a routing model's span says how unevenly the step before it
+        # loaded the experts: this step's own load arrives with its tokens)
+        with self._span(tracing, "step", n_active,
+                        tag=self.metrics.expert_tag()
+                        if tracing and self._routed is not None else None):
             out = fn(*args)
-            kss = vss = None
+            kss = vss = load = None
             if self._quantize:
                 kcs, kss, vcs, vss, valid, nxt = out
+                read = nxt
             else:
-                kcs, vcs, valid, nxt = out
-            nxt_host = np.asarray(nxt)   # ONE host gather per step: tokens
+                # a routing model's load rides behind the tokens
+                kcs, vcs, valid, nxt, *packed = out
+                read = packed[0] if packed else nxt
+            host = np.asarray(read)      # ONE host gather per step
+            nxt_host = host[:self.slots]
+            if self._routed is not None:
+                load = host[self.slots:].reshape(self._routed)
         with self._span(tracing, "deliver", n_active):
             self.cache.update(kcs, vcs, valid, kss, vss)
             self._tok = nxt
             dt = time.perf_counter() - t0
             self.metrics.record_step(dt, n_active, n_active, self.slots,
                                      under_prefill=bool(self._chunk_jobs))
+            if load is not None:
+                self.metrics.record_expert_load(load, tag=tracing)
             now = time.perf_counter()
             for slot in np.nonzero(active)[0]:
                 self._deliver(int(slot), int(nxt_host[slot]), now, step_s=dt)
@@ -1074,14 +1107,20 @@ class GenerativeServer:
             self._decode_fns[capacity] = fn
             return fn
 
+        routed = self._routed is not None
+
         def pure(params, kcs, vcs, valid, toks, active, keys, temps):
             # trace-time bump: fires exactly when XLA retraces — the
             # zero-steady-state-retrace proof tests assert
             engine.decode_compile_counter.bump()
             with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
                 t.param_store = {id(p): a for p, a in zip(plist, params)}
-                logits, kcs, vcs = model.decode_step_fixed(
-                    _trace.F, toks, kcs, vcs, valid)
+                if routed:
+                    logits, kcs, vcs, load = model.decode_step_fixed(
+                        _trace.F, toks, kcs, vcs, valid, active)
+                else:
+                    logits, kcs, vcs = model.decode_step_fixed(
+                        _trace.F, toks, kcs, vcs, valid)
             # the generated token's position is valid+1 (prefill used
             # `prompt_len` for the first token) — every token of a stream
             # folds a distinct position into its slot key
@@ -1089,6 +1128,10 @@ class GenerativeServer:
             act = active > 0
             nxt = jnp.where(act, nxt, 0)
             valid = valid + act.astype(jnp.int32)
+            if routed:
+                # what the host reads: the tokens, then the experts' load
+                return kcs, vcs, valid, nxt, jnp.concatenate(
+                    [nxt, load.astype(jnp.int32).reshape(-1)])
             return kcs, vcs, valid, nxt
 
         fn = self._jit(pure, donate=(1, 2, 3, 4), hint="step@c%d" % capacity)
@@ -1351,27 +1394,49 @@ class GenerativeServer:
             self._prefill_fns[(tp, capacity)] = fn
             return fn
 
+        routed = self._routed is not None
+
+        def page(kv, cache, plen):
+            """The prompt's K or V (1, H, tp, D) as ``cache``'s layer keeps
+            it: as it is, or, where the prompt's bucket is longer than the
+            layer's ring, the ring's rows: slot j holds the last position
+            p < plen with p % length == j."""
+            length = cache.shape[2]
+            if kv.shape[2] > length:
+                j = jnp.arange(length, dtype=jnp.int32)
+                kv = jnp.take(kv, jnp.clip(
+                    plen - 1 - (plen - 1 - j) % length, 0, kv.shape[2] - 1),
+                    axis=2)
+            return kv.astype(cache.dtype)
+
         def pure(params, kcs, vcs, valid, toks, tokens, plen, slot, key,
                  temp):
             engine.decode_compile_counter.bump()
             with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
                 t.param_store = {id(p): a for p, a in zip(plist, params)}
-                logits, kvs = model.forward_collect_kv(_trace.F, tokens)
+                if routed:
+                    # the model cuts the last live row itself: (1, 1, V)
+                    logits, kvs, load = model.forward_collect_kv(
+                        _trace.F, tokens, plen)
+                    last = jnp.reshape(logits, (1, -1))
+                else:
+                    logits, kvs = model.forward_collect_kv(_trace.F, tokens)
+                    last = jnp.reshape(jax.lax.dynamic_slice(
+                        logits, (zero, plen - 1, zero),
+                        (1, 1, logits.shape[2])), (1, -1))
             kcs = [jax.lax.dynamic_update_slice(
-                kc, k.astype(kc.dtype), (slot, zero, zero, zero))
+                kc, page(k, kc, plen), (slot, zero, zero, zero))
                 for kc, (k, _v) in zip(kcs, kvs)]
             vcs = [jax.lax.dynamic_update_slice(
-                vc, v.astype(vc.dtype), (slot, zero, zero, zero))
+                vc, page(v, vc, plen), (slot, zero, zero, zero))
                 for vc, (_k, v) in zip(vcs, kvs)]
             valid = jax.lax.dynamic_update_slice(
                 valid, jnp.reshape(plen, (1,)), (slot,))
-            last = jnp.reshape(jax.lax.dynamic_slice(
-                logits, (zero, plen - 1, zero),
-                (1, 1, logits.shape[2])), (1, -1))
             t0 = sample_tokens(last, key[None], plen[None], temp[None],
                                top_k)
             toks = jax.lax.dynamic_update_slice(toks, t0, (slot,))
-            return kcs, vcs, valid, toks, jnp.reshape(last, (-1,))
+            out = (kcs, vcs, valid, toks, jnp.reshape(last, (-1,)))
+            return out + (load.astype(jnp.int32),) if routed else out
 
         fn = self._jit(pure, donate=(1, 2, 3, 4),
                        hint="prefill@t%dc%d" % (tp, capacity))
@@ -1474,14 +1539,19 @@ class GenerativeServer:
             self._extract_fns[(tp, capacity)] = fn
             return fn
 
+        lengths = self.cache.page_lengths(tp)
+        # one stacked array where every layer's page has the same length,
+        # else (window rings beside full pages) one array a layer
+        pack = jnp.stack if len(set(lengths)) == 1 else tuple
+
         def pure(kcs, vcs, slot):
             engine.decode_compile_counter.bump()
-            ks = jnp.stack([jax.lax.dynamic_slice(
-                kc, (slot, zero, zero, zero), (1, H, tp, D))[0]
-                for kc in kcs])
-            vs = jnp.stack([jax.lax.dynamic_slice(
-                vc, (slot, zero, zero, zero), (1, H, tp, D))[0]
-                for vc in vcs])
+            ks = pack([jax.lax.dynamic_slice(
+                kc, (slot, zero, zero, zero), (1, H, n, D))[0]
+                for kc, n in zip(kcs, lengths)])
+            vs = pack([jax.lax.dynamic_slice(
+                vc, (slot, zero, zero, zero), (1, H, n, D))[0]
+                for vc, n in zip(vcs, lengths)])
             return ks, vs
 
         # reads live caches: never donate
@@ -1517,7 +1587,7 @@ class GenerativeServer:
                     jnp.asarray(key), jnp.float32(0.0))
                 self.cache.update(kcs, vcs, valid, kss, vss)
             else:
-                kcs, vcs, valid, toks, _last = fn(
+                kcs, vcs, valid, toks, _last, *_load = fn(
                     params, self.cache.k, self.cache.v, self.cache.valid,
                     self._tok, jnp.asarray(padded), jnp.int32(int(b)),
                     jnp.int32(slot), jnp.asarray(key), jnp.float32(0.0))

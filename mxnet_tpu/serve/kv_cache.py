@@ -5,7 +5,8 @@ The decode-side analogue of ``executor_pool``'s pad-to-bucket discipline
 instead of a per-request cache tensor whose time axis grows every token —
 a new aval per step, so every compiled consumer retraces (graphlint GL007)
 — all in-flight requests share per-layer ``(slots, heads, capacity,
-head_dim)`` buffers. Each request owns one SLOT page; its tokens are
+head_dim)`` buffers (a sliding-window layer's is a ring of its window's
+length: ``windows``). Each request owns one SLOT page; its tokens are
 written in place at its own ``valid_len`` position by the ``cache_write``
 op and attention masks to the live prefix, so **no shape ever changes
 across decode steps**. What that write lowers to depends on the call
@@ -54,7 +55,9 @@ class PagedKVCache:
     Parameters
     ----------
     layers, heads, head_dim : int
-        Per-layer buffer geometry (``model.decode_state_spec()``).
+        Per-layer buffer geometry (``model.decode_state_spec()``);
+        ``heads`` are the K/V heads a buffer holds (fewer than the query
+        heads under grouped-query attention).
     slots : int
         Number of request pages — the padded decode batch size.
     max_capacity : int
@@ -70,11 +73,25 @@ class PagedKVCache:
         program; capacity buckets, donation and the one-dispatch step are
         unchanged. Scale buffers are capacity-independent, so migrations
         only pad the int8 pages.
+    windows : None or list
+        The geometry layer by layer: ``None`` for a full page (time axis =
+        the capacity bucket), or the length of a RING for a sliding-window
+        layer, whose time axis is ``min(capacity, window)``: the model
+        writes position p at ``p % length``, so a ring never holds more
+        than its window and never grows past it. Not with ``quantize``.
     """
 
     def __init__(self, layers, heads, head_dim, slots, max_capacity,
-                 dtype=np.float32, quantize=False):
+                 dtype=np.float32, quantize=False, windows=None):
         self.layers = int(layers)
+        self.windows = [None if w is None else int(w)
+                        for w in (windows or [None] * self.layers)]
+        if len(self.windows) != self.layers:
+            raise CacheError("%d windows for %d layers"
+                             % (len(self.windows), self.layers))
+        if quantize and any(w is not None for w in self.windows):
+            raise CacheError("int8 pages keep one running scale a page: "
+                             "not for window rings")
         self.heads = int(heads)
         self.head_dim = int(head_dim)
         self.slots = int(slots)
@@ -85,7 +102,7 @@ class PagedKVCache:
         # element — the denominator of the bytes-saved accounting
         self._ref_itemsize = np.dtype(dtype).itemsize
         self.capacity = 0
-        self.k = None     # list[L] of (slots, H, capacity, D) jax arrays
+        self.k = None     # list[L] of (slots, H, layer_length, D) jax arrays
         self.v = None
         self.k_scale = None  # list[L] of (slots, H, 1, 1) fp32 (quantized)
         self.v_scale = None
@@ -104,6 +121,26 @@ class PagedKVCache:
                 "max_length is %d" % (need, self.max_capacity))
         return min(self.max_capacity, next_pow2(need))
 
+    def layer_length(self, layer, capacity=None):
+        """Time axis of ``layer``'s buffers at a capacity bucket (the
+        current one by default): the bucket, or a ring's own length."""
+        cap = self.capacity if capacity is None else int(capacity)
+        w = self.windows[layer]
+        return cap if w is None else min(cap, w)
+
+    def page_lengths(self, tp):
+        """Positions a prompt of bucket ``tp`` leaves in each layer: ``tp``,
+        or the whole ring where the prompt is longer."""
+        return [min(int(tp), self.layer_length(i))
+                for i in range(self.layers)]
+
+    def page_bytes(self, tp, itemsize=None):
+        """Bytes of the K and V page of a prompt of bucket ``tp``, as the
+        prefix store keeps it."""
+        itemsize = self.dtype.itemsize if itemsize is None else itemsize
+        return 2 * sum(self.page_lengths(tp)) * self.heads * self.head_dim \
+            * itemsize
+
     def ensure_capacity(self, need):
         """Grow the buffers to the bucket that fits ``need`` (zero-padding
         the time axis — one migration dispatch per layer, then the decode
@@ -113,10 +150,11 @@ class PagedKVCache:
         cap = self.capacity_bucket(need)
         if cap <= self.capacity and self.k is not None:
             return False
-        shape = (self.slots, self.heads, cap, self.head_dim)
         if self.k is None:
-            self.k = [jnp.zeros(shape, self.dtype) for _ in range(self.layers)]
-            self.v = [jnp.zeros(shape, self.dtype) for _ in range(self.layers)]
+            shapes = [(self.slots, self.heads, self.layer_length(i, cap),
+                       self.head_dim) for i in range(self.layers)]
+            self.k = [jnp.zeros(shape, self.dtype) for shape in shapes]
+            self.v = [jnp.zeros(shape, self.dtype) for shape in shapes]
             if self.quantize:
                 sshape = (self.slots, self.heads, 1, 1)
                 self.k_scale = [jnp.zeros(sshape, jnp.float32)
@@ -124,9 +162,15 @@ class PagedKVCache:
                 self.v_scale = [jnp.zeros(sshape, jnp.float32)
                                 for _ in range(self.layers)]
         else:
-            pad = ((0, 0), (0, 0), (0, cap - self.capacity), (0, 0))
-            self.k = [jnp.pad(k, pad) for k in self.k]
-            self.v = [jnp.pad(v, pad) for v in self.v]
+            # a ring that has reached its window stays as it is: until then
+            # no position has wrapped, and padding keeps p % length == p
+            def grow(a, i):
+                more = self.layer_length(i, cap) - a.shape[2]
+                return jnp.pad(a, ((0, 0), (0, 0), (0, more), (0, 0))) \
+                    if more else a
+
+            self.k = [grow(k, i) for i, k in enumerate(self.k)]
+            self.v = [grow(v, i) for i, v in enumerate(self.v)]
             # scale buffers are (slots, H, 1, 1) — capacity-independent
             self.migrations += 1
         self.capacity = cap
@@ -199,9 +243,15 @@ class PagedKVCache:
         model dtype's (pass 2 to compare against a bf16 cache)."""
         if self.k is None:
             return 0
-        elems = 2 * self.layers * self.slots * self.heads \
-            * self.capacity * self.head_dim
+        elems = 2 * self.slots * self.heads * self.head_dim \
+            * sum(self.layer_length(i) for i in range(self.layers))
         return elems * (self._ref_itemsize if itemsize is None else itemsize)
+
+
+def _host(stack):
+    """A page stack as host arrays: one array, or a tuple of one a layer."""
+    return tuple(np.asarray(a) for a in stack) \
+        if isinstance(stack, (tuple, list)) else np.asarray(stack)
 
 
 class PrefixCache:
@@ -209,7 +259,9 @@ class PrefixCache:
 
     Entries hold host-side copies ``(k_stack, v_stack, prompt_len,
     last_logits)`` with ``k_stack``/``v_stack`` of shape (layers, heads,
-    padded_prompt_len, head_dim) — exact dtypes (bf16 stays bf16). A hit
+    padded_prompt_len, head_dim) — exact dtypes (bf16 stays bf16); where
+    the layers' pages differ in length (window rings beside full pages) a
+    stack is a tuple of one (heads, length, head_dim) array a layer. A hit
     skips the whole-prompt forward: the stored pages are injected into the
     request's slot by a tiny compiled program and the first token is
     sampled from the stored logits with the request's own key/temperature
@@ -240,7 +292,7 @@ class PrefixCache:
 
     def put(self, tokens, k_stack, v_stack, prompt_len, last_logits):
         self._store[self.key(tokens)] = (
-            np.asarray(k_stack), np.asarray(v_stack), int(prompt_len),
+            _host(k_stack), _host(v_stack), int(prompt_len),
             np.asarray(last_logits))
 
     def __len__(self):

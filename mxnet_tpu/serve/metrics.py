@@ -27,6 +27,8 @@ from __future__ import annotations
 
 import threading
 
+import numpy as np
+
 from .. import profiler
 
 
@@ -203,6 +205,13 @@ def _ring_percentiles(ring, n, prefix):
     return out
 
 
+def _max_over_mean(load):
+    """The largest expert's load over the mean load, the worst layer's, of
+    a (layers, experts) array of picks (1.0 where a layer has none)."""
+    mean = load.mean(axis=1)
+    return float(np.max(load.max(axis=1) / np.where(mean > 0, mean, 1.0)))
+
+
 class GenerativeMetrics(ServeMetrics):
     """ServeMetrics plus the token-level counters autoregressive serving
     is judged by: tokens/s (over decode-active wall time, not idle time),
@@ -238,6 +247,13 @@ class GenerativeMetrics(ServeMetrics):
         # medians (each bucket gets its own ring → per-bucket percentiles
         # under `bucket=` labels in /metrics)
         self._ttft_by_bucket = {}           # bucket(int) -> [ring, n]
+        # a routing model (the server's spec ``routed``): picks that went
+        # to each expert held, by layer, those that went to experts held
+        # elsewhere, and the last decode step's imbalance
+        self._expert_load = None            # (layers, held) int64
+        self.picks_elsewhere = 0
+        self._xmax = None
+        self._xhit = 0
 
     @staticmethod
     def _pow2_bucket(n):
@@ -289,6 +305,32 @@ class GenerativeMetrics(ServeMetrics):
             self._active_slot_steps += int(n_active)
             self._slot_steps += int(slots)
 
+    def record_expert_load(self, load, tag=False):
+        """``load`` (layers, held + 1) int: the picks one prefill or decode
+        step sent to each expert held and, last, to experts held elsewhere.
+        With ``tag`` (a decode step's, while the profiler runs) it also sets
+        what ``expert_tag`` reports: the largest expert's load over the mean
+        load, the worst layer's, and the number of (layer, expert) pairs
+        that got a pick."""
+        load = np.asarray(load, np.int64)
+        here = load[:, :-1]
+        with self._lock:
+            if self._expert_load is None:
+                self._expert_load = np.zeros_like(here)
+            self._expert_load += here
+            self.picks_elsewhere += int(load[:, -1].sum())
+            if tag:
+                self._xmax = _max_over_mean(here)
+                self._xhit = int((here > 0).sum())
+
+    def expert_tag(self):
+        """``xmax=<...> xhit=<...>`` of the last traced decode step for its
+        successor's span (profiler.decode_scope), or None before the first:
+        the imbalance, and how many (layer, expert) pairs got a pick, which
+        is how many experts' matrices the step had to read."""
+        x = self._xmax
+        return None if x is None else "xmax=%.2f xhit=%d" % (x, self._xhit)
+
     def record_spec_round(self, drafted, accepted):
         with self._lock:
             self.spec_rounds += 1
@@ -315,6 +357,14 @@ class GenerativeMetrics(ServeMetrics):
                                       / self.drafted_tokens, 4)
                                 if self.drafted_tokens else None),
             })
+            if self._expert_load is not None:
+                here = self._expert_load
+                snap.update({
+                    "expert_picks_here": int(here.sum()),
+                    "expert_picks_elsewhere": self.picks_elsewhere,
+                    "expert_load": here.tolist(),
+                    "expert_load_max_over_mean": _max_over_mean(here),
+                })
             snap.update(_ring_percentiles(
                 self._ttft, min(self._ttft_n, self._window), "ttft"))
             snap.update(_ring_percentiles(

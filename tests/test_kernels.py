@@ -451,6 +451,7 @@ def _kv_gate_case(case):
     S, H = 4, 2
     C, D, T = {"window_of_4": (256, 16, 4), "capacity_64": (64, 16, 1),
                "head_dim_128": (256, 128, 1),
+               "head_dim_192": (256, 192, 1),
                "head_dim_off_the_sublane_tile": (256, 12, 1),
                }.get(case, (256, 16, 1))
     index = (jnp.int32(9) if case == "scalar_index"
@@ -459,22 +460,25 @@ def _kv_gate_case(case):
 
 
 @pytest.mark.parametrize("case", [
-    "scalar_index", "window_of_4", "capacity_64", "head_dim_128",
+    "scalar_index", "window_of_4", "capacity_64", "head_dim_192",
     "head_dim_off_the_sublane_tile", "under_a_mesh", "on_the_cpu",
-    "tpu_and_tiles"])
+    "tpu_and_tiles", "head_dim_128"])
 def test_cache_write_gate(monkeypatch, case):
     """``cache_write`` decides at trace time, from what it can see: only a
     per-slot index with one token a slot, shapes that tile, a TPU and no
-    device mesh reach the kernel; every other call keeps today's path."""
+    device mesh reach the kernel (head widths under a lane tile by the
+    column path, whole lane tiles such as 128 by the row path); every other
+    call keeps today's path."""
     from mxnet_tpu import parallel
     from mxnet_tpu.ops import attention as A
     from mxnet_tpu.ops.pallas import kv_write
 
     calls = []
+    reaches = case in ("tpu_and_tiles", "head_dim_128")
 
     def kernel(cache, update, index):
         calls.append(cache.shape)
-        if case != "tpu_and_tiles":
+        if not reaches:
             raise AssertionError("%s reached the kernel" % case)
         return kv_write_orig(cache, update, index, interpret=True)
 
@@ -496,7 +500,7 @@ def test_cache_write_gate(monkeypatch, case):
     else:
         want = _vmap_dus(cache, update, index)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert len(calls) == (1 if case == "tpu_and_tiles" else 0)
+    assert len(calls) == (1 if reaches else 0)
 
 
 def test_server_tokens_same_with_the_kv_write_kernel(monkeypatch):

@@ -130,10 +130,16 @@ def test_flash_valid_len_compiles_fwd_bwd(one_chip):
 @pytest.mark.parametrize("shape,dtype", [
     ((8, 12, 1024, 64), jnp.bfloat16),     # GPT-2 small, the smoke's server
     ((32, 25, 1024, 64), jnp.float32),     # GPT-2 XL's heads, fp32 pages
-    ((160, 4, 128, 16), jnp.bfloat16)])    # more slots than lanes
+    ((160, 4, 128, 16), jnp.bfloat16),     # more slots than lanes
+    ((32, 8, 4096, 128), jnp.bfloat16),    # head width 128: a window ring
+    ((32, 8, 8192, 128), jnp.float32)])    # ... and a full page, fp32
 def test_kv_cache_write_compiles_in_place(one_chip, shape, dtype):
     """The K/V column write alone: Mosaic takes the blocks and the lane
-    rotate (of 16-bit values too), and the donated buffer is the result."""
+    rotate (of 16-bit values too; at head width 128 the row blocks and the
+    sublane select), the donated buffer is the result, and no ``while``
+    (the scatter's loop over the slots) is left."""
+    import re
+
     from mxnet_tpu.ops.pallas import kv_write
 
     S, H, C, D = shape
@@ -143,9 +149,47 @@ def test_kv_cache_write_compiles_in_place(one_chip, shape, dtype):
         jax.ShapeDtypeStruct((S, H, 1, D), dtype, sharding=one_chip),
         jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip)).compile()
     assert "kv_cache_write" in compiled.as_text()
+    assert not re.search(r" while\(", compiled.as_text())
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == S * H * C * D * jnp.dtype(dtype).itemsize
     assert mem.temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("seq,window", [(1024, 4096), (8192, 4096),
+                                        (8192, None)])
+def test_flash_grouped_window_compiles(one_chip, seq, window):
+    """The forward kernel with 128 query heads on 8 K/V heads of width 128,
+    with and without the 4096 window, at the served model's prefill
+    buckets."""
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = jax.ShapeDtypeStruct((1, 128, seq, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8, seq, 128), jnp.bfloat16,
+                              sharding=one_chip)
+    text = _compile(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=window), q, kv, kv)
+    assert "flash_fwd" in text
+
+
+@pytest.mark.parametrize("rows,tile", [(512, 16), (20480, 256)])
+def test_moe_ffn_compiles_at_the_served_widths(one_chip, rows, tile):
+    """The grouped expert FFN at width 4096 x 4096, 16 experts held: a
+    decode step's 16-row tiles and a prefill chunk's 256-row tiles. Mosaic
+    takes the blocks and the raised VMEM limit; nothing expert-sized is
+    allocated beside the operands."""
+    from mxnet_tpu.ops.pallas import moe_ffn as K
+
+    assert K.tiles(tile, 4096, 4096, jnp.bfloat16)
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    w = on((16, 4096, 4096), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda x, te, tv, wg, wu, wd: K.moe_ffn(x, te, tv, wg, wu, wd, tile)
+    ).lower(on((rows, 4096), jnp.bfloat16), on((rows // tile,), jnp.int32),
+            on((rows // tile,), jnp.int32), w, w, w).compile()
+    assert "moe_ffn" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_decode_step_writes_kv_in_place_with_the_kernel(one_chip,

@@ -41,7 +41,10 @@ import numpy as np
 from .store import (CompCacheStore, fingerprint, load_compiled_entry,
                     pack_entry, serialize_compiled)
 
-FORMAT = 1
+# 2: the generative programs take the cache's state as one pytree of page
+# records (serve/kv_cache.py), not lists of K and V buffers; an executable
+# of format 1 would be called with the wrong argument tree
+FORMAT = 2
 
 
 def _warn(msg):

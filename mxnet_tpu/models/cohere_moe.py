@@ -23,10 +23,11 @@ the cache geometry layer by layer: ``kv_heads`` K/V heads a buffer, and
 ``windows``: a ring of ``sliding_window`` positions for the window layers
 (written at ``position % ring length``), a full page for the others. A ring
 holds exactly the positions its layer may see, so one mask (``slot <=
-position``) serves both kinds. ``routed`` says that prefill and step take
-the live rows (pad rows and free slots route nowhere) and return the
-experts' load beside the caches. The int8, speculative and chunked-prefill
-protocols are not implemented: the server refuses those options by name.
+position``) serves both kinds. ``routed`` is the shape of the experts'
+load, which prefill and step return as their ``aux`` (pad rows and free
+slots route nowhere). The spec names neither ``int8_pages`` nor
+``multi_token`` (``decode_step`` takes plain pages and one token a slot),
+so the server refuses ``quantize``, ``draft`` and ``prefill_chunk`` by name.
 """
 from __future__ import annotations
 
@@ -35,6 +36,7 @@ import numpy as np
 from .. import initializer as init_mod
 from ..gluon import nn
 from ..gluon.block import HybridBlock, param_value
+from ..serve.kv_cache import PlainPage
 
 __all__ = ["CohereMoEModel", "cohere_moe_nano"]
 
@@ -101,11 +103,11 @@ class _GroupedAttention(HybridBlock):
                                      window=self._window)
         return self._merge(F, out), k, v
 
-    def step_cached(self, F, h, k_cache, v_cache, position, active):
+    def step_cached(self, F, h, page, position, active):
         """One token a row (``h`` (B, 1, C)) at per-row ``position`` (B,)
-        against this layer's buffer (B, kv_heads, L, D): the new K/V go to
-        slot ``position % L`` (a ring wraps; a full page is longer than any
-        position), and every slot at or before the position is live: a
+        against this layer's ``PlainPage`` (B, kv_heads, L, D): the new K/V
+        go to slot ``position % L`` (a ring wraps; a full page is longer
+        than any position), and every slot at or before the position is live: a
         wrapped ring holds exactly the window. So a row reads the first
         ``min(position + 1, L)`` slots of its buffer, and none where
         ``active`` (B,) 0/1 is 0. The read is ``F.cached_attention``, which
@@ -115,14 +117,14 @@ class _GroupedAttention(HybridBlock):
         the 16 query heads of a group, and only those that hold a live
         slot), which is built, tested and shut at the op's gate
         (``ops/attention.py: _DECODE_ROW_PATH`` says why)."""
-        L = k_cache.shape[2]
+        L = page.k.shape[2]
         q, k, v = self._qkv(F, h, F.reshape(position, shape=(-1, 1)))
         at = position % L
-        k_cache = F.cache_write(k_cache, k, at)
-        v_cache = F.cache_write(v_cache, v, at)
+        page = PlainPage(F.cache_write(page.k, k, at),
+                         F.cache_write(page.v, v, at))
         lengths = F.minimum(position + 1, L) * active
-        out = F.cached_attention(q, k_cache, v_cache, lengths)
-        return self._merge(F, out), k_cache, v_cache
+        out = F.cached_attention(q, page.k, page.v, lengths)
+        return self._merge(F, out), page
 
 
 class _MoEBlock(HybridBlock):
@@ -184,12 +186,11 @@ class _MoEBlock(HybridBlock):
         routed, shared, load = self._moe(F, h, live)
         return self._sum(F, x, a, routed, shared), k, v, load
 
-    def step_cached(self, F, x, k_cache, v_cache, position, live):
+    def step_cached(self, F, x, page, position, live):
         h = self.ln(x)
-        a, k_cache, v_cache = self.attn.step_cached(F, h, k_cache, v_cache,
-                                                    position, live)
+        a, page = self.attn.step_cached(F, h, page, position, live)
         routed, shared, load = self._moe(F, h, live)
-        return self._sum(F, x, a, routed, shared), k_cache, v_cache, load
+        return self._sum(F, x, a, routed, shared), page, load
 
 
 class CohereMoEModel(HybridBlock):
@@ -257,7 +258,7 @@ class CohereMoEModel(HybridBlock):
         and V buffers are (slots, ``kv_heads``, L_i, ``head_dim``) with L_i
         the capacity, or ``min(capacity, windows[i])`` for a ring;
         ``routed`` (layers, experts held + 1) is the shape of the load
-        array that prefill and step return after the caches."""
+        array that prefill and step return as ``aux``."""
         return {"layers": len(self.blocks), "heads": self._heads,
                 "kv_heads": self._kv_heads, "head_dim": self._head_dim,
                 "windows": list(self._windows),
@@ -285,22 +286,18 @@ class CohereMoEModel(HybridBlock):
             x = F.take(x, F.reshape(plen - 1, shape=(1,)), axis=1)
         return self._lm_logits(F, x), kvs, F.stack(*loads)
 
-    def decode_step_fixed(self, F, tokens, k_caches, v_caches, valid_len,
-                          active):
-        """One token a slot at per-slot positions ``valid_len``; ``active``
+    def decode_step(self, F, tokens, state, valid_len, active):
+        """One token a slot (``tokens`` (B, 1)) at per-slot positions
+        ``valid_len`` over ``state``, one ``PlainPage`` a layer; ``active``
         (B,) marks the live slots (a free slot routes nowhere). Returns
-        (logits (B, V), new K buffers, new V buffers, load)."""
-        x = self.word_embed(F.reshape(tokens, shape=(-1, 1)))  # (B, 1, C)
-        nk, nv, loads = [], [], []
-        for blk, kc, vc in zip(self.blocks, k_caches, v_caches):
-            x, kc, vc, load = blk.step_cached(F, x, kc, vc, valid_len,
-                                              active)
-            nk.append(kc)
-            nv.append(vc)
+        (logits (B, 1, V), the state written, load)."""
+        x = self.word_embed(tokens)                            # (B, 1, C)
+        new, loads = [], []
+        for blk, page in zip(self.blocks, state):
+            x, page, load = blk.step_cached(F, x, page, valid_len, active)
+            new.append(page)
             loads.append(load)
-        logits = self._lm_logits(F, x)
-        return (F.reshape(logits, shape=(logits.shape[0], -1)), nk, nv,
-                F.stack(*loads))
+        return self._lm_logits(F, x), new, F.stack(*loads)
 
 
 def cohere_moe_nano(vocab_size=256, **kwargs):

@@ -11,7 +11,7 @@ writes in place via ``F.cache_write`` with attention masked to the live
 prefix, so no shape ever changes across decode steps (the old growing
 (B, H, t, D) time axis retraced any compiled consumer every token —
 graphlint GL007). ``prefill`` fills the cache from the whole prompt in ONE
-forward pass; ``decode_step_fixed`` is the per-slot-position step the
+forward pass; ``decode_step`` is the per-slot-position step the
 ``serve.GenerativeServer`` continuous-batching scheduler traces into one
 fused program. All widths multiples of 128 at base size for MXU tiling;
 param names follow parallel.tensor_parallel.TRANSFORMER_RULES so the model
@@ -25,6 +25,7 @@ from .. import initializer as init_mod
 from ..base import next_pow2
 from ..gluon import nn
 from ..gluon.block import HybridBlock, param_value
+from ..serve.kv_cache import Int8Page, PlainPage
 
 __all__ = ["GPTModel", "gpt2_small", "gpt_nano"]
 
@@ -71,61 +72,62 @@ class _CausalSelfAttention(HybridBlock):
     def hybrid_forward(self, F, x):
         return self.forward_kv(F, x)[0]
 
-    def step_cached(self, F, x, k_cache, v_cache, start, lengths=None):
-        """Decode against the fixed-capacity cache: ``x`` (B, T, C) holds
-        the next T tokens (T=1 in steady-state decode), whose K/V are
-        written IN PLACE at time offset ``start`` via ``F.cache_write``
-        (its docstring says what each kind of ``start`` lowers to);
-        attention reads the live prefix through ``F.cached_attention``:
-        row ``t`` sees the positions ``[0, lengths + t)``, ``lengths`` by
-        default ``start + 1``. ``start`` is a python int (uniform
-        imperative decode) or a (B,) per-slot position vector (continuous
-        batching, where the server hands ``lengths`` with 0 for a free
-        slot). What the read lowers to: with per-slot lengths and T = 1 on
-        a TPU the Pallas kernel ``decode_attention`` (this model's head
-        widths take its column path: only the 128-position blocks that
-        hold a slot's live positions are fetched, none for a free slot);
-        with an int ``start``, T > 1 (speculative verify, chunked
-        prefill), under a mesh or off the TPU the dense masked attention
-        over all slots x capacity. Cache shapes never change across steps
-        — the whole point. Returns (out (B, T, C), k_cache', v_cache')."""
-        q, k_new, v_new = self._qkv_heads(F, x)
-        k_cache = F.cache_write(k_cache, k_new, start)
-        v_cache = F.cache_write(v_cache, v_new, start)
-        if lengths is None:
-            lengths = start + 1
-        out = F.cached_attention(q, k_cache, v_cache, lengths)
-        return self.attn_out(self._merge_heads(F, out)), k_cache, v_cache
+    def step_cached(self, F, x, page, start, lengths=None):
+        """Decode against one layer's fixed-capacity ``page`` (a record of
+        ``serve.kv_cache``): ``x`` (B, T, C) holds the next T tokens (T=1
+        in steady-state decode), whose K/V are written IN PLACE at time
+        offset ``start``; row ``t`` then attends to the positions ``[0,
+        lengths + t)``, ``lengths`` by default ``start + 1``. ``start`` is a
+        python int (uniform imperative decode) or a (B,) per-slot position
+        vector (continuous batching, where the server hands ``lengths``
+        with 0 for a free slot). The page's type decides the arithmetic, at
+        trace time:
 
-    def step_cached_quant(self, F, x, k_cache, k_scale, v_cache, v_scale,
-                          start):
-        """:meth:`step_cached` against int8 KV pages: new K/V quantize on
-        write and the fused write+read (``F.quant_cache_write_read``,
-        running per-page-per-head scale) hands attention the fp32 pages
-        directly from the pre-quantization values — no full-page
-        int8→fp32 convert per layer per step (the hlolint GL024 churn the
-        unfused quant_cache_write + dequant_cache pair pays). The cache
-        lives in HBM at half the bf16 bytes while shapes stay
-        step-invariant. Returns (out, k_cache', k_scale', v_cache',
-        v_scale')."""
-        B, T, C = x.shape
+        - a ``PlainPage`` is written by ``F.cache_write`` (its docstring
+          says what each kind of ``start`` lowers to) and read by
+          ``F.cached_attention``: with per-slot lengths and T = 1 on a TPU
+          the Pallas kernel ``decode_attention`` (this model's head widths
+          take its column path: only the 128-position blocks that hold a
+          slot's live positions are fetched, none for a free slot); with
+          an int ``start``, T > 1 (speculative verify, chunked prefill),
+          under a mesh or off the TPU the dense masked attention over all
+          slots x capacity;
+        - an ``Int8Page`` quantizes on write, and the fused write+read
+          (``F.quant_cache_write_read``, running per-page-per-head scale)
+          hands attention the fp32 pages directly from the
+          pre-quantization values — no full-page int8→fp32 convert per
+          layer per step (the hlolint GL024 churn the unfused
+          quant_cache_write + dequant_cache pair pays). Every row reads
+          densely through the mask ``position <= start + t``, whatever
+          ``lengths`` says: the whole page is dequantized already.
+
+        Cache shapes never change across steps — the whole point. Returns
+        (out (B, T, C), the page written)."""
         q, k_new, v_new = self._qkv_heads(F, x)
-        k_cache, k_scale, k_deq = F.quant_cache_write_read(
-            k_cache, k_scale, k_new, start)
-        v_cache, v_scale, v_deq = F.quant_cache_write_read(
-            v_cache, v_scale, v_new, start)
-        cap = k_cache.shape[2]
-        pos = F.reshape(F.arange(0, cap, dtype="int32"),
-                        shape=(1, 1, 1, cap))
-        rows = F.reshape(F.arange(0, T, dtype="int32"), shape=(1, 1, T, 1))
-        if isinstance(start, int):
-            limit = rows + start
-        else:  # (B,) per-slot positions
-            limit = rows + F.reshape(start, shape=(-1, 1, 1, 1))
-        mask = F.lesser_equal(pos, limit)
-        out = F.scaled_dot_attention(q, k_deq, v_deq, mask)
-        return (self.attn_out(self._merge_heads(F, out)),
-                k_cache, k_scale, v_cache, v_scale)
+        if isinstance(page, Int8Page):
+            k_cache, k_scale, k_deq = F.quant_cache_write_read(
+                page.k, page.k_scale, k_new, start)
+            v_cache, v_scale, v_deq = F.quant_cache_write_read(
+                page.v, page.v_scale, v_new, start)
+            page = Int8Page(k_cache, k_scale, v_cache, v_scale)
+            T, cap = x.shape[1], k_cache.shape[2]
+            pos = F.reshape(F.arange(0, cap, dtype="int32"),
+                            shape=(1, 1, 1, cap))
+            rows = F.reshape(F.arange(0, T, dtype="int32"),
+                             shape=(1, 1, T, 1))
+            if isinstance(start, int):
+                limit = rows + start
+            else:  # (B,) per-slot positions
+                limit = rows + F.reshape(start, shape=(-1, 1, 1, 1))
+            out = F.scaled_dot_attention(q, k_deq, v_deq,
+                                         F.lesser_equal(pos, limit))
+        else:
+            page = PlainPage(F.cache_write(page.k, k_new, start),
+                             F.cache_write(page.v, v_new, start))
+            if lengths is None:
+                lengths = start + 1
+            out = F.cached_attention(q, page.k, page.v, lengths)
+        return self.attn_out(self._merge_heads(F, out)), page
 
     def step(self, x, cache):
         """One-token decode against the fixed-capacity ``(k, v, n)`` cache
@@ -134,8 +136,8 @@ class _CausalSelfAttention(HybridBlock):
         from .. import nd
 
         ks, vs, n = cache
-        out, ks, vs = self.step_cached(nd, x, ks, vs, n)
-        return out, (ks, vs, n + 1)
+        out, page = self.step_cached(nd, x, PlainPage(ks, vs), n)
+        return out, (page.k, page.v, n + 1)
 
 
 class _GPTBlock(HybridBlock):
@@ -168,23 +170,16 @@ class _GPTBlock(HybridBlock):
     def hybrid_forward(self, F, x):
         return self.forward_kv(F, x)[0]
 
-    def step_cached(self, F, x, k_cache, v_cache, start, lengths=None):
-        a, k_cache, v_cache = self.attn.step_cached(F, self.ln1(x), k_cache,
-                                                    v_cache, start, lengths)
-        return self._ffn(x + a), k_cache, v_cache
-
-    def step_cached_quant(self, F, x, k_cache, k_scale, v_cache, v_scale,
-                          start):
-        a, k_cache, k_scale, v_cache, v_scale = self.attn.step_cached_quant(
-            F, self.ln1(x), k_cache, k_scale, v_cache, v_scale, start)
-        return self._ffn(x + a), k_cache, k_scale, v_cache, v_scale
+    def step_cached(self, F, x, page, start, lengths=None):
+        a, page = self.attn.step_cached(F, self.ln1(x), page, start, lengths)
+        return self._ffn(x + a), page
 
     def step(self, x, cache):
         ks, vs, n = cache
         from .. import nd
 
-        out, ks, vs = self.step_cached(nd, x, ks, vs, n)
-        return out, (ks, vs, n + 1)
+        out, page = self.step_cached(nd, x, PlainPage(ks, vs), n)
+        return out, (page.k, page.v, n + 1)
 
 
 class GPTModel(HybridBlock):
@@ -245,11 +240,15 @@ class GPTModel(HybridBlock):
     def decode_state_spec(self):
         """Cache-shape contract for external decode schedulers
         (serve.GenerativeServer): per layer, K/V buffers are
-        (slots, heads, capacity, head_dim) of ``dtype``."""
+        (slots, heads, capacity, head_dim) of ``dtype``; what
+        :meth:`decode_step` takes beside plain pages and one token a slot:
+        ``int8_pages`` (the attention layer handles an ``Int8Page``) and
+        ``multi_token`` (K > 1: speculative verify, chunked prefill)."""
         H = self.blocks[0].attn._heads
         return {"layers": len(self.blocks), "heads": H,
                 "head_dim": self._units // H, "max_length": self._max_len,
-                "dtype": np.dtype(self.word_embed.weight.data().dtype)}
+                "dtype": np.dtype(self.word_embed.weight.data().dtype),
+                "int8_pages": True, "multi_token": True}
 
     def init_cache(self, batch_size, capacity=None, dtype=None):
         """Fixed-capacity decode cache: per layer ``(k, v, n)`` with k/v
@@ -271,16 +270,20 @@ class GPTModel(HybridBlock):
                  nd.zeros((batch_size, H, cap, D), dtype=dtype), 0)
                 for _ in range(len(self.blocks))]
 
-    def forward_collect_kv(self, F, tokens):
+    def forward_collect_kv(self, F, tokens, plen=None):
         """Forward pass that also returns every layer's projected K/V —
         the prefill primitive: one whole-prompt dispatch yields both the
-        next-token logits and the complete cache contents."""
+        next-token logits and the complete cache contents. ``plen`` (the
+        prompt's length inside its padded bucket) changes nothing for a
+        dense causal model: every row's logits come back and the caller
+        cuts row ``plen - 1``. Returns (logits (B, T, V), [(K, V) a layer],
+        None: nothing for the host to read behind the tokens)."""
         x = self._embed(F, tokens)
         kvs = []
         for blk in self.blocks:
             x, k, v = blk.forward_kv(F, x)
             kvs.append((k, v))
-        return self._lm_logits(F, x), kvs
+        return self._lm_logits(F, x), kvs, None
 
     def prefill(self, tokens, caches):
         """Whole-prompt cache fill: ONE forward pass computes every
@@ -292,7 +295,7 @@ class GPTModel(HybridBlock):
 
         B, T = tokens.shape
         self._check_len(T)
-        logits, kvs = self.forward_collect_kv(nd, tokens)
+        logits, kvs, _ = self.forward_collect_kv(nd, tokens)
         new = [(nd.cache_write(kc, k, 0), nd.cache_write(vc, v, 0), T)
                for (k, v), (kc, vc, _n) in zip(kvs, caches)]
         last = nd.reshape(nd.slice_axis(logits, axis=1, begin=T - 1, end=T),
@@ -311,128 +314,50 @@ class GPTModel(HybridBlock):
         x = x + nd.slice_axis(pw, axis=0, begin=position, end=position + 1)
         new_caches = []
         for blk, (ks, vs, _n) in zip(self.blocks, caches):
-            x, ks, vs = blk.step_cached(nd, x, ks, vs, position)
-            new_caches.append((ks, vs, position + 1))
+            x, page = blk.step_cached(nd, x, PlainPage(ks, vs), position)
+            new_caches.append((page.k, page.v, position + 1))
         x = self.ln_f(x)
         w = param_value(self.word_embed.weight)
         logits = nd.dot(nd.reshape(x, shape=(x.shape[0], self._units)),
                         nd.transpose(w))
         return logits, new_caches
 
-    def decode_step_fixed(self, F, tokens, k_caches, v_caches, valid_len,
-                          active=None):
-        """Continuous-batching decode step over PER-SLOT positions: tokens
-        (B,) int — each slot's current input token; ``k_caches``/
-        ``v_caches`` per-layer (B, H, capacity, D); ``valid_len`` (B,) —
-        tokens already cached per slot (= this token's position);
-        ``active`` (B,) 0/1 marks the live slots (all, where it is not
-        given): a free slot reads nothing of its page, and what it computes
-        is finite and discarded by the caller. Each slot's K/V is written
-        at its own position and attends to its own live prefix; returns
-        (logits (B, V), new k_caches, new v_caches). Pure and F-generic:
+    def decode_step(self, F, tokens, state, valid_len, active=None):
+        """The served decode step over PER-SLOT positions: tokens (B, K) int
+        — each slot's current input token, followed for K > 1 by K-1 more
+        (the drafted tokens of a speculative verify, a chunk of a prompt),
+        occupying positions ``valid_len .. valid_len+K-1`` of that slot's
+        page; ``state`` one page record a layer (``serve.kv_cache``; the
+        attention layer decides the arithmetic from its type);
+        ``valid_len`` (B,) — tokens already cached per slot; ``active``
+        (B,) 0/1 marks the live slots (all, where it is not given): a free
+        slot reads nothing of its page, and what it computes is finite and
+        discarded by the caller. Row j's K/V is written at ``valid_len+j``
+        and attends to the live prefix plus the rows before it, so
+        logits[:, j] scores the token at position valid_len+j+1, and row 0
+        of any K has the bits of the K = 1 call. Cache rollback after a
+        rejected draft is the caller's job and is free: advancing
+        ``valid_len`` by only the accepted length masks the dead suffix,
+        and the next window overwrites it in place. Returns (logits
+        (B, K, V), the state written, None). Pure and F-generic:
         serve.GenerativeServer traces it (with sampling fused behind it)
-        into ONE cached XLA program per step."""
-        x = self.word_embed(F.reshape(tokens, shape=(-1, 1)))  # (B, 1, C)
-        pw = param_value(self.pos_embed.weight)
-        x = x + F.expand_dims(F.take(pw, valid_len), axis=1)
+        into ONE cached XLA program per kind of step."""
+        K = tokens.shape[1]
+        x = self.word_embed(tokens)                        # (B, K, C)
+        pos = F.expand_dims(valid_len, axis=1)
+        if K > 1:
+            pos = pos + F.reshape(F.arange(0, K, dtype="int32"),
+                                  shape=(1, -1))
+        x = x + F.take(param_value(self.pos_embed.weight), pos)
         lengths = valid_len + 1
         if active is not None:
             lengths = lengths * active
-        nk, nv = [], []
-        for blk, kc, vc in zip(self.blocks, k_caches, v_caches):
-            x, kc, vc = blk.step_cached(F, x, kc, vc, valid_len, lengths)
-            nk.append(kc)
-            nv.append(vc)
-        x = self.ln_f(x)
-        w = param_value(self.word_embed.weight)
-        logits = F.dot(F.reshape(x, shape=(x.shape[0], self._units)),
-                       F.transpose(w))
-        return logits, nk, nv
-
-    def decode_step_speculative(self, F, tokens, k_caches, v_caches,
-                                valid_len):
-        """Speculative verify step: tokens (B, K) int — each slot's current
-        input token followed by K-1 drafted tokens, occupying positions
-        ``valid_len .. valid_len+K-1`` of that slot's cache. One wide
-        dispatch scores all K positions: row j's K/V is written at
-        ``valid_len+j`` (the per-row ``F.cache_write`` window) and attends
-        to the live prefix plus the draft prefix ``pos <= valid_len+j`` —
-        exactly the mask :meth:`_CausalSelfAttention.step_cached` already
-        builds for a (B,) ``start`` with T=K. Returns (logits (B, K, V),
-        new k_caches, new v_caches); logits[:, j] scores the token at
-        position valid_len+j+1, i.e. drafted token j+1. K=1 is
-        bit-identical to :meth:`decode_step_fixed`. Cache rollback after
-        rejection is the caller's job and is free: advancing ``valid_len``
-        by only the accepted length masks the dead suffix, and the next
-        window overwrites it in place."""
-        B, K = tokens.shape
-        x = self.word_embed(tokens)                        # (B, K, C)
-        pw = param_value(self.pos_embed.weight)
-        pos = (F.reshape(valid_len, shape=(-1, 1))
-               + F.reshape(F.arange(0, K, dtype="int32"), shape=(1, -1)))
-        x = x + F.take(pw, pos)                            # (B, K, C)
-        nk, nv = [], []
-        for blk, kc, vc in zip(self.blocks, k_caches, v_caches):
-            x, kc, vc = blk.step_cached(F, x, kc, vc, valid_len)
-            nk.append(kc)
-            nv.append(vc)
-        x = self.ln_f(x)
-        w = param_value(self.word_embed.weight)
-        logits = F.dot(F.reshape(x, shape=(B * K, self._units)),
-                       F.transpose(w))
-        return F.reshape(logits, shape=(B, K, -1)), nk, nv
-
-    def decode_step_speculative_quant(self, F, tokens, k_caches, k_scales,
-                                      v_caches, v_scales, valid_len):
-        """:meth:`decode_step_speculative` over int8 KV pages (same scale
-        plumbing as :meth:`decode_step_fixed_quant`). Returns (logits
-        (B, K, V), new k_caches, new k_scales, new v_caches,
-        new v_scales)."""
-        B, K = tokens.shape
-        x = self.word_embed(tokens)                        # (B, K, C)
-        pw = param_value(self.pos_embed.weight)
-        pos = (F.reshape(valid_len, shape=(-1, 1))
-               + F.reshape(F.arange(0, K, dtype="int32"), shape=(1, -1)))
-        x = x + F.take(pw, pos)                            # (B, K, C)
-        nk, nks, nv, nvs = [], [], [], []
-        for blk, kc, ks, vc, vs in zip(self.blocks, k_caches, k_scales,
-                                       v_caches, v_scales):
-            x, kc, ks, vc, vs = blk.step_cached_quant(F, x, kc, ks, vc, vs,
-                                                      valid_len)
-            nk.append(kc)
-            nks.append(ks)
-            nv.append(vc)
-            nvs.append(vs)
-        x = self.ln_f(x)
-        w = param_value(self.word_embed.weight)
-        logits = F.dot(F.reshape(x, shape=(B * K, self._units)),
-                       F.transpose(w))
-        return F.reshape(logits, shape=(B, K, -1)), nk, nks, nv, nvs
-
-    def decode_step_fixed_quant(self, F, tokens, k_caches, k_scales,
-                                v_caches, v_scales, valid_len):
-        """:meth:`decode_step_fixed` over int8 KV pages with per-page-per-
-        head scales (``k_scales``/``v_scales`` per-layer (B, H, 1, 1) fp32).
-        Same per-slot-position semantics, same step-invariant shapes — one
-        compiled program per capacity; returns (logits, new k_caches,
-        new k_scales, new v_caches, new v_scales)."""
-        x = self.word_embed(F.reshape(tokens, shape=(-1, 1)))  # (B, 1, C)
-        pw = param_value(self.pos_embed.weight)
-        x = x + F.expand_dims(F.take(pw, valid_len), axis=1)
-        nk, nks, nv, nvs = [], [], [], []
-        for blk, kc, ks, vc, vs in zip(self.blocks, k_caches, k_scales,
-                                       v_caches, v_scales):
-            x, kc, ks, vc, vs = blk.step_cached_quant(F, x, kc, ks, vc, vs,
-                                                      valid_len)
-            nk.append(kc)
-            nks.append(ks)
-            nv.append(vc)
-            nvs.append(vs)
-        x = self.ln_f(x)
-        w = param_value(self.word_embed.weight)
-        logits = F.dot(F.reshape(x, shape=(x.shape[0], self._units)),
-                       F.transpose(w))
-        return logits, nk, nks, nv, nvs
+        new = []
+        for blk, page in zip(self.blocks, state):
+            x, page = blk.step_cached(F, x, page, valid_len, lengths)
+            new.append(page)
+        w = param_value(self.word_embed.weight)            # (V, C) tied head
+        return F.dot(self.ln_f(x), F.transpose(w)), new, None
 
     def generate(self, prompt, max_new_tokens=16, use_cache=True):
         """Greedy decode. prompt (B, T0) int → (B, T0 + max_new) int.

@@ -50,6 +50,7 @@ import jax.numpy as jnp
 from .. import _trace, engine, profiler
 from ..base import next_pow2
 from .batcher import DynamicBatcher, ServeError, ServeTimeout
+from . import kv_cache
 from .kv_cache import CacheError, PagedKVCache, PrefixCache
 from .metrics import GenerativeMetrics
 
@@ -184,21 +185,40 @@ class GenerativeServer:
 
     Parameters
     ----------
-    model : block implementing the fixed-capacity decode protocol
-        ``decode_state_spec()``, ``forward_collect_kv(F, tokens)`` and
-        ``decode_step_fixed(F, tokens, k_caches, v_caches, valid_len,
-        active)`` (``active`` (slots,) 0/1: a free slot reads nothing of
-        its page; ``models.gpt.GPTModel`` is the reference implementation).
-        Must be initialized; its parameter dtype decides the cache dtype.
-        The spec may give the cache geometry layer by layer: ``kv_heads``
-        (K/V heads a buffer, under grouped-query attention), ``windows``
-        (a ring length for each sliding-window layer, ``None`` for a full
-        page), and ``routed`` (layers, columns): the model routes tokens to
-        experts, so its ``forward_collect_kv`` takes the prompt's length
-        (pad rows route nowhere; it returns the last live row's logits),
-        a free slot of its ``decode_step_fixed`` routes nowhere, and both
-        return an int32 load array of that shape after the caches
-        (``models.cohere_moe.CohereMoEModel``).
+    model : block implementing the served decode protocol, three methods
+        (``models.gpt.GPTModel`` is the reference implementation;
+        ``models.cohere_moe.CohereMoEModel`` routes). Must be initialized;
+        its parameter dtype decides the cache dtype.
+
+        ``decode_state_spec()``: the cache geometry (``layers``, ``heads``,
+        ``head_dim``, ``max_length``, ``dtype``; layer by layer
+        ``kv_heads``, the K/V heads a buffer under grouped-query attention,
+        and ``windows``, a ring length for each sliding-window layer,
+        ``None`` for a full page) and what the model's step can take:
+        ``int8_pages`` (int8 pages: ``quantize``), ``multi_token`` (more
+        than one token a slot: ``draft``, ``prefill_chunk``), ``page`` (a
+        page record of the model's own), ``routed`` (the shape of the
+        ``aux`` array below, for ``stats()``).
+
+        ``forward_collect_kv(F, tokens, plen) -> (logits, kvs, aux)``: the
+        prefill. ``tokens`` (1, bucket) holds a prompt of ``plen`` tokens;
+        ``kvs`` is one (K, V) a layer as the pages keep them; ``logits``
+        every row's, or row ``plen - 1`` alone (1, 1, V).
+
+        ``decode_step(F, tokens, state, valid_len, active) -> (logits,
+        state, aux)``: ``tokens`` (slots, K): K = 1 is the decode step,
+        ``spec_k`` the verify window, the chunk the chunked prefill;
+        ``valid_len`` (slots,) the tokens cached; ``active`` (slots,) 0/1:
+        a free slot reads nothing of its page.
+
+        ``state`` is the cache's: one page record a layer
+        (``serve/kv_cache.py``: ``PlainPage``, ``Int8Page``), whose type
+        says its format. The server carries it from program to program
+        (donated) and never looks inside: the model's attention layer reads
+        and writes a page, the record's own methods move a prompt or a slot
+        in and out of it. ``aux`` is ``None`` or an array the host reads
+        behind the tokens (a routing model's expert load: pad rows and free
+        slots route nowhere).
     slots : int
         In-flight request pages — the padded decode batch. One decode
         dispatch serves all of them; free slots are masked, so join/leave
@@ -230,14 +250,15 @@ class GenerativeServer:
         with MXU accumulation) AND store KV pages as int8 with per-page-
         per-head scales. Decode stays ONE dispatch per token step with
         zero steady-state retrace; the cache costs ~0.5× the bf16 bytes.
-        The model must implement ``decode_step_fixed_quant`` (GPTModel
-        does). fp8 modes require :func:`quantization.fp8_supported`.
+        The model's spec must say ``int8_pages`` (GPTModel's does). fp8
+        modes require :func:`quantization.fp8_supported`.
     draft : None, speculative draft object, or a draft model
         Enables speculative decode: per scheduler tick the draft proposes
         ``spec_k - 1`` tokens per slot and the target scores the whole
-        window in ONE wide verify dispatch (``decode_step_speculative``),
-        emitting 1..spec_k tokens. Pass ``serve.NGramDraft()`` (host-side
-        pattern matcher, zero extra dispatches), ``serve.ModelDraft(m)``
+        window in ONE wide verify dispatch (``decode_step`` with K =
+        ``spec_k``), emitting 1..spec_k tokens. Pass ``serve.NGramDraft()``
+        (host-side pattern matcher, zero extra dispatches),
+        ``serve.ModelDraft(m)``
         (a smaller same-API model, one multi-step dispatch per round), or
         a bare model (wrapped in ``ModelDraft``). Greedy streams emit
         byte-identical tokens to plain greedy decode; sampled streams emit
@@ -264,19 +285,19 @@ class GenerativeServer:
                  metrics_port=None, quantize=None, draft=None, spec_k=4,
                  prefill_chunk=None):
         self._quantize = quantize or None
+        spec = model.decode_state_spec()
         if self._quantize is not None:
-            if not hasattr(model, "decode_step_fixed_quant"):
+            if not spec.get("int8_pages"):
                 raise ServeError(
-                    "quantize=%r: model %s has no decode_step_fixed_quant — "
-                    "the int8 paged-KV decode protocol (see models.gpt."
-                    "GPTModel)" % (quantize, type(model).__name__))
+                    "quantize=%r: model %s serves no int8 pages (its "
+                    "decode_state_spec() does not say int8_pages; see "
+                    "models.gpt.GPTModel)" % (quantize, type(model).__name__))
             from ..quantization import quantize_model
 
             # weight quantization BEFORE the param-list capture below so
             # the serving param store carries qweight/w_scale pages;
             # idempotent on an already-quantized model (snapshot load)
             quantize_model(model, mode=self._quantize)
-        spec = model.decode_state_spec()
         self.model = model
         self.name = name or ("generate:%s" % type(model).__name__.lower())
         self.slots = int(slots)
@@ -293,9 +314,9 @@ class GenerativeServer:
             spec["layers"], spec.get("kv_heads", spec["heads"]),
             spec["head_dim"], self.slots, spec["max_length"],
             dtype=spec["dtype"], quantize=self._quantize is not None,
-            windows=spec.get("windows"))
+            windows=spec.get("windows"), page=spec.get("page"))
         # (layers, columns) of the expert-load array a routing model's
-        # programs return; None for a dense model
+        # programs return as their aux; None for a dense model
         self._routed = spec.get("routed")
         self.prefix = PrefixCache() if prefix_cache else None
         self.metrics = GenerativeMetrics(self.name)
@@ -323,16 +344,12 @@ class GenerativeServer:
             draft = ModelDraft(draft)   # a bare model: wrap it
         self._draft = draft
         if self._draft is not None:
-            if self._quantize is not None and not hasattr(
-                    model, "decode_step_speculative_quant"):
+            if not spec.get("multi_token"):
                 raise ServeError(
-                    "draft + quantize: model %s has no decode_step_"
-                    "speculative_quant" % type(model).__name__)
-            if not hasattr(model, "decode_step_speculative"):
-                raise ServeError(
-                    "draft: model %s has no decode_step_speculative — the "
-                    "wide-window verify protocol (see models.gpt.GPTModel)"
-                    % type(model).__name__)
+                    "draft: model %s takes one token a slot (its "
+                    "decode_state_spec() does not say multi_token) — the "
+                    "verify window is decode_step with K = spec_k (see "
+                    "models.gpt.GPTModel)" % type(model).__name__)
             self._draft.bind(self)
         # speculation windows write K/V through valid+spec_k-1: capacity
         # sizing must leave that margin past the generation budget or the
@@ -344,10 +361,11 @@ class GenerativeServer:
         # decode until their final chunk lands
         self._prefill_chunk = None
         if prefill_chunk is not None:
-            if not hasattr(model, "decode_step_speculative"):
+            if not spec.get("multi_token"):
                 raise ServeError(
-                    "prefill_chunk: model %s has no decode_step_speculative "
-                    "— chunks run through the wide-window protocol (see "
+                    "prefill_chunk: model %s takes one token a slot (its "
+                    "decode_state_spec() does not say multi_token) — a "
+                    "chunk is decode_step with K = the chunk (see "
                     "models.gpt.GPTModel)" % type(model).__name__)
             self._prefill_chunk = next_pow2(max(8, int(prefill_chunk)))
             if self._draft is not None and self._prefill_chunk < self.spec_k:
@@ -693,54 +711,34 @@ class GenerativeServer:
         if span is not None:
             span["kind"] = "inject" if hit is not None else "prefill"
         engine.dispatch_counter.bump()
+        c = self.cache
         scope = (profiler.decode_scope("prefill%d" % tp, self.slots,
-                                       self.cache.num_active)
+                                       c.num_active)
                  if tracing else None)
         try:
             if scope is not None:
                 scope.__enter__()
-            kss = vss = None
+            aux = None
             if hit is not None:
+                # the store's entries are fp pages whatever the pool's
+                # format: the page record takes them in as it takes a prompt
                 k_stack, v_stack, plen, last = hit
-                fn = self._inject_fn(tp, self.cache.capacity)
-                if self._quantize:
-                    # prefix entries stay in the fp format: inject
-                    # re-quantizes into the slot's page (exact round-trip
-                    # with extract's dequantize — same scale re-derives)
-                    kcs, kss, vcs, vss, valid, toks = fn(
-                        self.cache.k, self.cache.k_scale, self.cache.v,
-                        self.cache.v_scale, self.cache.valid, self._tok,
-                        jnp.asarray(k_stack), jnp.asarray(v_stack),
-                        jnp.int32(plen), jnp.int32(slot), jnp.asarray(last),
-                        jnp.asarray(key), jnp.float32(stream.temperature))
-                else:
-                    as_dev = lambda st: jax.tree_util.tree_map(jnp.asarray,
-                                                               st)
-                    kcs, vcs, valid, toks = fn(
-                        self.cache.k, self.cache.v, self.cache.valid,
-                        self._tok, as_dev(k_stack), as_dev(v_stack),
-                        jnp.int32(plen), jnp.int32(slot), jnp.asarray(last),
-                        jnp.asarray(key), jnp.float32(stream.temperature))
+                as_dev = lambda st: jax.tree_util.tree_map(jnp.asarray, st)
+                state, valid, toks = self._inject_fn(tp, c.capacity)(
+                    c.state, c.valid, self._tok, as_dev(k_stack),
+                    as_dev(v_stack), jnp.int32(plen), jnp.int32(slot),
+                    jnp.asarray(last), jnp.asarray(key),
+                    jnp.float32(stream.temperature))
             else:
-                fn = self._prefill_fn(tp, self.cache.capacity)
-                params = self._params()
-                if self._quantize:
-                    kcs, kss, vcs, vss, valid, toks, last = fn(
-                        params, self.cache.k, self.cache.k_scale,
-                        self.cache.v, self.cache.v_scale, self.cache.valid,
-                        self._tok, jnp.asarray(padded), jnp.int32(t0_len),
-                        jnp.int32(slot), jnp.asarray(key),
-                        jnp.float32(stream.temperature))
-                else:
-                    kcs, vcs, valid, toks, last, *load = fn(
-                        params, self.cache.k, self.cache.v, self.cache.valid,
-                        self._tok, jnp.asarray(padded), jnp.int32(t0_len),
-                        jnp.int32(slot), jnp.asarray(key),
-                        jnp.float32(stream.temperature))
+                state, valid, toks, last, *aux = self._prefill_fn(
+                    tp, c.capacity)(
+                    self._params(), c.state, c.valid, self._tok,
+                    jnp.asarray(padded), jnp.int32(t0_len), jnp.int32(slot),
+                    jnp.asarray(key), jnp.float32(stream.temperature))
         finally:
             if scope is not None:
                 scope.__exit__(None, None, None)
-        self.cache.update(kcs, vcs, valid, kss, vss)
+        c.update(state, valid)
         self._tok = toks
         if hit is None:
             self.metrics.record_prefill()
@@ -750,28 +748,21 @@ class GenerativeServer:
                 # put() waits for the prefill and copies the page off the
                 # device on this, the loop's own, thread
                 engine.dispatch_counter.bump()
-                c = self.cache
                 copied = None
                 if tracing:
-                    # the K and V page as the store keeps them (a quantized
-                    # cache reads out in float32)
-                    copied = {"mb": round(1e-6 * c.page_bytes(
-                        tp, 4 if self._quantize else None), 3)}
+                    # the K and V page as the store keeps them
+                    copied = {"mb": round(1e-6 * c.page_bytes(tp), 3)}
                 with self._span(tracing, "readout%d" % tp, c.num_active,
                                 copied):
-                    if self._quantize:
-                        ks, vs = self._extract_fn(tp, c.capacity)(
-                            c.k, c.k_scale, c.v, c.v_scale, jnp.int32(slot))
-                    else:
-                        ks, vs = self._extract_fn(tp, c.capacity)(
-                            c.k, c.v, jnp.int32(slot))
+                    ks, vs = self._extract_fn(tp, c.capacity)(
+                        c.state, jnp.int32(slot))
                     self.prefix.put(stream.prompt, ks, vs, t0_len,
                                     np.asarray(last))
         first = int(np.asarray(self._tok)[slot])
         now = time.perf_counter()
-        if hit is None and self._routed is not None:
+        if aux:
             # read behind the first token: the prefill has finished
-            self.metrics.record_expert_load(np.asarray(load[0]))
+            self.metrics.record_expert_load(np.asarray(aux[0]))
         if tr is not None:
             # prefill (or prefix-inject) dispatch, closed by the first-token
             # host readback; the first token is sampled inside this program
@@ -812,15 +803,8 @@ class GenerativeServer:
         if self._draft is not None:
             return self._speculate_once(active, n_active, tracing)
         fn = self._decode_fn(self.cache.capacity)
-        params = self._params()
-        if self._quantize:
-            args = (params, self.cache.k, self.cache.k_scale, self.cache.v,
-                    self.cache.v_scale, self.cache.valid, self._tok,
-                    self._dev_active, self._dev_keys, self._dev_temps)
-        else:
-            args = (params, self.cache.k, self.cache.v, self.cache.valid,
-                    self._tok, self._dev_active, self._dev_keys,
-                    self._dev_temps)
+        args = (self._params(), self.cache.state, self.cache.valid,
+                self._tok, self._dev_active, self._dev_keys, self._dev_temps)
         engine.dispatch_counter.bump()
         t0 = time.perf_counter()
         # the step as the scheduler sees it: dispatch to the tokens' arrival
@@ -828,21 +812,15 @@ class GenerativeServer:
         # loaded the experts: this step's own load arrives with its tokens)
         with self._span(tracing, "step", n_active,
                         tag=self._step_tag(active) if tracing else None):
-            out = fn(*args)
-            kss = vss = load = None
-            if self._quantize:
-                kcs, kss, vcs, vss, valid, nxt = out
-                read = nxt
-            else:
-                # a routing model's load rides behind the tokens
-                kcs, vcs, valid, nxt, *packed = out
-                read = packed[0] if packed else nxt
-            host = np.asarray(read)      # ONE host gather per step
-            nxt_host = host[:self.slots]
-            if self._routed is not None:
-                load = host[self.slots:].reshape(self._routed)
+            # what the model's step returned behind the logits (a routing
+            # model's load) rides behind the tokens
+            state, valid, nxt, *packed = fn(*args)
+            host = np.asarray(packed[0] if packed else nxt)
+            nxt_host = host[:self.slots]     # ONE host gather per step
+            load = host[self.slots:].reshape(self._routed) if packed \
+                else None
         with self._span(tracing, "deliver", n_active):
-            self.cache.update(kcs, vcs, valid, kss, vss)
+            self.cache.update(state, valid)
             self._tok = nxt
             dt = time.perf_counter() - t0
             self.metrics.record_step(dt, n_active, n_active, self.slots,
@@ -900,30 +878,19 @@ class GenerativeServer:
         else:
             drafts = draft.propose(None, k)
         fn = self._verify_fn(self.cache.capacity)
-        params = self._params()
-        if self._quantize:
-            args = (params, self.cache.k, self.cache.k_scale, self.cache.v,
-                    self.cache.v_scale, self.cache.valid, self._tok, drafts,
-                    self._dev_active, self._dev_keys, self._dev_temps)
-        else:
-            args = (params, self.cache.k, self.cache.v, self.cache.valid,
-                    self._tok, drafts, self._dev_active, self._dev_keys,
-                    self._dev_temps)
+        args = (self._params(), self.cache.state, self.cache.valid,
+                self._tok, drafts, self._dev_active, self._dev_keys,
+                self._dev_temps)
         engine.dispatch_counter.bump()
         engine.verify_dispatch_counter.bump()
         t0 = time.perf_counter()
         with self._span(tracing, "verify%d" % k, n_active):
-            out = fn(*args)
-            kss = vss = None
-            if self._quantize:
-                kcs, kss, vcs, vss, valid, nxt, emit, n_emit = out
-            else:
-                kcs, vcs, valid, nxt, emit, n_emit = out
+            state, valid, nxt, emit, n_emit = fn(*args)
             # ONE batched host gather for both outputs (two np.asarray
             # calls would sync the device twice per round)
             emit_h, n_emit_h = jax.device_get((emit, n_emit))
         with self._span(tracing, "deliver", n_active):
-            self.cache.update(kcs, vcs, valid, kss, vss)
+            self.cache.update(state, valid)
             self._tok = nxt
             dt = time.perf_counter() - t0
             emitted = int(n_emit_h.sum())
@@ -980,22 +947,12 @@ class GenerativeServer:
         try:
             if scope is not None:
                 scope.__enter__()
-            if self._quantize:
-                kcs, kss, vcs, vss, valid, toks = fn(
-                    params, self.cache.k, self.cache.k_scale, self.cache.v,
-                    self.cache.v_scale, self.cache.valid, self._tok,
-                    jnp.asarray(chunk), jnp.int32(pos0), jnp.int32(plen),
-                    jnp.int32(slot), jnp.asarray(job["key"]),
-                    jnp.float32(stream.temperature))
-                self.cache.update(kcs, vcs, valid, kss, vss)
-            else:
-                kcs, vcs, valid, toks = fn(
-                    params, self.cache.k, self.cache.v, self.cache.valid,
-                    self._tok, jnp.asarray(chunk), jnp.int32(pos0),
-                    jnp.int32(plen), jnp.int32(slot),
-                    jnp.asarray(job["key"]),
-                    jnp.float32(stream.temperature))
-                self.cache.update(kcs, vcs, valid)
+            state, valid, toks = fn(
+                params, self.cache.state, self.cache.valid, self._tok,
+                jnp.asarray(chunk), jnp.int32(pos0), jnp.int32(plen),
+                jnp.int32(slot), jnp.asarray(job["key"]),
+                jnp.float32(stream.temperature))
+            self.cache.update(state, valid)
         finally:
             if scope is not None:
                 scope.__exit__(None, None, None)
@@ -1104,69 +1061,47 @@ class GenerativeServer:
             return fn
         model, plist, top_k = self.model, self._plist, self.top_k
 
-        if self._quantize:
-            def pure(params, kcs, kss, vcs, vss, valid, toks, active, keys,
-                     temps):
-                # trace-time bump: fires exactly when XLA retraces — the
-                # zero-steady-state-retrace proof tests assert (the
-                # quantized step keeps the identical contract)
-                engine.decode_compile_counter.bump()
-                with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
-                    t.param_store = {id(p): a
-                                     for p, a in zip(plist, params)}
-                    logits, kcs, kss, vcs, vss = \
-                        model.decode_step_fixed_quant(
-                            _trace.F, toks, kcs, kss, vcs, vss, valid)
-                nxt = sample_tokens(logits, keys, valid + 1, temps, top_k)
-                act = active > 0
-                nxt = jnp.where(act, nxt, 0)
-                valid = valid + act.astype(jnp.int32)
-                return kcs, kss, vcs, vss, valid, nxt
-
-            fn = self._jit(pure, donate=(1, 2, 3, 4, 5, 6),
-                           hint="step@c%d" % capacity)
-            self._decode_fns[capacity] = fn
-            return fn
-
-        routed = self._routed is not None
-
-        def pure(params, kcs, vcs, valid, toks, active, keys, temps):
+        def pure(params, state, valid, toks, active, keys, temps):
             # trace-time bump: fires exactly when XLA retraces — the
-            # zero-steady-state-retrace proof tests assert
+            # zero-steady-state-retrace proof tests assert (whatever the
+            # pages' format: the contract is the same)
             engine.decode_compile_counter.bump()
             with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
                 t.param_store = {id(p): a for p, a in zip(plist, params)}
                 # a free slot reads nothing of its page (and routes nowhere)
-                # (a routing model's load follows the caches)
-                logits, kcs, vcs, *load = model.decode_step_fixed(
-                    _trace.F, toks, kcs, vcs, valid, active)
+                logits, state, aux = model.decode_step(
+                    _trace.F, jnp.reshape(toks, (-1, 1)), state, valid,
+                    active)
             # the generated token's position is valid+1 (prefill used
             # `prompt_len` for the first token) — every token of a stream
             # folds a distinct position into its slot key
-            nxt = sample_tokens(logits, keys, valid + 1, temps, top_k)
+            nxt = sample_tokens(
+                jnp.reshape(logits, (logits.shape[0], -1)), keys, valid + 1,
+                temps, top_k)
             act = active > 0
             nxt = jnp.where(act, nxt, 0)
             valid = valid + act.astype(jnp.int32)
-            if routed:
-                # what the host reads: the tokens, then the experts' load
-                return kcs, vcs, valid, nxt, jnp.concatenate(
-                    [nxt, load[0].astype(jnp.int32).reshape(-1)])
-            return kcs, vcs, valid, nxt
+            if aux is not None:
+                # what the host reads: the tokens, then the model's aux
+                return state, valid, nxt, jnp.concatenate(
+                    [nxt, aux.astype(jnp.int32).reshape(-1)])
+            return state, valid, nxt
 
-        fn = self._jit(pure, donate=(1, 2, 3, 4), hint="step@c%d" % capacity)
+        fn = self._jit(pure, donate=(1, 2, 3), hint="step@c%d" % capacity)
         self._decode_fns[capacity] = fn
         return fn
 
     def _verify_fn(self, capacity):
         """Speculative verify program: score the (current token + drafted)
-        k-window in one wide dispatch, sample every row at its own
-        sequence position with the slot's folded key, and accept the
-        longest prefix where the sample equals the draft — the first
-        mismatching row's sample IS the rejection-resample (exact for
-        deterministic drafts: the proposal is one-hot, so accept-w.p.-p(d)
-        and the residual distribution both collapse to 'sample from p,
-        keep on agreement'). Greedy rows therefore reproduce plain greedy
-        decode bit-for-bit; k=1 degenerates to the plain step."""
+        k-window in one wide dispatch (``decode_step`` with K = spec_k),
+        sample every row at its own sequence position with the slot's
+        folded key, and accept the longest prefix where the sample equals
+        the draft — the first mismatching row's sample IS the
+        rejection-resample (exact for deterministic drafts: the proposal is
+        one-hot, so accept-w.p.-p(d) and the residual distribution both
+        collapse to 'sample from p, keep on agreement'). Greedy rows
+        therefore reproduce plain greedy decode bit-for-bit; k=1
+        degenerates to the plain step."""
         fn = self._verify_fns.get(capacity)
         if fn is not None:
             return fn
@@ -1198,31 +1133,7 @@ class GenerativeServer:
                 act, jnp.take_along_axis(y, al[:, None], axis=1)[:, 0], 0)
             return valid + n_emit, nxt, emit, n_emit
 
-        if self._quantize:
-            def pure(params, kcs, kss, vcs, vss, valid, toks, drafts,
-                     active, keys, temps):
-                # trace-time bump: zero-steady-state-retrace proof (the
-                # verify DISPATCH count is engine.verify_dispatch_counter,
-                # bumped at the call site)
-                engine.decode_compile_counter.bump()
-                window = jnp.concatenate([toks[:, None], drafts], axis=1)
-                with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
-                    t.param_store = {id(p): a
-                                     for p, a in zip(plist, params)}
-                    logits, kcs, kss, vcs, vss = \
-                        model.decode_step_speculative_quant(
-                            _trace.F, window, kcs, kss, vcs, vss, valid)
-                valid, nxt, emit, n_emit = accept_emit(
-                    logits, valid, drafts, active, keys, temps)
-                return kcs, kss, vcs, vss, valid, nxt, emit, n_emit
-
-            fn = self._jit(pure, donate=(1, 2, 3, 4, 5, 6),
-                           hint="verify%d@c%d" % (k, capacity))
-            self._verify_fns[capacity] = fn
-            return fn
-
-        def pure(params, kcs, vcs, valid, toks, drafts, active, keys,
-                 temps):
+        def pure(params, state, valid, toks, drafts, active, keys, temps):
             # trace-time bump: zero-steady-state-retrace proof (the verify
             # DISPATCH count is engine.verify_dispatch_counter, bumped at
             # the call site)
@@ -1230,33 +1141,31 @@ class GenerativeServer:
             window = jnp.concatenate([toks[:, None], drafts], axis=1)
             with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
                 t.param_store = {id(p): a for p, a in zip(plist, params)}
-                logits, kcs, vcs = model.decode_step_speculative(
-                    _trace.F, window, kcs, vcs, valid)
+                logits, state, _aux = model.decode_step(
+                    _trace.F, window, state, valid)
             valid, nxt, emit, n_emit = accept_emit(
                 logits, valid, drafts, active, keys, temps)
-            return kcs, vcs, valid, nxt, emit, n_emit
+            return state, valid, nxt, emit, n_emit
 
-        fn = self._jit(pure, donate=(1, 2, 3, 4),
+        fn = self._jit(pure, donate=(1, 2, 3),
                        hint="verify%d@c%d" % (k, capacity))
         self._verify_fns[capacity] = fn
         return fn
 
     def _chunk_fn(self, tc, capacity):
-        """Prefill-chunk program: slice the slot's page out of the shared
-        buffers, run ``tc`` prompt positions through the wide-window step
-        at offset ``pos0`` (``decode_step_speculative`` with a (1,) valid
-        vector — prefix attention + in-window causality + the per-row
-        window write are exactly the verify semantics), and write the page
-        back. The final chunk (pos0 + tc >= plen) samples the first token
-        at its true row and sets valid to the full prompt length;
-        non-final chunks park valid at the chunk frontier, so interleaved
-        decode garbage for this masked slot lands exactly where the next
-        chunk overwrites it."""
+        """Prefill-chunk program: take the slot's page out of the shared
+        state, run ``tc`` prompt positions through ``decode_step`` at offset
+        ``pos0`` (K = tc with a (1,) valid vector — prefix attention +
+        in-window causality + the per-row window write are exactly the
+        verify semantics), and put the page back. The final chunk (pos0 +
+        tc >= plen) samples the first token at its true row and sets valid
+        to the full prompt length; non-final chunks park valid at the chunk
+        frontier, so interleaved decode garbage for this masked slot lands
+        exactly where the next chunk overwrites it."""
         fn = self._chunk_fns.get((tc, capacity))
         if fn is not None:
             return fn
         model, plist, top_k = self.model, self._plist, self.top_k
-        H, D = self.cache.heads, self.cache.head_dim
         zero = jnp.int32(0)
 
         def finish(logits, valid, toks, pos0, plen, slot, key, temp):
@@ -1273,98 +1182,25 @@ class GenerativeServer:
                                top_k)
             return valid, jax.lax.dynamic_update_slice(toks, t0, (slot,))
 
-        if self._quantize:
-            def pure(params, kcs, kss, vcs, vss, valid, toks, tokens, pos0,
-                     plen, slot, key, temp):
-                engine.decode_compile_counter.bump()
-                pk = [jax.lax.dynamic_slice(
-                    kc, (slot, zero, zero, zero), (1, H, capacity, D))
-                    for kc in kcs]
-                pv = [jax.lax.dynamic_slice(
-                    vc, (slot, zero, zero, zero), (1, H, capacity, D))
-                    for vc in vcs]
-                # fresh page scale on the first chunk (slot reuse must not
-                # inherit the previous stream's running max)
-                wipe = (pos0 == 0)
-
-                def slice_scale(s):
-                    sl = jax.lax.dynamic_slice(
-                        s, (slot, zero, zero, zero), (1, H, 1, 1))
-                    return jnp.where(wipe, jnp.zeros_like(sl), sl)
-
-                ps = [slice_scale(s) for s in kss]
-                qs = [slice_scale(s) for s in vss]
-                with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
-                    t.param_store = {id(p): a
-                                     for p, a in zip(plist, params)}
-                    logits, pk, ps, pv, qs = \
-                        model.decode_step_speculative_quant(
-                            _trace.F, tokens, pk, ps, pv, qs,
-                            jnp.reshape(pos0, (1,)))
-                kcs = [jax.lax.dynamic_update_slice(
-                    kc, p, (slot, zero, zero, zero))
-                    for kc, p in zip(kcs, pk)]
-                kss = [jax.lax.dynamic_update_slice(
-                    s0, s, (slot, zero, zero, zero))
-                    for s0, s in zip(kss, ps)]
-                vcs = [jax.lax.dynamic_update_slice(
-                    vc, p, (slot, zero, zero, zero))
-                    for vc, p in zip(vcs, pv)]
-                vss = [jax.lax.dynamic_update_slice(
-                    s0, s, (slot, zero, zero, zero))
-                    for s0, s in zip(vss, qs)]
-                valid, toks = finish(logits, valid, toks, pos0, plen, slot,
-                                     key, temp)
-                return kcs, kss, vcs, vss, valid, toks
-
-            fn = self._jit(pure, donate=(1, 2, 3, 4, 5, 6),
-                           hint="chunk%d@c%d" % (tc, capacity))
-            self._chunk_fns[(tc, capacity)] = fn
-            return fn
-
-        def pure(params, kcs, vcs, valid, toks, tokens, pos0, plen, slot,
-                 key, temp):
+        def pure(params, state, valid, toks, tokens, pos0, plen, slot, key,
+                 temp):
             engine.decode_compile_counter.bump()
-            pk = [jax.lax.dynamic_slice(
-                kc, (slot, zero, zero, zero), (1, H, capacity, D))
-                for kc in kcs]
-            pv = [jax.lax.dynamic_slice(
-                vc, (slot, zero, zero, zero), (1, H, capacity, D))
-                for vc in vcs]
+            # the first chunk starts a stream: the page carries nothing over
+            # from the one that held the slot before
+            pages = kv_cache.take_slot(state, slot, pos0 == 0)
             with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
                 t.param_store = {id(p): a for p, a in zip(plist, params)}
-                logits, pk, pv = model.decode_step_speculative(
-                    _trace.F, tokens, pk, pv, jnp.reshape(pos0, (1,)))
-            kcs = [jax.lax.dynamic_update_slice(
-                kc, p, (slot, zero, zero, zero)) for kc, p in zip(kcs, pk)]
-            vcs = [jax.lax.dynamic_update_slice(
-                vc, p, (slot, zero, zero, zero)) for vc, p in zip(vcs, pv)]
+                logits, pages, _aux = model.decode_step(
+                    _trace.F, tokens, pages, jnp.reshape(pos0, (1,)))
+            state = kv_cache.put_slot(state, slot, pages)
             valid, toks = finish(logits, valid, toks, pos0, plen, slot,
                                  key, temp)
-            return kcs, vcs, valid, toks
+            return state, valid, toks
 
-        fn = self._jit(pure, donate=(1, 2, 3, 4),
+        fn = self._jit(pure, donate=(1, 2, 3),
                        hint="chunk%d@c%d" % (tc, capacity))
         self._chunk_fns[(tc, capacity)] = fn
         return fn
-
-    @staticmethod
-    def _quantize_pages(pages, plen, tp):
-        """Quantize per-layer fp K or V (1, H, tp, D) into int8 pages with
-        a fresh per-head scale, masking positions ≥ plen out of the amax
-        (pad garbage must not inflate the scale). Fresh overwrite, not a
-        running max: slot reuse relies on prefill/inject resetting the
-        page scale. Returns [(q (1,H,tp,D) int8, scale (1,H,1,1) f32)]."""
-        maskf = (jnp.arange(tp) < plen).astype(jnp.float32).reshape(
-            (1, 1, tp, 1))
-        out = []
-        for a in pages:
-            a = a.astype(jnp.float32) * maskf
-            amax = jnp.max(jnp.abs(a), axis=(2, 3), keepdims=True)
-            scale = jnp.maximum(amax / 127.0, 1e-8)
-            q = jnp.clip(jnp.round(a / scale), -127, 127).astype(jnp.int8)
-            out.append((q, scale))
-        return out
 
     def _prefill_fn(self, tp, capacity):
         fn = self._prefill_fns.get((tp, capacity))
@@ -1373,91 +1209,28 @@ class GenerativeServer:
         model, plist, top_k = self.model, self._plist, self.top_k
         zero = jnp.int32(0)
 
-        if self._quantize:
-            quantize_pages = self._quantize_pages
-
-            def pure(params, kcs, kss, vcs, vss, valid, toks, tokens, plen,
-                     slot, key, temp):
-                engine.decode_compile_counter.bump()
-                with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
-                    t.param_store = {id(p): a
-                                     for p, a in zip(plist, params)}
-                    logits, kvs = model.forward_collect_kv(_trace.F, tokens)
-                qk = quantize_pages([k for k, _v in kvs], plen, tp)
-                qv = quantize_pages([v for _k, v in kvs], plen, tp)
-                kcs = [jax.lax.dynamic_update_slice(
-                    kc, q, (slot, zero, zero, zero))
-                    for kc, (q, _s) in zip(kcs, qk)]
-                kss = [jax.lax.dynamic_update_slice(
-                    ks, s, (slot, zero, zero, zero))
-                    for ks, (_q, s) in zip(kss, qk)]
-                vcs = [jax.lax.dynamic_update_slice(
-                    vc, q, (slot, zero, zero, zero))
-                    for vc, (q, _s) in zip(vcs, qv)]
-                vss = [jax.lax.dynamic_update_slice(
-                    vs, s, (slot, zero, zero, zero))
-                    for vs, (_q, s) in zip(vss, qv)]
-                valid = jax.lax.dynamic_update_slice(
-                    valid, jnp.reshape(plen, (1,)), (slot,))
-                last = jnp.reshape(jax.lax.dynamic_slice(
-                    logits, (zero, plen - 1, zero),
-                    (1, 1, logits.shape[2])), (1, -1))
-                t0 = sample_tokens(last, key[None], plen[None], temp[None],
-                                   top_k)
-                toks = jax.lax.dynamic_update_slice(toks, t0, (slot,))
-                return (kcs, kss, vcs, vss, valid, toks,
-                        jnp.reshape(last, (-1,)))
-
-            fn = self._jit(pure, donate=(1, 2, 3, 4, 5, 6),
-                           hint="prefill@t%dc%d" % (tp, capacity))
-            self._prefill_fns[(tp, capacity)] = fn
-            return fn
-
-        routed = self._routed is not None
-
-        def page(kv, cache, plen):
-            """The prompt's K or V (1, H, tp, D) as ``cache``'s layer keeps
-            it: as it is, or, where the prompt's bucket is longer than the
-            layer's ring, the ring's rows: slot j holds the last position
-            p < plen with p % length == j."""
-            length = cache.shape[2]
-            if kv.shape[2] > length:
-                j = jnp.arange(length, dtype=jnp.int32)
-                kv = jnp.take(kv, jnp.clip(
-                    plen - 1 - (plen - 1 - j) % length, 0, kv.shape[2] - 1),
-                    axis=2)
-            return kv.astype(cache.dtype)
-
-        def pure(params, kcs, vcs, valid, toks, tokens, plen, slot, key,
-                 temp):
+        def pure(params, state, valid, toks, tokens, plen, slot, key, temp):
             engine.decode_compile_counter.bump()
             with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
                 t.param_store = {id(p): a for p, a in zip(plist, params)}
-                if routed:
-                    # the model cuts the last live row itself: (1, 1, V)
-                    logits, kvs, load = model.forward_collect_kv(
-                        _trace.F, tokens, plen)
-                    last = jnp.reshape(logits, (1, -1))
-                else:
-                    logits, kvs = model.forward_collect_kv(_trace.F, tokens)
-                    last = jnp.reshape(jax.lax.dynamic_slice(
-                        logits, (zero, plen - 1, zero),
-                        (1, 1, logits.shape[2])), (1, -1))
-            kcs = [jax.lax.dynamic_update_slice(
-                kc, page(k, kc, plen), (slot, zero, zero, zero))
-                for kc, (k, _v) in zip(kcs, kvs)]
-            vcs = [jax.lax.dynamic_update_slice(
-                vc, page(v, vc, plen), (slot, zero, zero, zero))
-                for vc, (_k, v) in zip(vcs, kvs)]
+                logits, kvs, aux = model.forward_collect_kv(
+                    _trace.F, tokens, plen)
+            # every row's logits, or (a model that cuts the last live row
+            # itself: pad rows route nowhere) that row's alone
+            if logits.shape[1] != 1:
+                logits = jax.lax.dynamic_slice(
+                    logits, (zero, plen - 1, zero), (1, 1, logits.shape[2]))
+            last = jnp.reshape(logits, (1, -1))
+            state = kv_cache.write_prompt(state, kvs, plen, slot)
             valid = jax.lax.dynamic_update_slice(
                 valid, jnp.reshape(plen, (1,)), (slot,))
             t0 = sample_tokens(last, key[None], plen[None], temp[None],
                                top_k)
             toks = jax.lax.dynamic_update_slice(toks, t0, (slot,))
-            out = (kcs, vcs, valid, toks, jnp.reshape(last, (-1,)))
-            return out + (load.astype(jnp.int32),) if routed else out
+            out = (state, valid, toks, jnp.reshape(last, (-1,)))
+            return out if aux is None else out + (aux.astype(jnp.int32),)
 
-        fn = self._jit(pure, donate=(1, 2, 3, 4),
+        fn = self._jit(pure, donate=(1, 2, 3),
                        hint="prefill@t%dc%d" % (tp, capacity))
         self._prefill_fns[(tp, capacity)] = fn
         return fn
@@ -1467,60 +1240,20 @@ class GenerativeServer:
         if fn is not None:
             return fn
         top_k = self.top_k
-        zero = jnp.int32(0)
 
-        if self._quantize:
-            quantize_pages = self._quantize_pages
-
-            def pure(kcs, kss, vcs, vss, valid, toks, k_stack, v_stack,
-                     plen, slot, last, key, temp):
-                engine.decode_compile_counter.bump()
-                L = len(kcs)
-                qk = quantize_pages([k_stack[i][None] for i in range(L)],
-                                    plen, tp)
-                qv = quantize_pages([v_stack[i][None] for i in range(L)],
-                                    plen, tp)
-                kcs = [jax.lax.dynamic_update_slice(
-                    kc, q, (slot, zero, zero, zero))
-                    for kc, (q, _s) in zip(kcs, qk)]
-                kss = [jax.lax.dynamic_update_slice(
-                    ks, s, (slot, zero, zero, zero))
-                    for ks, (_q, s) in zip(kss, qk)]
-                vcs = [jax.lax.dynamic_update_slice(
-                    vc, q, (slot, zero, zero, zero))
-                    for vc, (q, _s) in zip(vcs, qv)]
-                vss = [jax.lax.dynamic_update_slice(
-                    vs, s, (slot, zero, zero, zero))
-                    for vs, (_q, s) in zip(vss, qv)]
-                valid = jax.lax.dynamic_update_slice(
-                    valid, jnp.reshape(plen, (1,)), (slot,))
-                t0 = sample_tokens(last[None], key[None], plen[None],
-                                   temp[None], top_k)
-                toks = jax.lax.dynamic_update_slice(toks, t0, (slot,))
-                return kcs, kss, vcs, vss, valid, toks
-
-            fn = self._jit(pure, donate=(0, 1, 2, 3, 4, 5),
-                           hint="inject@t%dc%d" % (tp, capacity))
-            self._inject_fns[(tp, capacity)] = fn
-            return fn
-
-        def pure(kcs, vcs, valid, toks, k_stack, v_stack, plen, slot, last,
+        def pure(state, valid, toks, k_stack, v_stack, plen, slot, last,
                  key, temp):
             engine.decode_compile_counter.bump()
-            kcs = [jax.lax.dynamic_update_slice(
-                kc, k_stack[i][None].astype(kc.dtype),
-                (slot, zero, zero, zero)) for i, kc in enumerate(kcs)]
-            vcs = [jax.lax.dynamic_update_slice(
-                vc, v_stack[i][None].astype(vc.dtype),
-                (slot, zero, zero, zero)) for i, vc in enumerate(vcs)]
+            state = kv_cache.write_prompt(
+                state, kv_cache.stored_kvs(k_stack, v_stack), plen, slot)
             valid = jax.lax.dynamic_update_slice(
                 valid, jnp.reshape(plen, (1,)), (slot,))
             t0 = sample_tokens(last[None], key[None], plen[None], temp[None],
                                top_k)
             toks = jax.lax.dynamic_update_slice(toks, t0, (slot,))
-            return kcs, vcs, valid, toks
+            return state, valid, toks
 
-        fn = self._jit(pure, donate=(0, 1, 2, 3),
+        fn = self._jit(pure, donate=(0, 1, 2),
                        hint="inject@t%dc%d" % (tp, capacity))
         self._inject_fns[(tp, capacity)] = fn
         return fn
@@ -1529,51 +1262,13 @@ class GenerativeServer:
         fn = self._extract_fns.get((tp, capacity))
         if fn is not None:
             return fn
-        H, D = self.cache.heads, self.cache.head_dim
-        zero = jnp.int32(0)
-
-        if self._quantize:
-            def pure(kcs, kss, vcs, vss, slot):
-                # prefix entries store fp pages: dequantize on read-out so
-                # the PrefixCache format is quantization-agnostic (inject
-                # re-quantizes exactly — the max element re-derives the
-                # same scale)
-                engine.decode_compile_counter.bump()
-
-                def slice_deq(cs, ss):
-                    out = []
-                    for c, s in zip(cs, ss):
-                        page = jax.lax.dynamic_slice(
-                            c, (slot, zero, zero, zero), (1, H, tp, D))
-                        sc = jax.lax.dynamic_slice(
-                            s, (slot, zero, zero, zero), (1, H, 1, 1))
-                        out.append((page.astype(jnp.float32) * sc)[0])
-                    return jnp.stack(out)
-
-                return slice_deq(kcs, kss), slice_deq(vcs, vss)
-
-            # reads live caches: never donate
-            fn = self._jit(pure, donate=(),
-                           hint="extract@t%dc%d" % (tp, capacity))
-            self._extract_fns[(tp, capacity)] = fn
-            return fn
-
         lengths = self.cache.page_lengths(tp)
-        # one stacked array where every layer's page has the same length,
-        # else (window rings beside full pages) one array a layer
-        pack = jnp.stack if len(set(lengths)) == 1 else tuple
 
-        def pure(kcs, vcs, slot):
+        def pure(state, slot):
             engine.decode_compile_counter.bump()
-            ks = pack([jax.lax.dynamic_slice(
-                kc, (slot, zero, zero, zero), (1, H, n, D))[0]
-                for kc, n in zip(kcs, lengths)])
-            vs = pack([jax.lax.dynamic_slice(
-                vc, (slot, zero, zero, zero), (1, H, n, D))[0]
-                for vc, n in zip(vcs, lengths)])
-            return ks, vs
+            return kv_cache.read_prompt(state, slot, lengths)
 
-        # reads live caches: never donate
+        # reads the live state: never donate
         fn = self._jit(pure, donate=(),
                        hint="extract@t%dc%d" % (tp, capacity))
         self._extract_fns[(tp, capacity)] = fn
@@ -1594,50 +1289,23 @@ class GenerativeServer:
             if slot is None:
                 break
             tp = min(next_pow2(int(b)), self.cache.capacity)
-            fn = self._prefill_fn(tp, self.cache.capacity)
-            params = self._params()
-            key = np.asarray(jax.random.PRNGKey(0), np.uint32)
-            padded = np.zeros((1, tp), np.int32)
-            if self._quantize:
-                kcs, kss, vcs, vss, valid, toks, _last = fn(
-                    params, self.cache.k, self.cache.k_scale, self.cache.v,
-                    self.cache.v_scale, self.cache.valid, self._tok,
-                    jnp.asarray(padded), jnp.int32(int(b)), jnp.int32(slot),
-                    jnp.asarray(key), jnp.float32(0.0))
-                self.cache.update(kcs, vcs, valid, kss, vss)
-            else:
-                kcs, vcs, valid, toks, _last, *_load = fn(
-                    params, self.cache.k, self.cache.v, self.cache.valid,
-                    self._tok, jnp.asarray(padded), jnp.int32(int(b)),
-                    jnp.int32(slot), jnp.asarray(key), jnp.float32(0.0))
-                self.cache.update(kcs, vcs, valid)
-            self._tok = toks
+            c = self.cache
+            key = jnp.asarray(jax.random.PRNGKey(0), jnp.uint32)
+            plen, at, temp = jnp.int32(int(b)), jnp.int32(slot), \
+                jnp.float32(0.0)
+            state, valid, self._tok, last, *_aux = self._prefill_fn(
+                tp, c.capacity)(
+                self._params(), c.state, c.valid, self._tok,
+                jnp.zeros((1, tp), jnp.int32), plen, at, key, temp)
+            c.update(state, valid)
             if self.prefix is not None:
                 # prefix-store (extract) and replay (inject) programs are
                 # part of the join path: compile them now too
-                if self._quantize:
-                    ks, vs = self._extract_fn(tp, self.cache.capacity)(
-                        self.cache.k, self.cache.k_scale, self.cache.v,
-                        self.cache.v_scale, jnp.int32(slot))
-                    kcs, kss, vcs, vss, valid, toks = self._inject_fn(
-                        tp, self.cache.capacity)(
-                        self.cache.k, self.cache.k_scale, self.cache.v,
-                        self.cache.v_scale, self.cache.valid, self._tok,
-                        ks, vs, jnp.int32(int(b)), jnp.int32(slot),
-                        jnp.asarray(_last), jnp.asarray(key),
-                        jnp.float32(0.0))
-                    self.cache.update(kcs, vcs, valid, kss, vss)
-                else:
-                    ks, vs = self._extract_fn(tp, self.cache.capacity)(
-                        self.cache.k, self.cache.v, jnp.int32(slot))
-                    kcs, vcs, valid, toks = self._inject_fn(
-                        tp, self.cache.capacity)(
-                        self.cache.k, self.cache.v, self.cache.valid,
-                        self._tok, ks, vs, jnp.int32(int(b)),
-                        jnp.int32(slot), jnp.asarray(_last),
-                        jnp.asarray(key), jnp.float32(0.0))
-                    self.cache.update(kcs, vcs, valid)
-                self._tok = toks
+                ks, vs = self._extract_fn(tp, c.capacity)(c.state, at)
+                state, valid, self._tok = self._inject_fn(tp, c.capacity)(
+                    c.state, c.valid, self._tok, ks, vs, plen, at, last,
+                    key, temp)
+                c.update(state, valid)
             self.cache.release(slot)
         if self._draft is not None:
             # draft-side programs (cache fill per prompt bucket + the
@@ -1672,20 +1340,11 @@ class GenerativeServer:
         params = self._params()
         key = np.asarray(jax.random.PRNGKey(0), np.uint32)
         chunk = np.zeros((1, tc), np.int32)
-        if self._quantize:
-            kcs, kss, vcs, vss, valid, toks = fn(
-                params, self.cache.k, self.cache.k_scale, self.cache.v,
-                self.cache.v_scale, self.cache.valid, self._tok,
-                jnp.asarray(chunk), jnp.int32(0), jnp.int32(tc),
-                jnp.int32(slot), jnp.asarray(key), jnp.float32(0.0))
-            self.cache.update(kcs, vcs, valid, kss, vss)
-        else:
-            kcs, vcs, valid, toks = fn(
-                params, self.cache.k, self.cache.v, self.cache.valid,
-                self._tok, jnp.asarray(chunk), jnp.int32(0), jnp.int32(tc),
-                jnp.int32(slot), jnp.asarray(key), jnp.float32(0.0))
-            self.cache.update(kcs, vcs, valid)
-        self._tok = toks
+        state, valid, self._tok = fn(
+            params, self.cache.state, self.cache.valid, self._tok,
+            jnp.asarray(chunk), jnp.int32(0), jnp.int32(tc),
+            jnp.int32(slot), jnp.asarray(key), jnp.float32(0.0))
+        self.cache.update(state, valid)
         self.cache.release(slot)
 
     # ------------------------------------------------ snapshot interface

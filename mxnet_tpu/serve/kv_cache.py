@@ -42,8 +42,12 @@ whole-prompt forward.
 """
 from __future__ import annotations
 
+import functools
+from typing import Any, NamedTuple
+
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from ..base import BoundedCache, env_cap, next_pow2
@@ -53,14 +57,221 @@ class CacheError(RuntimeError):
     """Misuse of the paged cache (capacity/slot exhaustion)."""
 
 
+# ------------------------------------------------------------- page records
+# One layer's pool of pages is a RECORD whose type says its format. The
+# cache's state is a list of them, one a layer: a pytree that the serving
+# programs carry (donated) without looking inside. What knows a format: the
+# record here (allocation, growth, and the traced operations that move a
+# prompt's or a slot's page in and out of the pool) and the model's
+# attention layer (the write and read of a decode step, chosen at trace time
+# from the record's type). A new format is a new record with these methods
+# and a model whose ``decode_state_spec()`` names it under ``"page"``.
+@functools.lru_cache(maxsize=None)
+def _zero():
+    # ONE device scalar for every start index of every program (a program
+    # then carries one constant for them), made at the first use and not at
+    # import: importing this module starts no backend (a fleet's router
+    # stays off the chip). The first use is inside a trace: made eagerly.
+    with jax.ensure_compile_time_eval():
+        return jnp.int32(0)
+
+
+def _slot_start(slot):
+    zero = _zero()
+    return (slot, zero, zero, zero)
+
+
+def _take(a, slot):
+    return jax.lax.dynamic_slice(a, _slot_start(slot), (1,) + a.shape[1:])
+
+
+def _put(pool, slot, page):
+    """``pool`` with ``slot``'s page replaced by ``page`` (a pool of one
+    slot, as ``take_slot`` gave it), leaf by leaf."""
+    at = _slot_start(slot)
+    return type(pool)(*(jax.lax.dynamic_update_slice(a, p, at)
+                        for a, p in zip(pool, page)))
+
+
+def _pad_time(a, more):
+    return jnp.pad(a, ((0, 0), (0, 0), (0, more), (0, 0))) if more else a
+
+
+class PlainPage(NamedTuple):
+    """K and V of one layer in the model's dtype: ``k``, ``v`` (slots,
+    heads, length, head_dim), ``length`` the capacity bucket or a ring's
+    own length."""
+
+    k: Any
+    v: Any
+
+    @classmethod
+    def zeros(cls, slots, heads, length, head_dim, dtype):
+        shape = (slots, heads, length, head_dim)
+        return cls(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+    def grow(self, more):
+        """The time axis zero-padded by ``more`` positions."""
+        return PlainPage(_pad_time(self.k, more), _pad_time(self.v, more))
+
+    def _rows(self, kv, plen):
+        """A prompt's K or V (1, H, tp, D) as this layer keeps it: as it
+        is, or, where the prompt's bucket is longer than the layer's ring,
+        the ring's rows: slot j holds the last position p < plen with
+        p % length == j."""
+        length = self.k.shape[2]
+        if kv.shape[2] > length:
+            j = jnp.arange(length, dtype=jnp.int32)
+            kv = jnp.take(kv, jnp.clip(
+                plen - 1 - (plen - 1 - j) % length, 0, kv.shape[2] - 1),
+                axis=2)
+        return kv.astype(self.k.dtype)
+
+    def write_prompt(self, k, v, plen, slot):
+        """The pool with a prompt's ``k``, ``v`` (1, H, tp, D; ``plen``
+        live positions) written into ``slot``'s page from position 0."""
+        at = _slot_start(slot)
+        return PlainPage(*(
+            jax.lax.dynamic_update_slice(a, self._rows(new, plen), at)
+            for a, new in zip(self, (k, v))))
+
+    def read_prompt(self, slot, n):
+        """The first ``n`` positions of ``slot``'s page as the prefix store
+        keeps them: (k, v), each (H, n, D)."""
+        H, D = self.k.shape[1], self.k.shape[3]
+        return tuple(jax.lax.dynamic_slice(
+            a, _slot_start(slot), (1, H, n, D))[0] for a in self)
+
+    def prompt_bytes(self, n):
+        """Bytes of what :meth:`read_prompt` returns."""
+        H, D = self.k.shape[1], self.k.shape[3]
+        return 2 * H * n * D * self.k.dtype.itemsize
+
+    def take_slot(self, slot, fresh):
+        """``slot``'s page as a pool of one slot (``fresh``: a traced flag,
+        the slot starts a new stream; a plain page carries nothing over)."""
+        return PlainPage(*(_take(a, slot) for a in self))
+
+    put_slot = _put
+
+
+class Int8Page(NamedTuple):
+    """K and V of one layer as int8 with one float32 scale a page and head:
+    ``k``, ``v`` (slots, heads, capacity, head_dim) int8, ``k_scale``,
+    ``v_scale`` (slots, heads, 1, 1). A decode step keeps a running max
+    (``ops/attention.py: quant_cache_write_read``); a prompt's write sets the
+    scale afresh, which is what lets a slot be taken again."""
+
+    k: Any
+    k_scale: Any
+    v: Any
+    v_scale: Any
+
+    @classmethod
+    def zeros(cls, slots, heads, length, head_dim, dtype):
+        # four buffers of their own: a donated argument is handed over once
+        page = lambda: jnp.zeros((slots, heads, length, head_dim), jnp.int8)
+        scale = lambda: jnp.zeros((slots, heads, 1, 1), jnp.float32)
+        return cls(page(), scale(), page(), scale())
+
+    def grow(self, more):
+        # the scales are capacity-independent: only the int8 pages pad
+        return self._replace(k=_pad_time(self.k, more),
+                             v=_pad_time(self.v, more))
+
+    def write_prompt(self, k, v, plen, slot):
+        # a fresh scale, not a running max, with positions >= plen masked
+        # out of the amax (pad garbage must not inflate it)
+        tp = k.shape[2]
+        maskf = (jnp.arange(tp) < plen).astype(jnp.float32).reshape(
+            (1, 1, tp, 1))
+
+        def quantize(a):
+            a = a.astype(jnp.float32) * maskf
+            amax = jnp.max(jnp.abs(a), axis=(2, 3), keepdims=True)
+            scale = jnp.maximum(amax / 127.0, 1e-8)
+            return (jnp.clip(jnp.round(a / scale), -127, 127).astype(
+                jnp.int8), scale)
+
+        at = _slot_start(slot)
+        return Int8Page(*(
+            jax.lax.dynamic_update_slice(a, new, at)
+            for a, new in zip(self, quantize(k) + quantize(v))))
+
+    def read_prompt(self, slot, n):
+        # the store's entries are fp whatever the pool's format: dequantised
+        # on the way out, and write_prompt re-derives the same scale from
+        # the page's largest element on the way back in
+        H, D = self.k.shape[1], self.k.shape[3]
+        at = _slot_start(slot)
+
+        def deq(a, scale):
+            page = jax.lax.dynamic_slice(a, at, (1, H, n, D))
+            return (page.astype(jnp.float32) * _take(scale, slot))[0]
+
+        return deq(self.k, self.k_scale), deq(self.v, self.v_scale)
+
+    def prompt_bytes(self, n):
+        H, D = self.k.shape[1], self.k.shape[3]
+        return 2 * H * n * D * 4
+
+    def take_slot(self, slot, fresh):
+        # a fresh stream must not inherit the running max of the one that
+        # held the slot before it
+        def scale(s):
+            s = _take(s, slot)
+            return jnp.where(fresh, jnp.zeros_like(s), s)
+
+        return Int8Page(_take(self.k, slot), scale(self.k_scale),
+                        _take(self.v, slot), scale(self.v_scale))
+
+    put_slot = _put
+
+
+def write_prompt(state, kvs, plen, slot):
+    """The state with a prompt's K/V (``kvs``: one (k, v) a layer, each
+    (1, H, tp, D)) written into ``slot``'s page of every layer. Traced."""
+    return [page.write_prompt(k, v, plen, slot)
+            for page, (k, v) in zip(state, kvs)]
+
+
+def read_prompt(state, slot, lengths):
+    """``slot``'s page read out as the prefix store keeps it: (k_stack,
+    v_stack), each one stacked array where every layer's page has the same
+    length, else (window rings beside full pages) one array a layer.
+    ``lengths``: ``PagedKVCache.page_lengths`` of the prompt's bucket.
+    Traced."""
+    pack = jnp.stack if len(set(lengths)) == 1 else tuple
+    ks, vs = zip(*(page.read_prompt(slot, n)
+                   for page, n in zip(state, lengths)))
+    return pack(ks), pack(vs)
+
+
+def stored_kvs(k_stack, v_stack):
+    """A prefix entry's stacks as :func:`write_prompt` takes them."""
+    return [(k_stack[i][None], v_stack[i][None])
+            for i in range(len(k_stack))]
+
+
+def take_slot(state, slot, fresh):
+    """Every layer's page of ``slot`` as a state of one slot. Traced."""
+    return [page.take_slot(slot, fresh) for page in state]
+
+
+def put_slot(state, slot, pages):
+    """The state with ``slot``'s pages replaced by ``pages``. Traced."""
+    return [page.put_slot(slot, p) for page, p in zip(state, pages)]
+
+
 class PagedKVCache:
     """Slot-paged fixed-capacity KV cache shared by all in-flight requests.
 
-    Holds the device-side carried state of the decode loop — per-layer K/V
-    buffers plus the per-slot ``valid_len`` vector — and the host-side slot
-    bookkeeping (which request owns which page). The compiled prefill/
-    decode programs take these arrays as (donated) inputs and return the
-    updated ones; the server writes them back via :meth:`update`.
+    Holds the device-side carried state of the decode loop — ``state``, one
+    page record a layer (:class:`PlainPage`, :class:`Int8Page` or the
+    model's own), plus the per-slot ``valid_len`` vector — and the host-side
+    slot bookkeeping (which request owns which page). The compiled prefill/
+    decode programs take ``state`` and ``valid`` as (donated) inputs and
+    return the updated ones; the server writes them back via :meth:`update`.
 
     Parameters
     ----------
@@ -73,26 +284,25 @@ class PagedKVCache:
     max_capacity : int
         Hard ceiling on the time axis (the model's ``max_length``).
     dtype : np.dtype
-        K/V element dtype (the model's parameter dtype; bf16 models
-        cache in bf16).
+        The model's parameter dtype (bf16 models cache in bf16).
     quantize : bool
-        Store pages as int8 with per-page-per-head fp32 scales
-        (``k_scale``/``v_scale``, (slots, H, 1, 1) per layer): ~0.5× the
-        bf16 page bytes. Pages quantize on write (``quant_cache_write``'s
-        running-max scale) and dequantize on read inside the decode
-        program; capacity buckets, donation and the one-dispatch step are
-        unchanged. Scale buffers are capacity-independent, so migrations
-        only pad the int8 pages.
+        Store pages as :class:`Int8Page`: ~0.5× the bf16 page bytes. Pages
+        quantize on write and dequantize on read inside the decode program;
+        capacity buckets, donation and the one-dispatch step are unchanged.
     windows : None or list
         The geometry layer by layer: ``None`` for a full page (time axis =
         the capacity bucket), or the length of a RING for a sliding-window
         layer, whose time axis is ``min(capacity, window)``: the model
         writes position p at ``p % length``, so a ring never holds more
         than its window and never grows past it. Not with ``quantize``.
+    page : None or type
+        The page record of a model that keeps its own format
+        (``decode_state_spec()["page"]``); by default :class:`Int8Page`
+        with ``quantize``, else :class:`PlainPage`.
     """
 
     def __init__(self, layers, heads, head_dim, slots, max_capacity,
-                 dtype=np.float32, quantize=False, windows=None):
+                 dtype=np.float32, quantize=False, windows=None, page=None):
         self.layers = int(layers)
         self.windows = [None if w is None else int(w)
                         for w in (windows or [None] * self.layers)]
@@ -106,16 +316,10 @@ class PagedKVCache:
         self.head_dim = int(head_dim)
         self.slots = int(slots)
         self.max_capacity = int(max_capacity)
-        self.quantize = bool(quantize)
-        self.dtype = np.dtype(np.int8) if self.quantize else np.dtype(dtype)
-        # what a non-quantized cache of the model's dtype would cost per
-        # element — the denominator of the bytes-saved accounting
-        self._ref_itemsize = np.dtype(dtype).itemsize
+        self.dtype = np.dtype(dtype)
+        self.page = page or (Int8Page if quantize else PlainPage)
         self.capacity = 0
-        self.k = None     # list[L] of (slots, H, layer_length, D) jax arrays
-        self.v = None
-        self.k_scale = None  # list[L] of (slots, H, 1, 1) fp32 (quantized)
-        self.v_scale = None
+        self.state = None     # list[L] of page records, once allocated
         self.valid = jnp.zeros((self.slots,), jnp.int32)
         self._free = list(range(self.slots))
         self._owner = [None] * self.slots
@@ -144,12 +348,11 @@ class PagedKVCache:
         return [min(int(tp), self.layer_length(i))
                 for i in range(self.layers)]
 
-    def page_bytes(self, tp, itemsize=None):
+    def page_bytes(self, tp):
         """Bytes of the K and V page of a prompt of bucket ``tp``, as the
         prefix store keeps it."""
-        itemsize = self.dtype.itemsize if itemsize is None else itemsize
-        return 2 * sum(self.page_lengths(tp)) * self.heads * self.head_dim \
-            * itemsize
+        return sum(page.prompt_bytes(n)
+                   for page, n in zip(self.state, self.page_lengths(tp)))
 
     def ensure_capacity(self, need):
         """Grow the buffers to the bucket that fits ``need`` (zero-padding
@@ -158,30 +361,19 @@ class PagedKVCache:
         migration happened — live programs for the old capacity stay
         cached, so shrinking traffic never re-migrates."""
         cap = self.capacity_bucket(need)
-        if cap <= self.capacity and self.k is not None:
+        if cap <= self.capacity and self.state is not None:
             return False
-        if self.k is None:
-            shapes = [(self.slots, self.heads, self.layer_length(i, cap),
-                       self.head_dim) for i in range(self.layers)]
-            self.k = [jnp.zeros(shape, self.dtype) for shape in shapes]
-            self.v = [jnp.zeros(shape, self.dtype) for shape in shapes]
-            if self.quantize:
-                sshape = (self.slots, self.heads, 1, 1)
-                self.k_scale = [jnp.zeros(sshape, jnp.float32)
-                                for _ in range(self.layers)]
-                self.v_scale = [jnp.zeros(sshape, jnp.float32)
-                                for _ in range(self.layers)]
+        if self.state is None:
+            self.state = [
+                self.page.zeros(self.slots, self.heads,
+                                self.layer_length(i, cap), self.head_dim,
+                                self.dtype) for i in range(self.layers)]
         else:
             # a ring that has reached its window stays as it is: until then
             # no position has wrapped, and padding keeps p % length == p
-            def grow(a, i):
-                more = self.layer_length(i, cap) - a.shape[2]
-                return jnp.pad(a, ((0, 0), (0, 0), (0, more), (0, 0))) \
-                    if more else a
-
-            self.k = [grow(k, i) for i, k in enumerate(self.k)]
-            self.v = [grow(v, i) for i, v in enumerate(self.v)]
-            # scale buffers are (slots, H, 1, 1) — capacity-independent
+            self.state = [
+                page.grow(self.layer_length(i, cap) - self.layer_length(i))
+                for i, page in enumerate(self.state)]
             self.migrations += 1
         self.capacity = cap
         return True
@@ -226,37 +418,27 @@ class PagedKVCache:
         return np.asarray([0 if (o is None or i in exclude) else 1
                            for i, o in enumerate(self._owner)], np.int32)
 
-    def update(self, k, v, valid, k_scale=None, v_scale=None):
-        """Install the arrays a compiled step returned (the old buffers
+    def update(self, state, valid):
+        """Install the state a compiled step returned (the old buffers
         were donated on TPU — they must not be touched again)."""
-        self.k, self.v, self.valid = list(k), list(v), valid
-        if k_scale is not None:
-            self.k_scale = list(k_scale)
-        if v_scale is not None:
-            self.v_scale = list(v_scale)
+        self.state, self.valid = list(state), valid
 
     # ------------------------------------------------------------ accounting
     def nbytes(self):
-        """Live page-buffer bytes (K + V + scales) — the measured side of
-        the quantized-cache acceptance ratio."""
-        if self.k is None:
-            return 0
-        total = sum(int(a.nbytes) for a in self.k)
-        total += sum(int(a.nbytes) for a in self.v)
-        if self.quantize:
-            total += sum(int(a.nbytes) for a in self.k_scale)
-            total += sum(int(a.nbytes) for a in self.v_scale)
-        return total
+        """Live page-buffer bytes (every leaf of the state: K, V, scales) —
+        the measured side of the quantized-cache acceptance ratio."""
+        return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(
+            self.state))
 
     def nbytes_unquantized(self, itemsize=None):
         """What the SAME geometry would cost unquantized — the denominator
         of the ≤ 0.55× bytes acceptance check. ``itemsize`` defaults to the
         model dtype's (pass 2 to compare against a bf16 cache)."""
-        if self.k is None:
+        if self.state is None:
             return 0
         elems = 2 * self.slots * self.heads * self.head_dim \
             * sum(self.layer_length(i) for i in range(self.layers))
-        return elems * (self._ref_itemsize if itemsize is None else itemsize)
+        return elems * (self.dtype.itemsize if itemsize is None else itemsize)
 
 
 def _host(stack):

@@ -2,7 +2,7 @@
 
 Speculative decoding amortizes the target model over k tokens per verify
 dispatch: a cheap DRAFT proposes k-1 tokens per slot, the target scores
-the whole window in ONE wide ``decode_step_speculative`` dispatch, and the
+the whole window in ONE wide ``decode_step`` dispatch (K = spec_k), and the
 longest sampled-prefix-equals-drafted-prefix is accepted (the first
 mismatching row's sample IS the resample — for a deterministic draft the
 proposal distribution is one-hot, so "sample y ~ p, accept iff y == d,
@@ -11,7 +11,7 @@ accept w.p. p(d), residual norm(max(p - q, 0)) = p with d masked out).
 Greedy requests therefore emit BYTE-IDENTICAL streams to plain greedy
 decode, and sampled requests emit the same per-(seed, position) tokens as
 the plain path — each emitted token is sampled at its own sequence
-position with the slot key folded exactly as ``decode_step_fixed`` would.
+position with the slot key folded exactly as the plain step would.
 
 Two drafts, one protocol (``join``/``propose``/``release``/``warm``):
 
@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from .. import _trace, engine
 from .batcher import ServeError
-from .kv_cache import PagedKVCache
+from .kv_cache import PagedKVCache, write_prompt
 
 __all__ = ["NGramDraft", "ModelDraft", "ngram_propose"]
 
@@ -126,7 +126,7 @@ class NGramDraft:
 class ModelDraft:
     """Device draft: a smaller model speaking the same fixed-capacity
     decode protocol (``decode_state_spec``/``forward_collect_kv``/
-    ``decode_step_fixed``) with its own slot-paged KV cache mirroring the
+    ``decode_step``) with its own slot-paged KV cache mirroring the
     target server's slots and capacity buckets. The draft model must share
     the target's vocabulary and cover its ``max_length``."""
 
@@ -170,7 +170,7 @@ class ModelDraft:
         model, plist = self.model, self._plist
         k = self._server.spec_k
 
-        def pure(params, kcs, vcs, valid, toks):
+        def pure(params, state, valid, toks):
             # trace-time bump: the zero-steady-state-retrace proof covers
             # the draft program too (tests/test_speculative.py)
             engine.decode_compile_counter.bump()
@@ -183,18 +183,18 @@ class ModelDraft:
                 # to write its K/V at valid+k-1 (else a full accept next
                 # round would attend over a hole) — its argmax is dropped
                 for j in range(k):
-                    logits, kcs, vcs = model.decode_step_fixed(
-                        _trace.F, x, kcs, vcs, valid + j)
-                    x = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    logits, state, _aux = model.decode_step(
+                        _trace.F, x[:, None], state, valid + j)
+                    x = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
                     if j < k - 1:
                         props.append(x)
             if props:
                 drafts = jnp.stack(props, axis=1)
             else:
                 drafts = jnp.zeros((toks.shape[0], 0), jnp.int32)
-            return kcs, vcs, drafts
+            return state, drafts
 
-        fn = self._server._jit(pure, donate=(1, 2),
+        fn = self._server._jit(pure, donate=(1,),
                                hint="draftstep@c%d" % capacity)
         self._step_fns[capacity] = fn
         return fn
@@ -204,22 +204,17 @@ class ModelDraft:
         if fn is not None:
             return fn
         model, plist = self.model, self._plist
-        zero = jnp.int32(0)
 
-        def pure(params, kcs, vcs, tokens, slot):
+        def pure(params, state, tokens, slot):
             engine.decode_compile_counter.bump()
             with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
                 t.param_store = {id(p): a for p, a in zip(plist, params)}
-                _logits, kvs = model.forward_collect_kv(_trace.F, tokens)
-            kcs = [jax.lax.dynamic_update_slice(
-                kc, kv[0].astype(kc.dtype), (slot, zero, zero, zero))
-                for kc, kv in zip(kcs, kvs)]
-            vcs = [jax.lax.dynamic_update_slice(
-                vc, kv[1].astype(vc.dtype), (slot, zero, zero, zero))
-                for vc, kv in zip(vcs, kvs)]
-            return kcs, vcs
+                _logits, kvs, _aux = model.forward_collect_kv(_trace.F,
+                                                              tokens)
+            # the whole bucket counts as live: the target's valid_len masks
+            return write_prompt(state, kvs, tokens.shape[1], slot)
 
-        fn = self._server._jit(pure, donate=(1, 2),
+        fn = self._server._jit(pure, donate=(1,),
                                hint="draftfill@t%dc%d" % (tp, capacity))
         self._fill_fns[(tp, capacity)] = fn
         return fn
@@ -236,9 +231,8 @@ class ModelDraft:
         engine.dispatch_counter.bump()
         fn = self._fill_fn(padded.shape[1], self.cache.capacity)
         params = [p.data()._data for p in self._plist]
-        kcs, vcs = fn(params, self.cache.k, self.cache.v,
-                      jnp.asarray(padded), jnp.int32(slot))
-        self.cache.update(kcs, vcs, self.cache.valid)
+        self.cache.state = fn(params, self.cache.state, jnp.asarray(padded),
+                              jnp.int32(slot))
 
     def propose(self, histories, k):
         """(slots, k-1) device proposals via ONE k-unrolled dispatch,
@@ -248,9 +242,8 @@ class ModelDraft:
         engine.dispatch_counter.bump()
         fn = self._step_fn(self.cache.capacity)
         params = [p.data()._data for p in self._plist]
-        kcs, vcs, drafts = fn(params, self.cache.k, self.cache.v,
-                              srv.cache.valid, srv._tok)
-        self.cache.update(kcs, vcs, self.cache.valid)
+        self.cache.state, drafts = fn(params, self.cache.state,
+                                      srv.cache.valid, srv._tok)
         return drafts
 
     def warm(self, tp_buckets=()):
@@ -260,13 +253,13 @@ class ModelDraft:
         params = [p.data()._data for p in self._plist]
         for tp in tp_buckets:
             fn = self._fill_fn(int(tp), self.cache.capacity)
-            kcs, vcs = fn(params, self.cache.k, self.cache.v,
-                          jnp.zeros((1, int(tp)), jnp.int32), jnp.int32(0))
-            self.cache.update(kcs, vcs, self.cache.valid)
+            self.cache.state = fn(
+                params, self.cache.state,
+                jnp.zeros((1, int(tp)), jnp.int32), jnp.int32(0))
         fn = self._step_fn(self.cache.capacity)
-        kcs, vcs, _d = fn(params, self.cache.k, self.cache.v,
-                          self._server.cache.valid, self._server._tok)
-        self.cache.update(kcs, vcs, self.cache.valid)
+        self.cache.state, _d = fn(params, self.cache.state,
+                                  self._server.cache.valid,
+                                  self._server._tok)
 
     # ----------------------------------------------- snapshot interface
     def export_executables(self):

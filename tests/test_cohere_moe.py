@@ -306,12 +306,12 @@ def test_cache_geometry_follows_the_layers():
     c = PagedKVCache(layers=4, heads=2, head_dim=16, slots=3,
                      max_capacity=128, windows=[16, 16, 16, None])
     c.ensure_capacity(8)
-    assert [k.shape for k in c.k] == [(3, 2, 8, 16)] * 4
-    c.k = [k + 1 for k in c.k]
+    assert [p.k.shape for p in c.state] == [(3, 2, 8, 16)] * 4
+    c.state = [p._replace(k=p.k + 1) for p in c.state]
     c.ensure_capacity(60)           # rings stop at their window
-    assert [k.shape[2] for k in c.k] == [16, 16, 16, 64]
-    assert all(float(k[:, :, :8].min()) == 1 and float(k[:, :, 8:].max()) == 0
-               for k in c.k)
+    assert [p.k.shape[2] for p in c.state] == [16, 16, 16, 64]
+    assert all(float(p.k[:, :, :8].min()) == 1
+               and float(p.k[:, :, 8:].max()) == 0 for p in c.state)
     assert c.page_lengths(32) == [16, 16, 16, 32]
     assert c.page_bytes(32) == 2 * (3 * 16 + 32) * 2 * 16 * 4
     assert c.nbytes() == c.nbytes_unquantized()

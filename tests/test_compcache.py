@@ -402,6 +402,24 @@ def test_snapshot_stale_fingerprint_falls_back(rng, tmp_path):
     assert engine.serve_compile_counter.count >= 1  # honest recompile
 
 
+def test_snapshot_of_an_older_format_is_refused(tmp_path):
+    """A manifest of another format (format 1: executables over lists of K
+    and V buffers) is refused at the manifest, never loaded against the
+    argument tree of this build's programs."""
+    from mxnet_tpu.cache.snapshot import FORMAT
+
+    _srv, prefix = _snapshot_server(_mlp(), tmp_path, buckets=(2,))
+    mpath = prefix + "-snapshot.json"
+    with open(mpath) as fh:
+        manifest = json.load(fh)
+    assert manifest["format"] == FORMAT
+    manifest["format"] = FORMAT - 1
+    with open(mpath, "w") as fh:
+        json.dump(manifest, fh)
+    with pytest.raises(ValueError, match="this build reads %d" % FORMAT):
+        mx.serve.load(prefix, snapshot=True)
+
+
 def test_snapshot_wrong_key_exec_falls_back(rng, tmp_path):
     """An exec file whose internal key disagrees with the manifest slot
     (mis-assembled artifact): that entry is rejected with a warning and

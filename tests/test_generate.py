@@ -11,14 +11,19 @@ classes + SLO-aware shedding on the admission queue, in-program sampling
 iterators, and the generative serve metrics.
 """
 import time
+from typing import Any, NamedTuple
 
 import numpy as np
 import pytest
 
+import jax
+import jax.numpy as jnp
+
 import mxnet_tpu as mx
-from mxnet_tpu import engine, nd
-from mxnet_tpu.models.gpt import gpt_nano
-from mxnet_tpu.serve import CacheError, PagedKVCache, ServerBusy, ServeTimeout
+from mxnet_tpu import _trace, engine, nd
+from mxnet_tpu.models.gpt import GPTModel, _CausalSelfAttention, gpt_nano
+from mxnet_tpu.serve import (CacheError, NGramDraft, PagedKVCache, ServerBusy,
+                             ServeTimeout)
 from mxnet_tpu.serve.batcher import DynamicBatcher
 
 
@@ -120,7 +125,7 @@ def test_paged_cache_slots_and_capacity_buckets():
     assert c.ensure_capacity(3) is False     # shrink never migrates
     assert c.ensure_capacity(9) is True      # pow2 growth, zero-padded
     assert c.capacity == 16 and c.migrations == 1
-    assert c.k[0].shape == (3, 2, 16, 4)
+    assert c.state[0].k.shape == (3, 2, 16, 4)
     s0 = c.acquire("a")
     s1 = c.acquire("b")
     s2 = c.acquire("c")
@@ -333,6 +338,171 @@ def test_generative_stats_and_profiler_events(model, rng, tmp_path):
     assert srv.name in agg["servers"]
     assert "decode_compile_counter" in agg
     srv.stop()
+
+
+# ------------------------------------------- the seam of the page's format
+class FusedPage(NamedTuple):
+    """A page kind the library does not know: K and V of a layer kept as
+    ONE leaf (2, slots, heads, length, head_dim). What ``serve/kv_cache.py``
+    asks of a page record: allocation, growth, and the traced operations
+    that move a prompt's or a slot's page in and out of the pool."""
+
+    kv: Any
+
+    @classmethod
+    def zeros(cls, slots, heads, length, head_dim, dtype):
+        return cls(jnp.zeros((2, slots, heads, length, head_dim), dtype))
+
+    def grow(self, more):
+        return FusedPage(jnp.pad(
+            self.kv, ((0, 0), (0, 0), (0, 0), (0, more), (0, 0))))
+
+    def _at(self, slot):
+        zero = jnp.int32(0)
+        return (zero, slot, zero, zero, zero)
+
+    def write_prompt(self, k, v, plen, slot):
+        return FusedPage(jax.lax.dynamic_update_slice(
+            self.kv, jnp.stack([k, v]).astype(self.kv.dtype),
+            self._at(slot)))
+
+    def read_prompt(self, slot, n):
+        _two, _slots, H, _length, D = self.kv.shape
+        page = jax.lax.dynamic_slice(self.kv, self._at(slot),
+                                     (2, 1, H, n, D))
+        return page[0, 0], page[1, 0]
+
+    def prompt_bytes(self, n):
+        _two, _slots, H, _length, D = self.kv.shape
+        return 2 * H * n * D * self.kv.dtype.itemsize
+
+    def take_slot(self, slot, fresh):
+        shape = self.kv.shape
+        return FusedPage(jax.lax.dynamic_slice(
+            self.kv, self._at(slot), shape[:1] + (1,) + shape[2:]))
+
+    def put_slot(self, slot, page):
+        return FusedPage(jax.lax.dynamic_update_slice(
+            self.kv, page.kv, self._at(slot)))
+
+
+class _FusedAttention(_CausalSelfAttention):
+    """The attention layer that handles a ``FusedPage`` (the eager decode
+    loop's plain pages go the library's way)."""
+
+    def step_cached(self, F, x, page, start, lengths=None):
+        if not isinstance(page, FusedPage):
+            return super().step_cached(F, x, page, start, lengths)
+        q, k_new, v_new = self._qkv_heads(F, x)
+        k = F.cache_write(page.kv[0], k_new, start)
+        v = F.cache_write(page.kv[1], v_new, start)
+        out = F.cached_attention(
+            q, k, v, start + 1 if lengths is None else lengths)
+        return (self.attn_out(self._merge_heads(F, out)),
+                FusedPage(jnp.stack([k, v])))
+
+
+class _FusedGPT(GPTModel):
+    """``gpt_nano`` serving its own page kind."""
+
+    def __init__(self):
+        super().__init__(vocab_size=256, units=64, num_layers=2, num_heads=2,
+                         max_length=64, dropout=0.0)
+        for blk in self.blocks:
+            blk.attn.__class__ = _FusedAttention
+
+    def decode_state_spec(self):
+        return dict(super().decode_state_spec(), page=FusedPage,
+                    int8_pages=False)
+
+
+def _served(model, prompts, n, **kwargs):
+    srv = mx.serve.GenerativeServer(model, slots=2, timeout_ms=60000.0,
+                                    **kwargs)
+    out = []
+    for p in prompts:        # one at a time: the third finds the first stored
+        s = srv.submit(p, max_new_tokens=n)
+        time.sleep(0.02)
+        _pump(srv, [s], ticks=400)
+        out.append(s.result(timeout_s=2))
+    stats = srv.stats()
+    srv.stop()
+    return out, stats, srv
+
+
+def test_a_page_kind_of_the_models_own_is_served_unseen(model, rng):
+    """The seam is where ISSUE 33 says it is: a page record defined HERE
+    and a model whose attention layer handles it go through
+    ``GenerativeServer`` (prefill, read-out into the prefix store, inject,
+    growth of the pool, step; and chunk by chunk) and yield the tokens of
+    plain ``gpt_nano`` on the same weights. ``serve/decoder.py`` carries the
+    state without looking inside."""
+    fused = _FusedGPT()
+    fused.initialize()
+    for src, dst in zip(model.collect_params().values(),
+                        fused.collect_params().values()):
+        dst.set_data(src.data())
+    a = rng.randint(0, 256, (5,)).tolist()
+    b = rng.randint(0, 256, (19,)).tolist()      # grows the pool: 16 -> 32
+    want, _stats, _srv = _served(model, [a, b, a], 6)
+    got, stats, srv = _served(fused, [a, b, a], 6)
+    assert got == want
+    assert stats["prefix_hits"] == 1 and stats["cache_migrations"] == 1
+    assert all(type(page) is FusedPage for page in srv.cache.state)
+    assert stats["kv_cache_bytes"] == 2 * 2 * (2 * 2 * 32 * 32) * 4
+    got, stats, _srv = _served(fused, [b], 6, prefix_cache=False,
+                               prefill_chunk=8)
+    assert got == want[1:2] and stats["prefill_chunks"] == 3
+
+
+@pytest.mark.parametrize("quantize", [None, "int8"])
+def test_one_decode_step_serves_step_verify_and_chunk(quantize, rng):
+    """ONE ``decode_step`` whatever K and whatever the pages' format: the
+    K = 1 call gives the K = 2 call's first row (to the last few bits: the
+    CPU's matmul sums a row in another order beside a second row), and a
+    greedy stream through the step, the verify (K = spec_k) and the chunk
+    (K = the chunk) programs is one token sequence."""
+    m = gpt_nano()
+    m.initialize()
+    cache = PagedKVCache(2, 2, 32, slots=2, max_capacity=64,
+                         quantize=quantize is not None)
+    cache.ensure_capacity(16)
+    plist = list(m.collect_params().values())
+
+    def decode_step(tokens, state, valid):
+        with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
+            t.param_store = {id(p): p.data()._data for p in plist}
+            return m.decode_step(_trace.F, jnp.asarray(tokens, jnp.int32),
+                                 state, jnp.asarray(valid, jnp.int32))
+
+    toks = rng.randint(0, 256, (2, 6))
+    _logits, state, aux = decode_step(toks[:, :4], cache.state, [0, 0])
+    assert aux is None
+    # (an int8 page's scale is a running max over the window written: on a
+    # page whose scale covers both tokens already, K = 2 moves it no more
+    # than K = 1 does)
+    _logits, covered, _aux = decode_step(toks[:, 4:], state, [4, 4])
+    if quantize:
+        state = [p._replace(k_scale=c.k_scale, v_scale=c.v_scale)
+                 for p, c in zip(state, covered)]
+    one, state1, _aux = decode_step(toks[:, 4:5], state, [4, 4])
+    two, state2, _aux = decode_step(toks[:, 4:], state, [4, 4])
+    assert one.shape == (2, 1, 256) and two.shape == (2, 2, 256)
+    np.testing.assert_allclose(np.asarray(one[:, 0]), np.asarray(two[:, 0]),
+                               rtol=0, atol=2e-6)
+    assert float(jnp.abs(one).max()) > 0.1
+    assert [type(p) for p in state1] == [type(p) for p in state2] \
+        == [cache.page] * 2
+
+    prompt = rng.randint(0, 256, (21,)).tolist()
+    kwargs = dict(prefix_cache=False, quantize=quantize)
+    (want,), _stats, _srv = _served(m, [prompt], 10, **kwargs)
+    (spec,), stats, _srv = _served(m, [prompt], 10, draft=NGramDraft(),
+                                   spec_k=3, **kwargs)
+    assert spec == want and stats["verify_dispatches"] > 0
+    (chunked,), stats, _srv = _served(m, [prompt], 10, prefill_chunk=8,
+                                      **kwargs)
+    assert chunked == want and stats["prefill_chunks"] == 3
 
 
 # ------------------------------------------------------------------ bench

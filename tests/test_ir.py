@@ -41,6 +41,10 @@ def rng():
 
 
 def _reset_ir_state():
+    # a lazy window an earlier test of this process left pending (ops never
+    # read back) would flush, and compile, at the next sync point: inside
+    # the test that counts compiles. Flush it before the counters reset.
+    engine.flush()
     base._BULK_CACHE.clear()
     base._TAPE_CACHE.clear()
     base._IR_CACHE.clear()

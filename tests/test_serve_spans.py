@@ -221,12 +221,19 @@ def test_profiler_off_one_switch_read_a_tick_and_no_span(model, monkeypatch):
     srv.stop()
 
 
-def test_each_kind_of_program_carries_its_own_name(model):
-    srv = mx.serve.GenerativeServer(model, slots=SLOTS, timeout_ms=60000.0)
+@pytest.mark.parametrize("quantize", [None, "int8"])
+def test_each_kind_of_program_carries_its_own_name(quantize):
+    """One program a kind whatever the pages' format: the names say the
+    kind and the buckets, never the format."""
+    model = gpt_nano()      # its own: ``quantize`` rewrites the weights
+    model.initialize()
+    srv = mx.serve.GenerativeServer(model, slots=SLOTS, timeout_ms=60000.0,
+                                    quantize=quantize)
     srv.warmup(prompt_buckets=[5, 12], max_tokens=40)
     spec = mx.serve.GenerativeServer(model, slots=SLOTS, timeout_ms=60000.0,
                                      draft=NGramDraft(), spec_k=3,
-                                     prefill_chunk=8, prefix_cache=False)
+                                     prefill_chunk=8, prefix_cache=False,
+                                     quantize=quantize)
     spec.warmup(prompt_buckets=[5], max_tokens=40)
     names = {}
     for kind, fns in (("step", srv._decode_fns),

@@ -189,7 +189,7 @@ def logit_mae(fp_model, q_model, prompts):
     return float(np.mean(maes)), float(np.mean(agree))
 
 
-def _time_decode_steps(srv, quant, n):
+def _time_decode_steps(srv, n):
     """Median per-step latency (us) of the compiled decode program at
     full slot occupancy, driving the real cache-donation update between
     steps — the stable measurement (end-to-end server ticks swing with
@@ -208,18 +208,10 @@ def _time_decode_steps(srv, quant, n):
     temps = jnp.asarray(np.zeros(srv.slots, np.float32))
 
     def step():
-        if quant:
-            out = fn(params, srv.cache.k, srv.cache.k_scale, srv.cache.v,
-                     srv.cache.v_scale, srv.cache.valid, srv._tok,
-                     active, keys, temps)
-            kcs, kss, vcs, vss, valid, nxt = out
-            srv.cache.update(kcs, vcs, valid, kss, vss)
-        else:
-            out = fn(params, srv.cache.k, srv.cache.v, srv.cache.valid,
-                     srv._tok, active, keys, temps)
-            kcs, vcs, valid, nxt = out
-            srv.cache.update(kcs, vcs, valid)
-        srv._tok = nxt
+        out = fn(params, srv.cache.state, srv.cache.valid, srv._tok, active,
+                 keys, temps)
+        state, valid, srv._tok = out
+        srv.cache.update(state, valid)
         return out
 
     jax.block_until_ready(step())  # first call outside the timed region
@@ -263,10 +255,10 @@ def run_wide(units=256, slots=8, mode="int8", steps=30, seed=0):
     try:
         # alternating half-blocks so host-clock drift cannot favour a side
         half = max(steps // 2, 5)
-        bf_a = _time_decode_steps(bf_srv, False, half)
-        q_a = _time_decode_steps(q_srv, True, half)
-        bf_b = _time_decode_steps(bf_srv, False, half)
-        q_b = _time_decode_steps(q_srv, True, half)
+        bf_a = _time_decode_steps(bf_srv, half)
+        q_a = _time_decode_steps(q_srv, half)
+        bf_b = _time_decode_steps(bf_srv, half)
+        q_b = _time_decode_steps(q_srv, half)
     finally:
         watchdog.disarm()
     recompiles = engine.decode_compile_counter.count

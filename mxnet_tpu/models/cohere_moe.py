@@ -110,7 +110,9 @@ class _GroupedAttention(HybridBlock):
         than any position), and every slot at or before the position is live: a
         wrapped ring holds exactly the window. So a row reads the first
         ``min(position + 1, L)`` slots of its buffer, and none where
-        ``active`` (B,) 0/1 is 0. The read is ``F.cached_attention``, which
+        ``active`` (B,) 0/1 is 0; such a row writes nothing either
+        (``F.cache_write`` is told ``active``). The read is
+        ``F.cached_attention``, which
         today lowers to the dense masked attention over rows x L on every
         backend: head width 128 would take the row path of the Pallas
         kernel ``decode_attention`` (blocks of 512 slots, fetched once for
@@ -120,8 +122,8 @@ class _GroupedAttention(HybridBlock):
         L = page.k.shape[2]
         q, k, v = self._qkv(F, h, F.reshape(position, shape=(-1, 1)))
         at = position % L
-        page = PlainPage(F.cache_write(page.k, k, at),
-                         F.cache_write(page.v, v, at))
+        page = PlainPage(F.cache_write(page.k, k, at, active),
+                         F.cache_write(page.v, v, at, active))
         lengths = F.minimum(position + 1, L) * active
         out = F.cached_attention(q, page.k, page.v, lengths)
         return self._merge(F, out), page

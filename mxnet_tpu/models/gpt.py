@@ -84,7 +84,9 @@ class _CausalSelfAttention(HybridBlock):
         trace time:
 
         - a ``PlainPage`` is written by ``F.cache_write`` (its docstring
-          says what each kind of ``start`` lowers to) and read by
+          says what each kind of ``start`` lowers to; with per-slot
+          lengths and T = 1 it is told them, and a free slot's page is
+          left as it lies) and read by
           ``F.cached_attention``: with per-slot lengths and T = 1 on a TPU
           the Pallas kernel ``decode_attention`` (this model's head widths
           take its column path: only the 128-position blocks that hold a
@@ -122,8 +124,10 @@ class _CausalSelfAttention(HybridBlock):
             out = F.scaled_dot_attention(q, k_deq, v_deq,
                                          F.lesser_equal(pos, limit))
         else:
-            page = PlainPage(F.cache_write(page.k, k_new, start),
-                             F.cache_write(page.v, v_new, start))
+            # a window of T > 1 (verify, chunk) is written in every row
+            live = () if lengths is None or x.shape[1] > 1 else (lengths,)
+            page = PlainPage(F.cache_write(page.k, k_new, start, *live),
+                             F.cache_write(page.v, v_new, start, *live))
             if lengths is None:
                 lengths = start + 1
             out = F.cached_attention(q, page.k, page.v, lengths)

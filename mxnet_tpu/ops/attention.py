@@ -352,7 +352,7 @@ def masked_softmax(x, mask=None, *, axis=-1):
 
 
 @register_op("cache_write", nondiff=True)
-def cache_write(cache, update, index):
+def cache_write(cache, update, index, live=None):
     """Write ``update`` (B, H, T, D) into the fixed-capacity KV cache
     ``cache`` (B, H, C, D) at time offset ``index`` along axis 2 — the
     decode-cache primitive: the cache shape NEVER changes across steps, so
@@ -361,36 +361,49 @@ def cache_write(cache, update, index):
 
     ``index`` is a scalar (whole-batch write at one offset: prefill, the
     uniform imperative decode loop) or a per-row ``(B,)`` vector (continuous
-    batching: each slot is at its own position). With the cache buffer
+    batching: each slot is at its own position). ``live`` (B,), where given,
+    says which rows hold a stream (the decode step's per-slot lengths or its
+    0/1 ``active``: not 0 is live): a row that is not live keeps its cache
+    as it was. Not given, every row is written. With the cache buffer
     donated, every path updates it in place. What each lowers to:
 
     - scalar ``index``: one ``lax.dynamic_update_slice``;
     - per-row ``index``, one token a row, on a TPU, where the shapes tile
       (``kv_write.tiles``) and no device mesh is being traced: the Pallas
       kernel ``kv_cache_write``, one pass over the blocks that hold the
-      rows' positions (128-position blocks with the capacity on the lanes
-      for head widths under 128; one sublane tile of rows for head widths
-      that are whole lane tiles);
+      live rows' positions (128-position blocks with the capacity on the
+      lanes for head widths under 128; one sublane tile of rows for head
+      widths that are whole lane tiles), and nothing moved for a row that
+      is not live;
     - per-row ``index`` otherwise: ``vmap(dynamic_update_slice)``, which
       is a ``scatter``, which XLA on the TPU expands into a serial
       ``while`` loop of B column updates.
 
-    All three give the same bits. Writes past the capacity are the caller's
-    bug; like dynamic_update_slice, the start index clamps to ``C - T``."""
+    All three give the same bits, with ``live`` too: the two dense ones
+    honour it by a select on the update (a row that is not live writes
+    back what it read). Writes past the capacity are the caller's bug;
+    like dynamic_update_slice, the start index clamps to ``C - T``."""
     index = jnp.asarray(index, jnp.int32)
     update = update.astype(cache.dtype)
     zero = jnp.int32(0)
+    # one flag a row, to be held against the row's update
+    keep = (None if live is None
+            else (jnp.asarray(live) != 0).reshape(-1, 1, 1, 1))
+
+    def write(c, u, at, keep):
+        if keep is not None:
+            u = jnp.where(keep, u, jax.lax.dynamic_slice(c, at, u.shape))
+        return jax.lax.dynamic_update_slice(c, u, at)
+
     if index.ndim == 0:
-        return jax.lax.dynamic_update_slice(cache, update,
-                                            (zero, zero, index, zero))
+        return write(cache, update, (zero, zero, index, zero), keep)
     if is_tpu_backend() and not under_mesh():
         from .pallas import kv_write
 
         if kv_write.tiles(cache.shape, update.shape, cache.dtype):
-            return kv_write.kv_cache_write(cache, update, index)
-    return jax.vmap(
-        lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (zero, i, zero))
-    )(cache, update, index)
+            return kv_write.kv_cache_write(cache, update, index, live)
+    return jax.vmap(lambda c, u, i, k: write(c, u, (zero, i, zero), k))(
+        cache, update, index, keep)
 
 
 @register_op("quant_cache_write", nondiff=True, n_outputs=2)

@@ -13,10 +13,14 @@ shape ever changes across decode steps**. What that write lowers to depends
 on the call (``ops/attention.py: cache_write``): a prefill or an inject
 writes a whole window at a scalar offset, one
 ``lax.dynamic_update_slice``; the decode
-step writes one token per slot at per-slot positions, on a TPU the Pallas
-kernel ``kv_cache_write`` (one pass over the 128-position blocks the
-positions fall in), elsewhere ``vmap(dynamic_update_slice)``, a
-``scatter`` that XLA runs as a serial loop over the slots. What the read
+step writes one token per live slot at per-slot positions, on a TPU the
+Pallas kernel ``kv_cache_write`` (one pass over the 128-position blocks the
+live slots' positions fall in: a free slot's page is not touched),
+elsewhere ``vmap(dynamic_update_slice)``, a ``scatter`` that XLA runs as
+a serial loop over the slots (there a free slot writes back what it read).
+A free slot's page is next written by the join that takes it (the
+prefill's or the inject's ``write_prompt``, from position 0), and until
+then no program reads it. What the read
 lowers to (``ops/attention.py: cached_attention``): the decode step hands
 per-slot lengths, 0 for a free slot, and on a TPU the Pallas kernel
 ``decode_attention`` fetches only the blocks that hold a slot's live

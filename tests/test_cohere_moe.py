@@ -215,21 +215,50 @@ def test_flash_kernel_takes_grouped_heads_and_the_window():
         np.testing.assert_allclose(got, want, atol=2e-5)
 
 
-def test_row_write_at_head_width_128_matches_the_scatter():
-    """``kv_cache_write``'s row path (D a whole lane tile), interpret mode,
-    bf16: bit-identical to vmap(dynamic_update_slice)."""
+# which of the five slots hold a stream (PR 34): a length or a 0/1 flag, 0
+# for a free slot. The slots stand at 0, 17, 63, 31 (17's tile of 16 rows in
+# bf16) and 70, which clamps
+_ROW_WRITE_LIVE = {
+    "not_told": None,
+    "no_slot_live": [0, 0, 0, 0, 0],
+    "one_slot_live": [0, 0, 64, 0, 0],
+    "some_slots_live_not_side_by_side": [1, 0, 1, 0, 1],
+    "all_slots_live": [1, 18, 64, 32, 1],
+    "two_live_slots_in_one_tile_index": [0, 18, 0, 32, 0],
+    "live_at_a_clamped_and_a_negative_position": [0, 0, 0, 1, 1],
+}
+
+
+@pytest.mark.parametrize("live", list(_ROW_WRITE_LIVE))
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_row_write_at_head_width_128_matches_the_scatter(dtype, live):
+    """``kv_cache_write``'s row path (D a whole lane tile), interpret mode:
+    bit-identical to vmap(dynamic_update_slice) in the slots it is told are
+    live (all, where it is not told), no bit of the others changed."""
     from mxnet_tpu.ops.pallas import kv_write
 
     rs = np.random.RandomState(3)
-    cache = jnp.asarray(rs.normal(size=(5, 2, 64, 128)), jnp.bfloat16)
-    upd = jnp.asarray(rs.normal(size=(5, 2, 1, 128)), jnp.bfloat16)
-    idx = jnp.asarray([0, 17, 63, 31, 70], jnp.int32)       # one clamps
+    cache = jnp.asarray(rs.normal(size=(5, 2, 64, 128)), dtype)
+    upd = jnp.asarray(rs.normal(size=(5, 2, 1, 128)), dtype)
+    idx = [0, 17, 63, 31, 70]                               # one clamps
+    if live == "live_at_a_clamped_and_a_negative_position":
+        idx[3] = -40
+    idx = jnp.asarray(idx, jnp.int32)
+    live = _ROW_WRITE_LIVE[live]
     assert kv_write.tiles(cache.shape, upd.shape, cache.dtype)
-    got = kv_write.kv_cache_write(cache, upd, idx, interpret=True)
+    told = () if live is None else (jnp.asarray(live, jnp.int32),)
+    got = kv_write.kv_cache_write(cache, upd, idx, *told, interpret=True)
     zero = jnp.int32(0)
     want = jax.vmap(lambda c, u, i: jax.lax.dynamic_update_slice(
         c, u, (zero, i, zero)))(cache, upd, idx)
+    if live is not None:
+        want = jnp.where((jnp.asarray(live) != 0).reshape(-1, 1, 1, 1), want,
+                         cache)
     assert (np.asarray(got, np.float32) == np.asarray(want, np.float32)).all()
+    if live is not None and all(live):
+        untold = kv_write.kv_cache_write(cache, upd, idx, interpret=True)
+        assert (np.asarray(got, np.float32)
+                == np.asarray(untold, np.float32)).all()
 
 
 # ------------------------------------------------ (d) no token dropped

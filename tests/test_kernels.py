@@ -386,12 +386,17 @@ def test_flash_attention_bf16_fwd_and_grads_match_oracle():
 
 
 # ------------------------------------------------ kv_cache_write (PR 29)
-def _vmap_dus(cache, update, index):
-    """Today's per-slot write, the kernel's reference: bit for bit."""
+def _vmap_dus(cache, update, index, live=None):
+    """Today's per-slot write, the kernel's reference, bit for bit: the
+    live rows as ``vmap(dynamic_update_slice)``, the other rows untouched."""
     zero = jnp.int32(0)
-    return jax.vmap(
+    written = jax.vmap(
         lambda c, u, i: jax.lax.dynamic_update_slice(c, u, (zero, i, zero))
     )(cache, update, jnp.asarray(index, jnp.int32))
+    if live is None:
+        return written
+    return jnp.where((jnp.asarray(live) != 0).reshape(-1, 1, 1, 1), written,
+                     cache)
 
 
 # the first slot's position; the other three slots stay at 5, C - 2 and 64
@@ -405,10 +410,22 @@ _KV_POSITIONS = {
     "negative_counts_from_the_end": lambda C: -3,
     "far_negative_clamps_to_zero": lambda C: -2 * C,
 }
+# which of six slots hold a stream (PR 34), by what the decode step hands
+# over: a length, 0 for a free slot. The slots stand at 5, C - 2, 64, 70
+# (the block of 64), -3 and 3 * C + 7
+_KV_LIVE = {
+    "no_slot_live": [0, 0, 0, 0, 0, 0],
+    "one_slot_live": [0, 0, 65, 0, 0, 0],
+    "some_slots_live_not_side_by_side": [6, 0, 65, 0, 0, 1],
+    "all_slots_live": [6, 1, 65, 71, 9, 1],
+    "two_live_slots_in_one_block_index": [0, 0, 65, 71, 0, 0],
+    "live_at_negative_and_clamped_positions": [0, 0, 0, 0, 9, 2],
+}
 
 
 @pytest.mark.parametrize("where", list(_KV_POSITIONS) + [
-    "all_slots_equal", "more_slots_than_lanes"])
+    "all_slots_equal", "more_slots_than_lanes"] + list(_KV_LIVE) + [
+    "more_slots_than_lanes_a_third_live"])
 @pytest.mark.parametrize("C", [128, 1024])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_kv_cache_write_interpret_matches_dynamic_update_slice(dtype, C,
@@ -416,34 +433,51 @@ def test_kv_cache_write_interpret_matches_dynamic_update_slice(dtype, C,
     """The Pallas K/V column write in interpret mode against
     ``vmap(dynamic_update_slice)``: the same bits at block edges, with the
     start clamped as ``dynamic_update_slice`` clamps it, with several slots
-    at one position, and every other lane and slot untouched."""
+    at one position, and every other lane and slot untouched; told which
+    slots are live, the same bits in those and no bit of the others
+    changed, with none, one, some and all live (all live is the call that
+    is not told)."""
     from mxnet_tpu.ops.pallas import kv_write
 
-    S, H = (130, 1) if where == "more_slots_than_lanes" else (4, 3)
+    S, H = (130, 1) if where.startswith("more_slots_than_lanes") else (4, 3)
+    live = None
+    if where in _KV_LIVE:
+        S, live = 6, _KV_LIVE[where]
     D = 16 if dtype == jnp.bfloat16 else 8      # one sublane tile
     ks = jax.random.split(jax.random.PRNGKey(C), 2)
     cache = jax.random.normal(ks[0], (S, H, C, D), dtype)
     update = jax.random.normal(ks[1], (S, H, 1, D), dtype)
     if where == "all_slots_equal":
         index = [77] * S
-    elif where == "more_slots_than_lanes":
+    elif where.startswith("more_slots_than_lanes"):
         index = [(37 * i) % C for i in range(S)]
+        if where.endswith("a_third_live"):
+            live = [(i + 1) * (i % 3 == 0) for i in range(S)]
+    elif live is not None:
+        index = [5, C - 2, 64, 70, -3, 3 * C + 7]
     else:
         index = [_KV_POSITIONS[where](C), 5, C - 2, 64]
     assert kv_write.tiles(cache.shape, update.shape, dtype)
+    told = () if live is None else (jnp.asarray(live, jnp.int32),)
     got = kv_write.kv_cache_write(cache, update, jnp.asarray(index, jnp.int32),
-                                  interpret=True)
-    want = _vmap_dus(cache, update, index)
+                                  *told, interpret=True)
+    want = _vmap_dus(cache, update, index, live)
     assert got.dtype == want.dtype and got.shape == want.shape
     as_bits = lambda a: np.asarray(a.astype(jnp.float32)).view(np.uint32)
     np.testing.assert_array_equal(as_bits(got), as_bits(want))
-    # and stated on its own: one column a slot changed, nothing else did
+    # and stated on its own: one column a live slot changed, nothing else did
     landed = np.clip([i + C if i < 0 else i for i in index], 0, C - 1)
+    wrote = np.ones(S, bool) if live is None else np.asarray(live) != 0
     kept = np.ones((S, H, C, D), bool)
-    kept[np.arange(S), :, landed, :] = False
+    kept[np.arange(S)[wrote], :, landed[wrote], :] = False
     np.testing.assert_array_equal(as_bits(got)[kept], as_bits(cache)[kept])
     np.testing.assert_array_equal(
-        as_bits(got)[np.arange(S), :, landed, :], as_bits(update)[:, :, 0, :])
+        as_bits(got)[np.arange(S)[wrote], :, landed[wrote], :],
+        as_bits(update)[wrote, :, 0, :])
+    if where == "all_slots_live":
+        untold = kv_write.kv_cache_write(
+            cache, update, jnp.asarray(index, jnp.int32), interpret=True)
+        np.testing.assert_array_equal(as_bits(got), as_bits(untold))
 
 
 def _kv_gate_case(case):
@@ -462,25 +496,33 @@ def _kv_gate_case(case):
 @pytest.mark.parametrize("case", [
     "scalar_index", "window_of_4", "capacity_64", "head_dim_192",
     "head_dim_off_the_sublane_tile", "under_a_mesh", "on_the_cpu",
-    "tpu_and_tiles", "head_dim_128"])
+    "tpu_and_tiles", "head_dim_128", "scalar_index_told_the_live_rows",
+    "window_of_4_told_the_live_rows", "under_a_mesh_told_the_live_rows",
+    "on_the_cpu_told_the_live_rows", "tpu_and_tiles_told_the_live_rows",
+    "head_dim_128_told_the_live_rows"])
 def test_cache_write_gate(monkeypatch, case):
     """``cache_write`` decides at trace time, from what it can see: only a
     per-slot index with one token a slot, shapes that tile, a TPU and no
     device mesh reach the kernel (head widths under a lane tile by the
     column path, whole lane tiles such as 128 by the row path); every other
-    call keeps today's path."""
+    call keeps today's path. Told which rows are live, the kernel, the one
+    ``dynamic_update_slice`` of a scalar index and the scatter agree: the
+    live rows written, the bits of the others as they were."""
     from mxnet_tpu import parallel
     from mxnet_tpu.ops import attention as A
     from mxnet_tpu.ops.pallas import kv_write
 
     calls = []
+    told = case.endswith("_told_the_live_rows")
+    case = case.replace("_told_the_live_rows", "")
     reaches = case in ("tpu_and_tiles", "head_dim_128")
 
-    def kernel(cache, update, index):
+    def kernel(cache, update, index, live=None):
         calls.append(cache.shape)
         if not reaches:
             raise AssertionError("%s reached the kernel" % case)
-        return kv_write_orig(cache, update, index, interpret=True)
+        assert (live is not None) == told
+        return kv_write_orig(cache, update, index, live, interpret=True)
 
     kv_write_orig = kv_write.kv_cache_write
     monkeypatch.setattr(kv_write, "kv_cache_write", kernel)
@@ -490,23 +532,25 @@ def test_cache_write_gate(monkeypatch, case):
     ks = jax.random.split(jax.random.PRNGKey(3), 2)
     cache = jax.random.normal(ks[0], cshape, jnp.float32)
     update = jax.random.normal(ks[1], ushape, jnp.float32)
+    live = (jnp.asarray([3, 0, 0, 1], jnp.int32),) if told else ()
     if case == "under_a_mesh":
         with parallel.use_mesh(parallel.make_mesh({"dp": -1})):
-            got = A.cache_write(cache, update, index)
+            got = A.cache_write(cache, update, index, *live)
     else:
-        got = A.cache_write(cache, update, index)
-    if index.ndim == 0:
-        want = jax.lax.dynamic_update_slice(cache, update, (0, 0, index, 0))
-    else:
-        want = _vmap_dus(cache, update, index)
+        got = A.cache_write(cache, update, index, *live)
+    want = _vmap_dus(cache, update, jnp.broadcast_to(index, cshape[:1]),
+                     *live)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert len(calls) == (1 if reaches else 0)
 
 
-def test_server_tokens_same_with_the_kv_write_kernel(monkeypatch):
+@pytest.mark.parametrize("slots", [3, 5])
+def test_server_tokens_same_with_the_kv_write_kernel(monkeypatch, slots):
     """``GenerativeServer``'s greedy tokens with the kernel forced on (the
     TPU gate open, interpret mode standing in for the chip) are the tokens
-    it serves without it: gpt_nano's widths over a 128-token capacity."""
+    it serves without it: gpt_nano's widths over a 128-token capacity, with
+    every slot taken and with two that never hold a stream (the kernel is
+    told which slots are live and leaves the others' pages as they lie)."""
     import mxnet_tpu as mx
     from mxnet_tpu.models.gpt import GPTModel
     from mxnet_tpu.ops import attention as A
@@ -520,9 +564,9 @@ def test_server_tokens_same_with_the_kv_write_kernel(monkeypatch):
         model = GPTModel(vocab_size=256, units=64, num_layers=2, num_heads=2,
                          max_length=128, dropout=0.0)
         model.initialize()
-        with mx.serve.GenerativeServer(model, slots=3,
+        with mx.serve.GenerativeServer(model, slots=slots,
                                        timeout_ms=120000.0) as srv:
-            streams = [srv.submit(p, max_new_tokens=70 + 3 * i)
+            streams = [srv.submit(p, max_new_tokens=40 + 15 * i)
                        for i, p in enumerate(prompts)]
             tokens = [s.result(120) for s in streams]
             assert srv.cache.capacity == 128
@@ -532,9 +576,9 @@ def test_server_tokens_same_with_the_kv_write_kernel(monkeypatch):
     calls = []
     orig = kv_write.kv_cache_write
 
-    def forced(cache, update, index):
+    def forced(cache, update, index, live):
         calls.append(cache.shape)
-        return orig(cache, update, index, interpret=True)
+        return orig(cache, update, index, live, interpret=True)
 
     monkeypatch.setattr(A, "is_tpu_backend", lambda: True)
     monkeypatch.setattr(kv_write, "kv_cache_write", forced)
@@ -543,7 +587,8 @@ def test_server_tokens_same_with_the_kv_write_kernel(monkeypatch):
     monkeypatch.setattr(decode_attention, "tiles", lambda *shapes: False)
     with_kernel = serve()
     # K and V of two layers, in every decode program traced
-    assert calls and len(calls) % 4 == 0 and set(calls) == {(3, 2, 128, 32)}
+    assert calls and len(calls) % 4 == 0
+    assert set(calls) == {(slots, 2, 128, 32)}
     assert with_kernel == plain
 
 
@@ -771,7 +816,8 @@ def test_server_tokens_same_with_the_decode_attention_kernel(monkeypatch,
     monkeypatch.setattr(A, "_DECODE_ROW_PATH", True)
     monkeypatch.setattr(K, "decode_attention", forced)
     monkeypatch.setattr(kv_write, "kv_cache_write",
-                        lambda c, u, i: write(c, u, i, interpret=True))
+                        lambda c, u, i, live: write(c, u, i, live,
+                                                    interpret=True))
     with_kernel, _ = _serve_in_waves(make, prompts, new_tokens, slots=3)
     # one call a layer in every decode program traced, on these buffers
     assert calls and set(calls) == buffers

@@ -127,27 +127,34 @@ def test_flash_valid_len_compiles_fwd_bwd(one_chip):
     assert all(n in text for n in ("flash_fwd", "flash_dq", "flash_dkv"))
 
 
+@pytest.mark.parametrize("told", [False, True],
+                         ids=["every_slot", "told_the_live_slots"])
 @pytest.mark.parametrize("shape,dtype", [
     ((8, 12, 1024, 64), jnp.bfloat16),     # GPT-2 small, the smoke's server
+    ((32, 20, 1024, 64), jnp.bfloat16),    # gpt2-large.chat-decode's pages
     ((32, 25, 1024, 64), jnp.float32),     # GPT-2 XL's heads, fp32 pages
     ((160, 4, 128, 16), jnp.bfloat16),     # more slots than lanes
     ((32, 8, 4096, 128), jnp.bfloat16),    # head width 128: a window ring
     ((32, 8, 8192, 128), jnp.float32)])    # ... and a full page, fp32
-def test_kv_cache_write_compiles_in_place(one_chip, shape, dtype):
-    """The K/V column write alone: Mosaic takes the blocks and the lane
-    rotate (of 16-bit values too; at head width 128 the row blocks and the
-    sublane select), the donated buffer is the result, and no ``while``
-    (the scatter's loop over the slots) is left."""
+def test_kv_cache_write_compiles_in_place(one_chip, shape, dtype, told):
+    """The K/V column write alone, told which slots are live or not: Mosaic
+    takes the kernel's own DMA of a dynamic 128-lane window out of the
+    buffer left in HBM and the lane rotate (of 16-bit values too; at head
+    width 128 a window of one sublane tile of rows and the sublane select),
+    the donated buffer is the result and nothing cache-sized stands beside
+    it, and no ``while`` (the scatter's loop over the slots) is left."""
     import re
 
     from mxnet_tpu.ops.pallas import kv_write
 
     S, H, C, D = shape
     assert kv_write.tiles(shape, (S, H, 1, D), dtype)
+    live = (jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip),) * told
     compiled = jax.jit(kv_write.kv_cache_write, donate_argnums=(0,)).lower(
         jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip),
         jax.ShapeDtypeStruct((S, H, 1, D), dtype, sharding=one_chip),
-        jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip)).compile()
+        jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip),
+        *live).compile()
     assert "kv_cache_write" in compiled.as_text()
     assert not re.search(r" while\(", compiled.as_text())
     mem = compiled.memory_analysis()
@@ -223,7 +230,8 @@ def test_decode_step_reads_and_writes_kv_with_the_kernels(
         one_chip, monkeypatch, S, H, Hkv, C, D):
     """A decode step in small (two layers: QKV projection, ``cache_write``
     of K and V at per-slot positions, ``cached_attention`` over per-slot
-    lengths, 0 for a free slot) over the serving cells' buffers, donated.
+    lengths, 0 for a free slot, which the write is told too) over the
+    serving cells' buffers, donated.
     What interpret mode cannot see: Mosaic takes both kernels at these
     widths; the view a kernel takes of a buffer (``(S,H,D,C)`` for head
     widths under 128) is a bitcast of the layout the device holds it in, so
@@ -252,8 +260,8 @@ def test_decode_step_reads_and_writes_kv_with_the_kernels(
             q = heads(qkv[:, :H * D], H)
             k = heads(qkv[:, H * D:(H + Hkv) * D], Hkv)
             v = heads(qkv[:, (H + Hkv) * D:], Hkv)
-            kc = A.cache_write(kc, k, pos % C)
-            vc = A.cache_write(vc, v, pos % C)
+            kc = A.cache_write(kc, k, pos % C, lengths)
+            vc = A.cache_write(vc, v, pos % C, lengths)
             o = A.cached_attention(q, kc, vc, lengths)
             x = x + jnp.dot(jnp.transpose(o, (0, 2, 1, 3)).reshape(S, H * D),
                             wo)

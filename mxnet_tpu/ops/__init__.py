@@ -4,6 +4,7 @@ from . import detection  # noqa: F401
 from . import control_flow  # noqa: F401
 from . import attention  # noqa: F401
 from . import moe  # noqa: F401
+from . import retention  # noqa: F401
 from . import ctc  # noqa: F401
 from . import roi  # noqa: F401
 from . import rcnn  # noqa: F401

@@ -189,26 +189,37 @@ def _grouped_attention(q, k, v, mask, causal, scale, window):
 
 
 @register_op("rotary", nondiff=True)
-def rotary(x, positions, *, theta=10000.0):
+def rotary(x, positions, *, theta=10000.0, pairing="interleaved"):
     """Rotary position embedding over the whole head width of ``x``
-    (B, H, T, D), interleaved pairs: ``(x[2i], x[2i+1])`` turn by
-    ``positions * theta ** (-2i / D)``. ``positions`` is (T,) or per row
-    (B, T), any integer type. Angles, sines and the rotation are float32;
-    the result is rounded once to ``x``'s type.
+    (B, H, T, D): pair i turns by ``positions * theta ** (-2i / D)``.
+    ``pairing`` says which elements are a pair: ``"interleaved"``,
+    ``(x[2i], x[2i+1])``, or ``"half"``, ``(x[i], x[i + D/2])``.
+    ``positions`` is (T,) or per row (B, T), any integer type. Angles, sines
+    and the rotation are float32; the result is rounded once to ``x``'s
+    type.
 
-    The pair's other element, signed (``(-x[2i+1], x[2i])``), is ``x``
-    times a fixed D x D matrix of 0 and +-1: exact in any type, one small
-    matmul, and it leaves the lane axis alone (a reshape to pairs or a
-    lane rotation would stand as float32 copies of a whole prompt's q)."""
+    The interleaved pair's other element, signed (``(-x[2i+1], x[2i])``), is
+    ``x`` times a fixed D x D matrix of 0 and +-1: exact in any type, one
+    small matmul, and it leaves the lane axis alone (a reshape to pairs or a
+    lane rotation would stand as float32 copies of a whole prompt's q). The
+    half-split pair's (``(-x[i + D/2], x[i])``) is the two halves swapped,
+    the first negated."""
     D = x.shape[-1]
     pos = jnp.asarray(positions).astype(jnp.float32)
     pos = pos[None, None] if pos.ndim == 1 else pos[:, None]   # (B|1,1,T)
     inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-    ang = jnp.repeat(pos[..., None] * inv, 2, axis=-1)         # (B|1,1,T,D)
-    i = jnp.arange(D)
-    swap = jnp.where(i[:, None] == (i ^ 1)[None, :],
-                     jnp.where(i[:, None] % 2 == 1, -1.0, 1.0), 0.0)
-    other = jnp.einsum("...i,ij->...j", x, swap.astype(x.dtype))
+    if pairing == "half":
+        ang = jnp.tile(pos[..., None] * inv, 2)                # (B|1,1,T,D)
+        other = jnp.concatenate([-x[..., D // 2:], x[..., :D // 2]], axis=-1)
+    elif pairing == "interleaved":
+        ang = jnp.repeat(pos[..., None] * inv, 2, axis=-1)
+        i = jnp.arange(D)
+        swap = jnp.where(i[:, None] == (i ^ 1)[None, :],
+                         jnp.where(i[:, None] % 2 == 1, -1.0, 1.0), 0.0)
+        other = jnp.einsum("...i,ij->...j", x, swap.astype(x.dtype))
+    else:
+        raise ValueError("rotary: pairing is 'interleaved' or 'half', got %r"
+                         % (pairing,))
     return (x.astype(jnp.float32) * jnp.cos(ang)
             + other.astype(jnp.float32) * jnp.sin(ang)).astype(x.dtype)
 

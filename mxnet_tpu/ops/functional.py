@@ -710,6 +710,17 @@ def LayerNorm(x, gamma, beta, *, axis=-1, eps=1e-5):
     return y.astype(x.dtype)
 
 
+@register_op("rms_norm")
+def rms_norm(x, gamma, *, eps=1e-6):
+    """``x / sqrt(mean(x^2) + eps) * gamma`` over the last axis: float32
+    statistics and one cast back to ``x``'s type, as :func:`LayerNorm`
+    without the mean and the shift."""
+    xf = x.astype(jnp.float32)
+    ms = jnp.mean(xf * xf, axis=-1, keepdims=True)
+    return (xf * lax.rsqrt(ms + eps)
+            * gamma.astype(jnp.float32)).astype(x.dtype)
+
+
 @register_op("InstanceNorm")
 def InstanceNorm(x, gamma, beta, *, eps=1e-5):
     red = tuple(range(2, x.ndim))

@@ -315,7 +315,9 @@ def decode_scope(kind, slots, n_active, args=None, tag=None):
     (``decode[step fill=0.41 b32 xmax=2.50 xhit=38 kvread=0.066]``), and
     every ``step`` carries ``kvread=<the 128-position K/V blocks its live
     slots hold over the blocks the pool holds>``: what share of the pool
-    the step's attention has to read (``serve.decoder._step_tag``)."""
+    the step's attention has to read (``serve.decoder._step_tag``); over a
+    pool of recurrent state (``serve.kv_cache.StatePage``) it carries
+    ``state=<MB its live slots hold>`` in that place."""
     name = "decode[%s fill=%.2f b%d%s]" % (
         kind, n_active / max(slots, 1), slots, " " + tag if tag else "")
     rec_args = {"slots": slots, "active": n_active}

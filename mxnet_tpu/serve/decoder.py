@@ -187,7 +187,9 @@ class GenerativeServer:
     ----------
     model : block implementing the served decode protocol, three methods
         (``models.gpt.GPTModel`` is the reference implementation;
-        ``models.cohere_moe.CohereMoEModel`` routes). Must be initialized;
+        ``models.cohere_moe.CohereMoEModel`` routes;
+        ``models.brumby.BrumbyModel`` keeps a recurrent state in the place
+        of K and V). Must be initialized;
         its parameter dtype decides the cache dtype.
 
         ``decode_state_spec()``: the cache geometry (``layers``, ``heads``,
@@ -212,9 +214,11 @@ class GenerativeServer:
         a free slot reads nothing of its page.
 
         ``state`` is the cache's: one page record a layer
-        (``serve/kv_cache.py``: ``PlainPage``, ``Int8Page``), whose type
-        says its format. The server carries it from program to program
-        (donated) and never looks inside: the model's attention layer reads
+        (``serve/kv_cache.py``: ``PlainPage``, ``Int8Page``; ``StatePage``,
+        a recurrent state of fixed size in the place of K and V, whose
+        prefill hands over (S, z) a layer), whose type says its format.
+        The server carries it from program to program (donated) and never
+        looks inside: the model's attention layer reads
         and writes a page, the record's own methods move a prompt or a slot
         in and out of it. ``aux`` is ``None`` or an array the host reads
         behind the tokens (a routing model's expert load: pad rows and free
@@ -374,6 +378,9 @@ class GenerativeServer:
                     "must fit behind the chunk frontier"
                     % (self._prefill_chunk, self.spec_k))
         self._chunk_jobs = {}
+        # snapshots of a recurrent state (a ``StatePage`` pool) read out to
+        # the prefix store and injected from it: [count, bytes] each way
+        self._snapshots_out, self._snapshots_in = [0, 0], [0, 0]
         # device-side carried state beyond the cache: current input token
         # per slot, and the per-slot sampling controls
         self._tok = jnp.zeros((self.slots,), jnp.int32)
@@ -724,6 +731,7 @@ class GenerativeServer:
                 # format: the page record takes them in as it takes a prompt
                 k_stack, v_stack, plen, last = hit
                 as_dev = lambda st: jax.tree_util.tree_map(jnp.asarray, st)
+                self._count_snapshot(self._snapshots_in, tp)
                 state, valid, toks = self._inject_fn(tp, c.capacity)(
                     c.state, c.valid, self._tok, as_dev(k_stack),
                     as_dev(v_stack), jnp.int32(plen), jnp.int32(slot),
@@ -758,6 +766,7 @@ class GenerativeServer:
                         c.state, jnp.int32(slot))
                     self.prefix.put(stream.prompt, ks, vs, t0_len,
                                     np.asarray(last))
+                self._count_snapshot(self._snapshots_out, tp)
         first = int(np.asarray(self._tok)[slot])
         now = time.perf_counter()
         if aux:
@@ -785,6 +794,11 @@ class GenerativeServer:
         self._ctl_dirty = True
         self.metrics.record_first_token((now - req.t_submit) * 1e3, t0_len)
         self._deliver(slot, first)
+
+    def _count_snapshot(self, counter, tp):
+        if self.cache.snapshots:
+            counter[0] += 1
+            counter[1] += self.cache.page_bytes(tp)
 
     # ------------------------------------------------------------- decoding
     def _decode_once(self, tracing=False):
@@ -835,22 +849,19 @@ class GenerativeServer:
     def _step_tag(self, active):
         """The fields of a traced ``decode[step ...]`` span's name beside
         ``fill=``: a routing model's ``xmax=``/``xhit=`` (of the step
-        before), and ``kvread=<share>``: the 128-position blocks of K and V
-        that hold a live position of an active slot, this step's token
-        included, over the blocks the pool holds, which is how much of the
-        pool the step's attention has to read. From lengths the host knows
-        (prompt plus tokens delivered): no device read."""
+        before), and the page record's own field, from the tokens each live
+        slot has cached, this step's included: ``kvread=<share>`` of a
+        record with a time axis (how much of the pool the step's attention
+        has to read), ``state=<MB>`` of a ``StatePage`` (the state the step
+        reads and writes). From lengths the host knows (prompt plus tokens
+        delivered): no device read."""
         c = self.cache
-        blocks = lambda n: -(-n // 128)
-        lengths = [c.layer_length(i) for i in range(c.layers)]
-        held = 0
+        contexts = []
         for slot in np.nonzero(active)[0]:
             stream = c.owner(int(slot))
-            n = int(stream.prompt.size) + len(stream.tokens)
-            held += sum(blocks(min(n, length)) for length in lengths)
-        pool = self.slots * sum(blocks(length) for length in lengths)
+            contexts.append(int(stream.prompt.size) + len(stream.tokens))
         tags = [self.metrics.expert_tag() if self._routed is not None
-                else None, "kvread=%.3f" % (held / pool)]
+                else None, c.page.step_tag(c.state, contexts)]
         return " ".join(t for t in tags if t)
 
     def _speculate_once(self, active, n_active, tracing=False):
@@ -1447,6 +1458,13 @@ class GenerativeServer:
             quantize=self._quantize,
             kv_cache_bytes=self.cache.nbytes(),
             kv_cache_bytes_unquantized=self.cache.nbytes_unquantized(),
+            # a pool of recurrent state (``StatePage``), and its snapshots
+            # to the prefix store and back
+            state_bytes=self.cache.nbytes() if self.cache.snapshots else 0,
+            state_snapshots_out=self._snapshots_out[0],
+            state_snapshot_bytes_out=self._snapshots_out[1],
+            state_snapshots_in=self._snapshots_in[0],
+            state_snapshot_bytes_in=self._snapshots_in[1],
             running=(self._loop_thread is not None
                      and self._loop_thread.is_alive()),
         )

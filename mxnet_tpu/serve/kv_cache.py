@@ -43,6 +43,12 @@ same donation discipline as ``executor_pool``.
 by the token-prefix hash; a hit replays the stored K/V pages into the new
 request's slot (one tiny inject dispatch) instead of re-running the
 whole-prompt forward.
+
+Not every model keeps K and V by position. ``StatePage`` is the record of a
+layer whose cache is a recurrent state of fixed size a slot (power
+retention, ``ops/retention.py``): the same pool of slots, carried by the
+same programs, with no time axis. Its prompt write and read-out move a
+snapshot of the state, which is what the prefix store then keeps.
 """
 from __future__ import annotations
 
@@ -101,6 +107,34 @@ def _pad_time(a, more):
     return jnp.pad(a, ((0, 0), (0, 0), (0, more), (0, 0))) if more else a
 
 
+# what a record with a time axis (``k`` (slots, heads, length, head_dim))
+# answers the cache's and the scheduler's questions with
+def _prompt_length(page, tp):
+    """Positions a prompt of bucket ``tp`` leaves in the page: ``tp``, or
+    the whole ring where the prompt is longer."""
+    return min(int(tp), page.k.shape[2])
+
+
+def _plain_bytes(page, itemsize):
+    """What K and V of the page's geometry cost at ``itemsize`` bytes an
+    element."""
+    return 2 * page.k.size * itemsize
+
+
+def _kvread_tag(pages, contexts):
+    """``kvread=<share>`` of a traced step's span: the 128-position blocks
+    of K and V that hold a live position of a stream (``contexts``: the
+    tokens each live slot has cached, this step's included), over the blocks
+    the pool holds, which is how much of the pool the step's attention has
+    to read."""
+    blocks = lambda n: -(-n // 128)
+    lengths = [page.k.shape[2] for page in pages]
+    held = sum(blocks(min(n, length)) for n in contexts
+               for length in lengths)
+    pool = pages[0].k.shape[0] * sum(blocks(length) for length in lengths)
+    return "kvread=%.3f" % (held / pool)
+
+
 class PlainPage(NamedTuple):
     """K and V of one layer in the model's dtype: ``k``, ``v`` (slots,
     heads, length, head_dim), ``length`` the capacity bucket or a ring's
@@ -157,6 +191,9 @@ class PlainPage(NamedTuple):
         return PlainPage(*(_take(a, slot) for a in self))
 
     put_slot = _put
+    prompt_length = _prompt_length
+    plain_bytes = _plain_bytes
+    step_tag = staticmethod(_kvread_tag)
 
 
 class Int8Page(NamedTuple):
@@ -230,6 +267,72 @@ class Int8Page(NamedTuple):
                         _take(self.v, slot), scale(self.v_scale))
 
     put_slot = _put
+    prompt_length = _prompt_length
+    plain_bytes = _plain_bytes
+    step_tag = staticmethod(_kvread_tag)
+
+
+class StatePage(NamedTuple):
+    """The recurrent state of one layer, one a slot, of the same size
+    whatever the context: ``S`` (slots, heads, rows x head_dim, head_dim)
+    and ``z`` (slots, heads, rows, head_dim) in float32, as
+    ``ops/retention.py`` lays them out (``zero_state``). There is no time
+    axis: the capacity bucket names the programs and bounds the positions,
+    and sizes nothing. What moves in and out of the pool is a SNAPSHOT: a
+    prefill hands over the state after the prompt's last token, the prefix
+    store keeps that state whole, and a join overwrites all of its slot's,
+    which is what lets a slot be taken again."""
+
+    S: Any
+    z: Any
+
+    # what read_prompt moves is the whole state, not a prompt's positions
+    snapshot = True
+
+    @classmethod
+    def zeros(cls, slots, heads, length, head_dim, dtype):
+        from ..ops.retention import zero_state
+
+        return cls(*zero_state(slots, heads, head_dim))
+
+    def grow(self, more):
+        return self
+
+    def write_prompt(self, S, z, plen, slot):
+        """The pool with the state after a prompt (``S``, ``z`` of one
+        slot, as the model's prefill or the prefix store gives them) in
+        ``slot``'s place."""
+        return _put(self, slot, StatePage(S, z))
+
+    def read_prompt(self, slot, n):
+        """``slot``'s state as the prefix store keeps it: (S, z) without
+        the slot axis."""
+        return tuple(_take(a, slot)[0] for a in self)
+
+    def prompt_length(self, tp):
+        return 0
+
+    def prompt_bytes(self, n):
+        """Bytes of what :meth:`read_prompt` returns: one slot's state."""
+        return sum(a.nbytes // a.shape[0] for a in self)
+
+    def plain_bytes(self, itemsize):
+        return sum(a.nbytes for a in self)
+
+    def take_slot(self, slot, fresh):
+        # a fresh stream starts from nothing
+        return StatePage(*(jnp.where(fresh, 0.0, _take(a, slot))
+                           for a in self))
+
+    put_slot = _put
+
+    @staticmethod
+    def step_tag(pages, contexts):
+        """``state=<MB>`` of a traced step's span: the state its live slots
+        hold, which the step reads and writes once, whatever their
+        contexts."""
+        return "state=%.1f" % (1e-6 * len(contexts) * sum(
+            page.prompt_bytes(0) for page in pages))
 
 
 def write_prompt(state, kvs, plen, slot):
@@ -242,7 +345,9 @@ def write_prompt(state, kvs, plen, slot):
 def read_prompt(state, slot, lengths):
     """``slot``'s page read out as the prefix store keeps it: (k_stack,
     v_stack), each one stacked array where every layer's page has the same
-    length, else (window rings beside full pages) one array a layer.
+    length, else (window rings beside full pages) one array a layer. The
+    two stacks are whatever the record keeps two of: K and V, or a
+    :class:`StatePage`'s ``S`` and ``z``.
     ``lengths``: ``PagedKVCache.page_lengths`` of the prompt's bucket.
     Traced."""
     pack = jnp.stack if len(set(lengths)) == 1 else tuple
@@ -301,8 +406,9 @@ class PagedKVCache:
         than its window and never grows past it. Not with ``quantize``.
     page : None or type
         The page record of a model that keeps its own format
-        (``decode_state_spec()["page"]``); by default :class:`Int8Page`
-        with ``quantize``, else :class:`PlainPage`.
+        (``decode_state_spec()["page"]``: :class:`StatePage`, a recurrent
+        state of fixed size, or the model's own); by default
+        :class:`Int8Page` with ``quantize``, else :class:`PlainPage`.
     """
 
     def __init__(self, layers, heads, head_dim, slots, max_capacity,
@@ -322,6 +428,8 @@ class PagedKVCache:
         self.max_capacity = int(max_capacity)
         self.dtype = np.dtype(dtype)
         self.page = page or (Int8Page if quantize else PlainPage)
+        # the record's word on what its read-out is (StatePage: the state)
+        self.snapshots = bool(getattr(self.page, "snapshot", False))
         self.capacity = 0
         self.state = None     # list[L] of page records, once allocated
         self.valid = jnp.zeros((self.slots,), jnp.int32)
@@ -347,10 +455,10 @@ class PagedKVCache:
         return cap if w is None else min(cap, w)
 
     def page_lengths(self, tp):
-        """Positions a prompt of bucket ``tp`` leaves in each layer: ``tp``,
-        or the whole ring where the prompt is longer."""
-        return [min(int(tp), self.layer_length(i))
-                for i in range(self.layers)]
+        """Positions a prompt of bucket ``tp`` leaves in each layer, by the
+        layer's record: ``tp``, the whole ring where the prompt is longer,
+        0 for a record without a time axis."""
+        return [page.prompt_length(tp) for page in self.state]
 
     def page_bytes(self, tp):
         """Bytes of the K and V page of a prompt of bucket ``tp``, as the
@@ -440,9 +548,8 @@ class PagedKVCache:
         model dtype's (pass 2 to compare against a bf16 cache)."""
         if self.state is None:
             return 0
-        elems = 2 * self.slots * self.heads * self.head_dim \
-            * sum(self.layer_length(i) for i in range(self.layers))
-        return elems * (self.dtype.itemsize if itemsize is None else itemsize)
+        itemsize = self.dtype.itemsize if itemsize is None else itemsize
+        return sum(page.plain_bytes(itemsize) for page in self.state)
 
 
 def _host(stack):

@@ -344,8 +344,9 @@ def test_generative_stats_and_profiler_events(model, rng, tmp_path):
 class FusedPage(NamedTuple):
     """A page kind the library does not know: K and V of a layer kept as
     ONE leaf (2, slots, heads, length, head_dim). What ``serve/kv_cache.py``
-    asks of a page record: allocation, growth, and the traced operations
-    that move a prompt's or a slot's page in and out of the pool."""
+    asks of a page record: allocation, growth, the traced operations that
+    move a prompt's or a slot's page in and out of the pool, and what the
+    cache's accounting and the scheduler's step span ask of a layer."""
 
     kv: Any
 
@@ -372,9 +373,19 @@ class FusedPage(NamedTuple):
                                      (2, 1, H, n, D))
         return page[0, 0], page[1, 0]
 
+    def prompt_length(self, tp):
+        return min(int(tp), self.kv.shape[3])
+
     def prompt_bytes(self, n):
         _two, _slots, H, _length, D = self.kv.shape
         return 2 * H * n * D * self.kv.dtype.itemsize
+
+    def plain_bytes(self, itemsize):
+        return self.kv.size * itemsize
+
+    @staticmethod
+    def step_tag(pages, contexts):
+        return "fused=%d" % len(contexts)
 
     def take_slot(self, slot, fresh):
         shape = self.kv.shape

@@ -822,3 +822,77 @@ def test_server_tokens_same_with_the_decode_attention_kernel(monkeypatch,
     # one call a layer in every decode program traced, on these buffers
     assert calls and set(calls) == buffers
     assert with_kernel == plain
+
+
+# ------------------------------------------------ retention_step (PR 35)
+@pytest.mark.parametrize("live", [(1, 0, 1), (1, 1, 1), (0, 0, 0), None],
+                         ids=["one_free", "all_live", "none_live", "untold"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_retention_step_interpret_matches_the_plain_lowering(dtype, live):
+    """The step's kernel in interpret mode against ``ops/retention.py``'s
+    ``jax.numpy`` step at the published head width (128: 65 rows of phi, 5
+    blocks of 13) and grouping (5 queries a K/V head): the live slots'
+    output, S and z; a free slot's state bit for bit, its output zeros."""
+    from mxnet_tpu.ops import retention as R
+    from mxnet_tpu.ops.pallas import retention_step as K
+
+    rs = np.random.RandomState(0)
+    slots, H, Hkv, D = 3, 10, 2, 128
+    rows = R.phi_rows(D)
+    q = jnp.asarray(rs.normal(0, 1, (slots, H, 1, D)), dtype)
+    k = jnp.asarray(rs.normal(0, 1, (slots, Hkv, 1, D)), dtype)
+    v = jnp.asarray(rs.normal(0, 1, (slots, Hkv, 1, D)), dtype)
+    log_c = jnp.asarray(rs.uniform(-1, 0, (slots, Hkv)), jnp.float32)
+    S = jnp.asarray(rs.normal(0, 1, (slots, Hkv, rows * D, D)), jnp.float32)
+    z = jnp.asarray(rs.normal(3, 1, (slots, Hkv, rows, D)), jnp.float32)
+    assert K.tiles(q.shape, k.shape)
+    told = None if live is None else jnp.asarray(live, jnp.int32)
+    o, S1, z1 = K.retention_step(q, k, v, log_c, S, z, told, interpret=True)
+    want_o, want_S, want_z = R._step(q, k, v, log_c, S, z, told, 1e-6)
+    assert o.dtype == dtype and S1.dtype == z1.dtype == jnp.float32
+    for slot, on in enumerate(live or (1, 1, 1)):
+        if on:
+            np.testing.assert_allclose(
+                np.asarray(o[slot], np.float32),
+                np.asarray(want_o[slot], np.float32),
+                atol=2e-2 if dtype == jnp.bfloat16 else 1e-4)
+            np.testing.assert_allclose(np.asarray(S1[slot]),
+                                       np.asarray(want_S[slot]), atol=1e-5)
+            np.testing.assert_allclose(np.asarray(z1[slot]),
+                                       np.asarray(want_z[slot]), atol=1e-5)
+        else:
+            assert not np.asarray(o[slot], np.float32).any()
+            assert np.asarray(S1[slot]).tobytes() \
+                == np.asarray(S[slot]).tobytes()
+            assert np.asarray(z1[slot]).tobytes() \
+                == np.asarray(z[slot]).tobytes()
+
+
+def test_power_retention_gate(monkeypatch):
+    """On a TPU one token a slot at head width 128 takes the kernel; more
+    tokens, another head width, a mesh and the CPU take ``jax.numpy``."""
+    from mxnet_tpu.ops import retention as R
+    from mxnet_tpu.ops.pallas import retention_step as K
+
+    calls = []
+    monkeypatch.setattr(
+        K, "retention_step",
+        lambda q, k, v, log_c, S, z, live, eps: calls.append(q.shape)
+        or R._step(q, k, v, log_c, S, z, live, eps))
+
+    def run(D, T=1):
+        q = jnp.ones((2, 10, T, D))
+        k = v = jnp.ones((2, 2, T, D))
+        R.power_retention(q, k, v, jnp.zeros((2, 2, T)),
+                          R.zero_state(2, 2, D), jnp.ones((2,) + (T,) * (T > 1)))
+
+    run(128)
+    assert not calls                                  # the CPU
+    monkeypatch.setattr(R, "is_tpu_backend", lambda: True)
+    run(128)
+    assert calls == [(2, 10, 1, 128)]
+    run(16), run(128, T=4)
+    assert len(calls) == 1
+    monkeypatch.setattr(R, "under_mesh", lambda: True)
+    run(128)
+    assert len(calls) == 1

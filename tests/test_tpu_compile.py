@@ -162,6 +162,33 @@ def test_kv_cache_write_compiles_in_place(one_chip, shape, dtype, told):
     assert mem.temp_size_in_bytes < 1 << 20
 
 
+def test_retention_step_compiles_in_place_at_the_published_sizes(one_chip):
+    """The step's kernel at Brumby-14B's sizes (32 slots, 40 query and 8 K/V
+    heads of width 128: 65 rows of phi a head, 1.09 GB of state a layer):
+    Mosaic takes the walk's DMA of the blocks of S and of a head's z out of
+    buffers left in HBM, the lane rotations that build phi, the transposed
+    float32 contraction on the matrix unit; S and z are the results
+    (aliased) and nothing of their size stands beside them."""
+    from mxnet_tpu.ops.pallas import retention_step as K
+
+    slots, H, Hkv, D, rows = 32, 40, 8, 128, 65
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=one_chip)
+    assert K.tiles((slots, H, 1, D), (slots, Hkv, 1, D))
+    compiled = jax.jit(K.retention_step, donate_argnums=(4, 5)).lower(
+        sd((slots, H, 1, D), jnp.bfloat16),
+        sd((slots, Hkv, 1, D), jnp.bfloat16),
+        sd((slots, Hkv, 1, D), jnp.bfloat16),
+        sd((slots, Hkv), jnp.float32),
+        sd((slots, Hkv, rows * D, D), jnp.float32),
+        sd((slots, Hkv, rows, D), jnp.float32),
+        sd((slots,), jnp.int32)).compile()
+    assert "retention_step" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == slots * Hkv * rows * D * (D + 1) * 4
+    assert mem.temp_size_in_bytes < 8 << 20
+
+
 @pytest.mark.parametrize("heads,shape,dtype", [
     (12, (8, 12, 1024, 64), jnp.bfloat16),     # GPT-2 small: the smoke's
     (25, (32, 25, 1024, 64), jnp.float32),     # GPT-2 XL's heads, fp32 pages

@@ -44,7 +44,10 @@ from .store import (CompCacheStore, fingerprint, load_compiled_entry,
 # 2: the generative programs take the cache's state as one pytree of page
 # records (serve/kv_cache.py), not lists of K and V buffers; an executable
 # of format 1 would be called with the wrong argument tree
-FORMAT = 2
+# 3: the generative programs no longer donate the per-slot input tokens (the
+# decode loop reads a step's tokens after they have gone into the next
+# program); an executable of format 2 would delete them under the host
+FORMAT = 3
 
 
 def _warn(msg):

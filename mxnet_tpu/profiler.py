@@ -297,9 +297,15 @@ def decode_scope(kind, slots, n_active, args=None, tag=None):
     ``chunk<tc>``    one chunked-prefill dispatch
     ``ctl``          upload of the per-slot sampling controls after a
                      join or retire
-    ``step``         one fused token step of the whole in-flight batch, from
-                     the dispatch to the host's read of the tokens
-                     (``verify<k>``: the speculative round likewise)
+    ``step``         one tick of the plain decode path, which keeps one
+                     fused token step in flight ahead of the host: from the
+                     dispatch of the step sent ahead to the host's read of
+                     the step before it (the first tick of a stretch sends
+                     two; one that sends none covers the read alone and
+                     names the step read). ``ahead=1``: its dispatch went
+                     out while another step was in flight, ``ahead=0``: not
+                     (``verify<k>``: the speculative round, from its dispatch
+                     to the read of its own tokens, carries no such field)
     ``deliver``      bookkeeping after the read: the walk over the active
                      slots handing tokens to streams, retiring requests
     ``idle``         one span for a whole stretch in which no tick
@@ -311,13 +317,16 @@ def decode_scope(kind, slots, n_active, args=None, tag=None):
     ``tag`` is more ``key=value`` fields of the name itself, which is what
     a reader of the trace sees: a routing model's ``step`` carries
     ``xmax=<the largest expert's load over the mean load>`` and
-    ``xhit=<(layer, expert) pairs that got a pick>`` of the step before it
-    (``decode[step fill=0.41 b32 xmax=2.50 xhit=38 kvread=0.066]``), and
-    every ``step`` carries ``kvread=<the 128-position K/V blocks its live
-    slots hold over the blocks the pool holds>``: what share of the pool
-    the step's attention has to read (``serve.decoder._step_tag``); over a
-    pool of recurrent state (``serve.kv_cache.StatePage``) it carries
-    ``state=<MB its live slots hold>`` in that place."""
+    ``xhit=<(layer, expert) pairs that got a pick>`` of the last step the
+    host has read (a step's own load arrives with its tokens)
+    (``decode[step fill=0.41 b32 xmax=2.50 xhit=38 kvread=0.066 ahead=1]``),
+    and every ``step`` carries ``kvread=<the 128-position K/V blocks its
+    live slots hold over the blocks the pool holds>``: what share of the
+    pool the step's attention has to read (``serve.decoder._step_tag``);
+    over a pool of recurrent state (``serve.kv_cache.StatePage``) it carries
+    ``state=<MB its live slots hold>`` in that place; and last
+    ``ahead=<0|1>`` (above). ``fill=`` and these fields are of the step the
+    span sends."""
     name = "decode[%s fill=%.2f b%d%s]" % (
         kind, n_active / max(slots, 1), slots, " " + tag if tag else "")
     rec_args = {"slots": slots, "active": n_active}

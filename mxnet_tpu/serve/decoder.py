@@ -180,6 +180,17 @@ class GenerationStream:
         return list(self.tokens)
 
 
+class _StepInFlight:
+    """A decode step the device has been sent and the host has not read yet,
+    with what it was sent with: the array the host will read (the tokens,
+    behind them a routing model's aux), the stream that owned each live slot
+    at the dispatch ({slot: stream}), and when it went out."""
+    __slots__ = ("host", "streams", "t0")
+
+    def __init__(self, host, streams, t0):
+        self.host, self.streams, self.t0 = host, streams, t0
+
+
 class GenerativeServer:
     """Continuous-batching generative decode scheduler.
 
@@ -246,8 +257,10 @@ class GenerativeServer:
         per-step KV page allocation the undonated programs would make).
         Safe on every backend: ``cache.update()`` replaces the host
         references after each call, so a donated-away buffer is never
-        re-read. ``MXNET_DECODE_DONATE=0`` force-disables (debugging
-        escape hatch: keeps step inputs alive for inspection).
+        re-read. The per-slot input tokens are never donated: the host
+        reads a step's tokens after they have gone into the next program
+        (``_decode_once``). ``MXNET_DECODE_DONATE=0`` force-disables
+        (debugging escape hatch: keeps step inputs alive for inspection).
     quantize : None or 'int8' / 'e4m3' / 'e5m2'
         Quantized serving: weight-quantize the model in place
         (``quantization.quantize_model`` — per-channel quantized matmuls
@@ -390,6 +403,13 @@ class GenerativeServer:
         self._dev_temps = None
         self._dev_active = None
         self._ctl_dirty = True
+        self._ctl_mask = None    # the mask _dev_active holds
+        # the plain decode path keeps ONE step in flight ahead of the host
+        # (_decode_once): sent, not read yet
+        self._flight = None
+        self._t_read = 0.0       # when the host last read a step's tokens
+        self._steps_ahead = 0    # steps sent while another was in flight
+        self._rows_discarded = 0  # rows computed for a stream that had ended
         # host bookkeeping per slot
         self._slot_req = [None] * self.slots   # admission handle (deadline)
         self._remaining = [0] * self.slots     # tokens left to generate
@@ -440,6 +460,7 @@ class GenerativeServer:
         if loop is not None:
             loop.join(timeout=timeout_s)
         self._batcher.stop(drain=False, timeout_s=timeout_s, reason=reason)
+        self._flight = None   # the step in flight is dropped, never read
         for slot in self.cache.active_slots:
             self._retire(slot, error=ServeError(reason))
         with self._join_cond:
@@ -590,18 +611,21 @@ class GenerativeServer:
     def step(self):
         """One scheduler tick: admit pending joins (prefill/inject, one
         dispatch each — or a chunk-job handoff for long prompts), run AT
-        MOST ONE prefill chunk, then run ONE fused decode step for the
-        whole in-flight batch and deliver each live slot's token(s).
-        Returns the number of slots progressed (0 = idle). The background
-        loop calls this continuously; tests call it directly for
-        counter-exact assertions."""
+        MOST ONE prefill chunk, then send ONE fused decode step for the
+        whole in-flight batch ahead of the host and deliver each live
+        slot's token(s) of the step before it (``_decode_once``: in steady
+        state one dispatch and one host gather a call; the first call of a
+        stretch sends two steps). Returns the number of slots progressed
+        (0 = idle). The background loop calls this continuously; tests call
+        it directly for counter-exact assertions."""
         # the one read of the profiler's switch a tick makes: while it runs
         # the loop's time lies under decode[...] spans end to end
         # (profiler.decode_scope lists the kinds), off it enters none
         tracing = profiler.is_running()
         # a tick with something to do is one decode[tick], the parent of
         # all it does; the others are gathered into decode[idle]
-        busy = tracing and bool(self._join_q or self.cache.num_active)
+        busy = tracing and bool(self._join_q or self.cache.num_active
+                                or self._flight is not None)
         if self._idle is not None and (busy or not tracing):
             self._end_idle()
         with self._span(busy, "tick", self.cache.num_active):
@@ -802,49 +826,118 @@ class GenerativeServer:
 
     # ------------------------------------------------------------- decoding
     def _decode_once(self, tracing=False):
-        # slots mid-chunked-prefill are owned (admission can't reuse them)
-        # but not decodable yet — masked out until their final chunk
-        active = self.cache.active_mask(exclude=self._chunk_jobs)
-        n_active = int(active.sum())
-        if n_active == 0:
+        """The plain path keeps ONE decode step in flight ahead of the host.
+        A tick sends step t+1, whose state, lengths and input tokens are
+        step t's outputs and may still be futures, THEN reads step t's
+        tokens, THEN delivers them: the device starts t+1 the moment t ends,
+        while the host copies, walks the slots and prepares t+2. The first
+        tick of a stretch, with nothing to read, sends two.
+
+        Who is live in the step sent ahead is ``_live_ahead``: a budget's
+        end is known before the read. What the host learns only from the
+        read (an EOS, a deadline) reaches the device one step late: t+1 has
+        run that slot once more, the row is thrown away
+        (``rows_discarded``), and its write lies in the slot's own page,
+        which the next join rewrites. With a draft set the order stays
+        serial (``_speculate_once``: the next drafts need the tokens on the
+        host)."""
+        if self._draft is not None:
+            return self._speculate_once(tracing)
+        flight = self._flight
+        shown = active = self._live_ahead()
+        going = bool(active.any())
+        if flight is None and not going:
             return 0
-        if self._ctl_dirty:
-            with self._span(tracing, "ctl", n_active):
+        if not going:
+            # none goes out: the span is of the step read
+            shown = np.zeros_like(active)
+            shown[list(flight.streams)] = 1
+        # ``ahead``: the span's step went out while another was in flight
+        with self._span(tracing, "step", int(shown.sum()),
+                        tag="%s ahead=%d" % (self._step_tag(shown),
+                                             going and flight is not None)
+                        if tracing else None):
+            sent = self._send_step(active, tracing)
+            if flight is None:
+                flight = self._flight = sent
+                sent = self._send_step(self._live_ahead(), tracing)
+            self._flight = sent
+            # ONE host gather per tick: the tokens, then what the model's
+            # step returned behind the logits (a routing model's load)
+            host = np.asarray(flight.host)
+        with self._span(tracing, "deliver", len(flight.streams)):
+            now = time.perf_counter()
+            # a step's time as a stream sees it: from the read before it
+            # (from its dispatch, where it is the first of a stretch)
+            dt = now - max(flight.t0, self._t_read)
+            self._t_read = now
+            # a row goes to the stream that owned the slot at the dispatch,
+            # if it still does: never to one that took the slot since
+            rows = [(slot, stream) for slot, stream
+                    in flight.streams.items()
+                    if self.cache.owner(slot) is stream
+                    and not stream.done()]
+            self._rows_discarded += len(flight.streams) - len(rows)
+            self.metrics.record_step(dt, len(rows), len(flight.streams),
+                                     self.slots,
+                                     under_prefill=bool(self._chunk_jobs))
+            if host.size > self.slots:
+                self.metrics.record_expert_load(
+                    host[self.slots:].reshape(self._routed), tag=tracing)
+            for slot, stream in rows:
+                self._deliver(slot, int(host[slot]), now, step_s=dt)
+        return len(flight.streams)
+
+    def _live_ahead(self):
+        """(slots,) mask of who is live in the NEXT step to send: the live
+        pages (a slot mid-chunked-prefill is owned, so admission cannot
+        reuse it, but not decodable until its final chunk) less the slots
+        whose budget ends with the token of the step in flight, which the
+        host knows before it reads that step."""
+        active = self.cache.active_mask(exclude=self._chunk_jobs)
+        flight = self._flight
+        if flight is not None:
+            for slot, stream in flight.streams.items():
+                if self._remaining[slot] <= 1 \
+                        and self.cache.owner(slot) is stream:
+                    active[slot] = 0
+        return active
+
+    def _sync_ctl(self, active, tracing):
+        """Uploads the per-slot controls (keys, temperatures, the mask of
+        live slots) after a join or a retire, or where the mask to send is
+        not the one the device holds (a budget that ends in flight)."""
+        if self._ctl_dirty or not np.array_equal(active, self._ctl_mask):
+            with self._span(tracing, "ctl", int(active.sum())):
                 self._dev_keys = jnp.asarray(self._keys)
                 self._dev_temps = jnp.asarray(self._temps)
                 self._dev_active = jnp.asarray(active)
-            self._ctl_dirty = False
-        if self._draft is not None:
-            return self._speculate_once(active, n_active, tracing)
-        fn = self._decode_fn(self.cache.capacity)
-        args = (self._params(), self.cache.state, self.cache.valid,
-                self._tok, self._dev_active, self._dev_keys, self._dev_temps)
+            self._ctl_dirty, self._ctl_mask = False, active
+
+    def _send_step(self, active, tracing):
+        """Dispatches one decode step over the slots of ``active`` behind
+        whatever the device has been sent, and installs its outputs (not
+        computed yet) as the cache's state and the next input tokens, so
+        that a join, a chunk or the next step dispatches behind it. Returns
+        the record of the step in flight; None where no slot is live (none
+        is sent)."""
+        if not active.any():
+            return None
+        self._sync_ctl(active, tracing)
+        c = self.cache
+        fn = self._decode_fn(c.capacity)
         engine.dispatch_counter.bump()
+        self._steps_ahead += self._flight is not None
         t0 = time.perf_counter()
-        # the step as the scheduler sees it: dispatch to the tokens' arrival
-        # (a routing model's span says how unevenly the step before it
-        # loaded the experts: this step's own load arrives with its tokens)
-        with self._span(tracing, "step", n_active,
-                        tag=self._step_tag(active) if tracing else None):
-            # what the model's step returned behind the logits (a routing
-            # model's load) rides behind the tokens
-            state, valid, nxt, *packed = fn(*args)
-            host = np.asarray(packed[0] if packed else nxt)
-            nxt_host = host[:self.slots]     # ONE host gather per step
-            load = host[self.slots:].reshape(self._routed) if packed \
-                else None
-        with self._span(tracing, "deliver", n_active):
-            self.cache.update(state, valid)
-            self._tok = nxt
-            dt = time.perf_counter() - t0
-            self.metrics.record_step(dt, n_active, n_active, self.slots,
-                                     under_prefill=bool(self._chunk_jobs))
-            if load is not None:
-                self.metrics.record_expert_load(load, tag=tracing)
-            now = time.perf_counter()
-            for slot in np.nonzero(active)[0]:
-                self._deliver(int(slot), int(nxt_host[slot]), now, step_s=dt)
-        return n_active
+        # what the model's step returned behind the logits (a routing
+        # model's load) rides behind the tokens in an array of its own
+        state, valid, self._tok, *packed = fn(
+            self._params(), c.state, c.valid, self._tok, self._dev_active,
+            self._dev_keys, self._dev_temps)
+        c.update(state, valid)
+        return _StepInFlight(packed[0] if packed else self._tok,
+                             {int(slot): c.owner(int(slot)) for slot
+                              in np.nonzero(active)[0]}, t0)
 
     def _step_tag(self, active):
         """The fields of a traced ``decode[step ...]`` span's name beside
@@ -854,17 +947,20 @@ class GenerativeServer:
         record with a time axis (how much of the pool the step's attention
         has to read), ``state=<MB>`` of a ``StatePage`` (the state the step
         reads and writes). From lengths the host knows (prompt plus tokens
-        delivered): no device read."""
+        delivered, plus the one of the step in flight): no device read."""
         c = self.cache
+        unread = self._flight.streams if self._flight is not None else {}
         contexts = []
         for slot in np.nonzero(active)[0]:
             stream = c.owner(int(slot))
-            contexts.append(int(stream.prompt.size) + len(stream.tokens))
+            # the token of the step in flight is cached and not delivered
+            contexts.append(int(stream.prompt.size) + len(stream.tokens)
+                            + (unread.get(int(slot)) is stream))
         tags = [self.metrics.expert_tag() if self._routed is not None
                 else None, c.page.step_tag(c.state, contexts)]
         return " ".join(t for t in tags if t)
 
-    def _speculate_once(self, active, n_active, tracing=False):
+    def _speculate_once(self, tracing=False):
         """One speculation round: draft proposes spec_k-1 tokens per slot
         (0 or 1 dispatch), the target scores the whole window in ONE wide
         verify dispatch, and each live slot receives its accepted prefix
@@ -872,6 +968,11 @@ class GenerativeServer:
         Rejected draft positions need no device-side scrub: ``valid_len``
         advances only past accepted tokens and the next window overwrites
         the dead suffix in place."""
+        active = self.cache.active_mask(exclude=self._chunk_jobs)
+        n_active = int(active.sum())
+        if n_active == 0:
+            return 0
+        self._sync_ctl(active, tracing)
         k = self.spec_k
         draft = self._draft
         if draft.needs_history:
@@ -1098,7 +1199,11 @@ class GenerativeServer:
                     [nxt, aux.astype(jnp.int32).reshape(-1)])
             return state, valid, nxt
 
-        fn = self._jit(pure, donate=(1, 2, 3), hint="step@c%d" % capacity)
+        # ``toks`` is not donated, here or in the programs of a join (32
+        # int32: donation buys nothing): a step's tokens have gone into the
+        # next program, the step sent ahead or a prefill, an inject or a
+        # chunk behind it, before the host reads them
+        fn = self._jit(pure, donate=(1, 2), hint="step@c%d" % capacity)
         self._decode_fns[capacity] = fn
         return fn
 
@@ -1208,7 +1313,7 @@ class GenerativeServer:
                                  key, temp)
             return state, valid, toks
 
-        fn = self._jit(pure, donate=(1, 2, 3),
+        fn = self._jit(pure, donate=(1, 2),
                        hint="chunk%d@c%d" % (tc, capacity))
         self._chunk_fns[(tc, capacity)] = fn
         return fn
@@ -1241,7 +1346,7 @@ class GenerativeServer:
             out = (state, valid, toks, jnp.reshape(last, (-1,)))
             return out if aux is None else out + (aux.astype(jnp.int32),)
 
-        fn = self._jit(pure, donate=(1, 2, 3),
+        fn = self._jit(pure, donate=(1, 2),
                        hint="prefill@t%dc%d" % (tp, capacity))
         self._prefill_fns[(tp, capacity)] = fn
         return fn
@@ -1264,7 +1369,7 @@ class GenerativeServer:
             toks = jax.lax.dynamic_update_slice(toks, t0, (slot,))
             return state, valid, toks
 
-        fn = self._jit(pure, donate=(0, 1, 2),
+        fn = self._jit(pure, donate=(0, 1),
                        hint="inject@t%dc%d" % (tp, capacity))
         self._inject_fns[(tp, capacity)] = fn
         return fn
@@ -1449,6 +1554,11 @@ class GenerativeServer:
             prefix_entries=(len(self.prefix) if self.prefix is not None
                             else None),
             decode_compile_counter=engine.decode_compile_counter.count,
+            # the look-ahead of the plain path: steps sent while another was
+            # in flight, and rows a step computed for a stream that had
+            # ended (an EOS or a deadline is found one step late)
+            steps_ahead=self._steps_ahead,
+            rows_discarded=self._rows_discarded,
             verify_dispatches=engine.verify_dispatch_counter.count,
             spec_k=self.spec_k if self._draft is not None else None,
             draft=(type(self._draft).__name__

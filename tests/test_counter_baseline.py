@@ -182,15 +182,17 @@ def test_decode_dispatch_counters_match_artifact():
         t0 = time.time()
         while not all(s.done() for s in streams) and time.time() - t0 < 120:
             # dispatches/step is measured over PURE decode ticks only —
-            # a tick that admits joins also pays prefill/inject (the same
-            # accounting tools/serve_bench.py --mode decode uses)
+            # a tick that admits joins also pays prefill/inject — and in
+            # steady state: the tick that reads a stretch's last step sends
+            # none behind it and leaves none in flight (the same accounting
+            # tools/serve_bench.py --mode decode uses)
             joins0 = srv.metrics.prefills + (srv.prefix.hits
                                              if srv.prefix else 0)
             engine.dispatch_counter.reset()
             n = srv.step()
             joins1 = srv.metrics.prefills + (srv.prefix.hits
                                              if srv.prefix else 0)
-            if n and joins1 == joins0:
+            if n and joins1 == joins0 and srv._flight is not None:
                 pure_disp += engine.dispatch_counter.count
                 pure_steps += 1
             elif n == 0:
@@ -245,7 +247,7 @@ def test_quant_decode_counters_match_artifact():
             n = srv.step()
             joins1 = srv.metrics.prefills + (srv.prefix.hits
                                              if srv.prefix else 0)
-            if n and joins1 == joins0:
+            if n and joins1 == joins0 and srv._flight is not None:
                 pure_disp += engine.dispatch_counter.count
                 pure_steps += 1
             elif n == 0:

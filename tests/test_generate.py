@@ -163,8 +163,10 @@ def test_decode_one_dispatch_zero_retrace_steady_state(model, rng):
     while not (s1.done() and s2.done() and s3.done()):
         engine.dispatch_counter.reset()
         n = srv.step()
-        if n:   # steady decode (incl. after s2/s3 leave): ONE dispatch
-            assert engine.dispatch_counter.count == 1
+        if n:   # steady decode (incl. after s2/s3 leave): ONE dispatch, the
+            # step sent ahead; the tick that reads the last step sends none
+            last = s1.done() and s2.done() and s3.done()
+            assert engine.dispatch_counter.count == (0 if last else 1)
         time.sleep(0.002)
     assert engine.decode_compile_counter.count == 0, \
         "steady-state decode retraced"
@@ -517,6 +519,181 @@ def test_one_decode_step_serves_step_verify_and_chunk(quantize, rng):
 
 
 # ------------------------------------------------------------------ bench
+# ------------------------------------------- one step in flight (ISSUE 36)
+def _submit_now(srv, prompt, **kw):
+    """Submit and wait until the request stands in the join queue, so that
+    the next ``step()`` admits it (or leaves it queued for a slot)."""
+    before = len(srv._join_q)
+    s = srv.submit(prompt, **kw)
+    deadline = time.perf_counter() + 10.0
+    while len(srv._join_q) <= before:
+        assert time.perf_counter() < deadline, "request never reached the loop"
+        time.sleep(0.002)
+    return s
+
+
+def _strictly_serial(srv):
+    """The order before the look-ahead: with a step in flight nobody is
+    live in the next, so every tick sends one step and reads it."""
+    ahead = srv._live_ahead
+    srv._live_ahead = lambda: (ahead() if srv._flight is None else
+                               np.zeros(srv.slots, np.int32))
+    return srv
+
+
+def _page_model(kind):
+    if kind == "state":
+        from mxnet_tpu.models.brumby import brumby_nano
+
+        m = brumby_nano()
+    else:
+        m = gpt_nano()      # its own: ``quantize`` rewrites the weights
+    m.initialize()
+    return m, {"quantize": "int8"} if kind == "int8" else {}
+
+
+def _replay(srv, script, ticks=400):
+    """Hand-stepped: request i of ``script`` (tick, prompt, keywords) is
+    submitted before tick ``tick``; returns every stream's tokens."""
+    streams, tick = [], 0
+    while len(streams) < len(script) or not all(s.done() for s in streams):
+        assert tick < ticks, "streams did not finish in %d ticks" % ticks
+        for at, prompt, kw in script[len(streams):]:
+            if at > tick:
+                break
+            streams.append(_submit_now(srv, prompt, **kw))
+        srv.step()
+        tick += 1
+    while srv.step():       # the step an EOS left in flight
+        pass
+    return [s.result(5) for s in streams]
+
+
+@pytest.mark.parametrize("kind", ["plain", "int8", "state"])
+def test_look_ahead_serves_the_tokens_of_a_strictly_serial_replay(kind):
+    """Greedy and sampled streams over two slots, across joins in
+    mid-flight, budget retires and an EOS met while the next step is
+    already out: token for token what a server reads that sends one step
+    and reads it before it sends the next. A ``PlainPage``, an ``Int8Page``
+    and a ``StatePage`` pool: the server does not look inside."""
+    model, kw = _page_model(kind)
+    rs = np.random.RandomState(36)
+    script = [(0, rs.randint(1, 256, (5,)), dict(max_new_tokens=9)),
+              (0, rs.randint(1, 256, (7,)),
+               dict(max_new_tokens=12, temperature=0.9, seed=3)),
+              (2, rs.randint(1, 256, (3,)), dict(max_new_tokens=6)),
+              (4, rs.randint(1, 256, (6,)),
+               dict(max_new_tokens=7, temperature=0.7, seed=11)),
+              (9, rs.randint(1, 256, (4,)), dict(max_new_tokens=5))]
+
+    def serve(eos_id, serial):
+        srv = mx.serve.GenerativeServer(model, slots=2, eos_id=eos_id,
+                                        timeout_ms=60000.0,
+                                        prefix_cache=False, **kw)
+        try:
+            out = _replay(_strictly_serial(srv) if serial else srv, script)
+            return out, srv.stats()
+        finally:
+            srv.stop()
+
+    free, _ = serve(None, True)
+    assert [len(t) for t in free] == [9, 12, 6, 7, 5]
+    # an EOS that a stream meets in mid-flight: a decode step brings it
+    # (not the prefill's own first token), with budget left
+    i, k, eos = next((i, k, t[k]) for i, t in enumerate(free)
+                     for k in range(1, len(t) - 1) if t[k] != t[0])
+    want, serial_stats = serve(eos, True)
+    got, stats = serve(eos, False)
+    assert got == want
+    assert len(got[i]) <= k + 1 and got[i][-1] == eos
+    assert serial_stats["steps_ahead"] == 0 == serial_stats["rows_discarded"]
+    assert stats["steps_ahead"] > 0 and stats["rows_discarded"] >= 1
+    # without an EOS too, and no row is computed in vain: a budget's end is
+    # known before the step in flight is read
+    got, stats = serve(None, False)
+    assert got == free
+    assert stats["rows_discarded"] == 0
+
+
+def test_a_stale_row_never_reaches_the_stream_that_took_the_slot(model):
+    """One slot. The first stream meets its EOS at the read of step t with
+    step t+1 already out; the queued request takes the slot before t+1 is
+    read. Its row of t+1 is thrown away and counted, never handed on."""
+    rs = np.random.RandomState(5)
+    # an EOS that a decode step brings (not the prefill's own first token),
+    # with budget left, and a second prompt whose answer does not hold it
+    pa, eos = next((p, t[4]) for p, t in (
+        (p, _oracle(model, p, 8)) for p in (
+            rs.randint(1, 256, (6,)) for _ in range(50))) if t[4] != t[0])
+    pb = next(p for p in (rs.randint(1, 256, (4,)) for _ in range(50))
+              if eos not in _oracle(model, p, 6))
+    srv = mx.serve.GenerativeServer(model, slots=1, eos_id=eos,
+                                    timeout_ms=60000.0, prefix_cache=False)
+    try:
+        a = _submit_now(srv, pa, max_new_tokens=8)
+        srv.step()
+        b = _submit_now(srv, pb, max_new_tokens=6)   # waits for the slot
+        while not a.done():
+            assert srv.step() == 1
+        # the EOS came with a read: the step behind it is still in flight,
+        # sent for the stream that has just ended
+        assert a.result(5)[-1] == eos and len(a.tokens) <= 5
+        assert srv._flight is not None and srv._flight.streams == {0: a}
+        assert srv.stats()["rows_discarded"] == 0
+        assert srv.step() == 1      # b joins behind it; the stale row read
+        assert srv.cache.owner(0) is b
+        assert srv.stats()["rows_discarded"] == 1
+        while srv.step():
+            pass
+        assert b.result(5) == _oracle(model, pb, 6)
+        assert srv.stats()["rows_discarded"] == 1
+    finally:
+        srv.stop()
+
+
+def test_one_dispatch_and_one_host_gather_a_step_call(model, monkeypatch):
+    """Steady state: a ``step()`` call sends one step and reads one. A
+    stream of N tokens costs at most N + 1 step dispatches (here N - 1:
+    the first token is the prefill's, and a budget's end is known before
+    the step in flight is read), all but the first sent ahead."""
+    srv = mx.serve.GenerativeServer(model, slots=2, timeout_ms=60000.0,
+                                    prefix_cache=False)
+    srv.warmup(prompt_buckets=(8,), max_tokens=32)
+    gathers = []
+    asarray = np.asarray
+
+    def counting(a, *args, **kwargs):
+        if isinstance(a, jax.Array):
+            gathers.append(a.shape)
+        return asarray(a, *args, **kwargs)
+
+    n = 10
+    try:
+        s = _submit_now(srv, np.arange(1, 6), max_new_tokens=n)
+        ahead0 = srv.stats()["steps_ahead"]
+        engine.dispatch_counter.reset()
+        assert srv.step() == 1
+        # the prefill, then the first call of a stretch sends two steps
+        assert engine.dispatch_counter.count == 3
+        sent = 2
+        monkeypatch.setattr(np, "asarray", counting)
+        while not s.done():
+            engine.dispatch_counter.reset()
+            del gathers[:]
+            assert srv.step() == 1
+            # the call that reads the last step sends none behind it
+            assert engine.dispatch_counter.count == (0 if s.done() else 1)
+            assert gathers == [(2,)]
+            sent += engine.dispatch_counter.count
+        monkeypatch.setattr(np, "asarray", asarray)
+        assert len(s.result(5)) == n and sent == n - 1 <= n + 1
+        assert srv.stats()["steps_ahead"] - ahead0 == sent - 1
+        assert srv._flight is None and srv.step() == 0
+    finally:
+        monkeypatch.setattr(np, "asarray", asarray)
+        srv.stop()
+
+
 @pytest.mark.slow
 def test_serve_decode_bench_quick_subprocess():
     """tools/serve_bench.py --quick --mode decode end-to-end: ≥5× tokens/s

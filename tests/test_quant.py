@@ -111,7 +111,9 @@ def test_quantized_decode_one_dispatch_zero_retrace_kv_ratio():
                 n = srv.step()
                 joins1 = srv.metrics.prefills + (srv.prefix.hits
                                                  if srv.prefix else 0)
-                if n and joins1 == joins0:
+                if n and joins1 == joins0 and srv._flight is not None:
+                    # steady state: one step sent ahead, one read (the tick
+                    # that reads a stretch's last step sends none)
                     pure_disp += engine.dispatch_counter.count
                     pure_steps += 1
                 elif n == 0:

@@ -116,7 +116,8 @@ def test_every_kind_of_span_appears_in_the_given_format(capture):
     recs = capture["plain"]
     for r in recs:
         m = re.match(r"^decode\[([a-z]+)\d* fill=(\d\.\d\d) b%d"
-                     r"(?: kvread=(\d\.\d{3}))?\]$" % SLOTS, r["name"])
+                     r"(?: kvread=(\d\.\d{3}) ahead=[01])?\]$" % SLOTS,
+                     r["name"])
         assert m, r["name"]
         assert r["cat"] == "serve"
         # a step says what share of the pool's 128-position K/V blocks its
@@ -178,6 +179,34 @@ def test_one_step_and_one_deliver_record_per_counted_step(
     assert capture[counted] > 0
     assert sum(_kind(r) == step_kind for r in recs) == capture[counted]
     assert sum(_kind(r) == "deliver" for r in recs) == capture[counted]
+
+
+def test_a_step_says_whether_it_went_out_ahead_and_a_verify_does_not(
+        capture):
+    """One step in flight (ISSUE 36): every busy tick of the plain path
+    writes one ``step`` span that carries ``ahead=`` (1: its dispatch went
+    out while another step was in flight) and one ``deliver`` span; a
+    stretch opens with ``ahead=0``. Speculation keeps its serial order: its
+    ``verify<k>`` ticks carry no such field."""
+    recs = capture["plain"]
+    ticks = [r for r in recs if _kind(r) == "tick"]
+    steps = [r for r in recs if _kind(r) == "step"]
+    delivers = [r for r in recs if _kind(r) == "deliver"]
+    for t in ticks:
+        assert sum(_inside(r, t) for r in steps) == 1
+        assert sum(_inside(r, t) for r in delivers) == 1
+    ahead = [int(re.search(r" ahead=([01])\]$", r["name"]).group(1))
+             for r in steps]
+    # a (4 tokens) and b (3) together: the opening tick finds no step in
+    # flight and sends two, the next sends a's last step behind them, the
+    # third reads it and sends none (a budget's end is known beforehand);
+    # then c, whose two tokens are its prefill's and one step's
+    assert ahead == [0, 1, 0, 0]
+    stats = capture["srv"].stats()
+    assert stats["steps_ahead"] == 2 and stats["rows_discarded"] == 0
+    spec = capture["spec"]
+    assert any(_kind(r) == "verify" for r in spec)
+    assert not any("ahead=" in r["name"] for r in spec)
 
 
 def test_a_busy_tick_is_one_span_and_an_idle_stretch_is_one_span(capture):

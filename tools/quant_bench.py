@@ -124,7 +124,8 @@ class DecodeSide:
             dt = time.perf_counter() - t0
             joins1 = srv.metrics.prefills + (srv.prefix.hits
                                              if srv.prefix else 0)
-            if n and joins1 == joins0:
+            if n and joins1 == joins0 and srv._flight is not None:
+                # steady state: one step sent ahead, the one before it read
                 self.pure_disp += engine.dispatch_counter.count
                 self.pure_steps += 1
                 self.ticks.append(n / dt)

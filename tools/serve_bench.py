@@ -176,14 +176,17 @@ def run_decode(requests, iters, max_new, slots, seed=0, quantize=None):
         t0 = time.perf_counter()
         while not all(s.done() for s in streams):
             # a tick that admits joins also pays prefill/inject dispatches;
-            # dispatches/step is measured over PURE decode ticks only
+            # dispatches/step is measured over PURE decode ticks only, in
+            # steady state: the tick sends one step ahead and reads the one
+            # before it (the tick that reads a stretch's last step sends
+            # none and leaves none in flight)
             joins0 = srv.metrics.prefills + (srv.prefix.hits
                                              if srv.prefix else 0)
             engine.dispatch_counter.reset()
             n = srv.step()
             joins1 = srv.metrics.prefills + (srv.prefix.hits
                                              if srv.prefix else 0)
-            if n and joins1 == joins0:
+            if n and joins1 == joins0 and srv._flight is not None:
                 pure_disp += engine.dispatch_counter.count
                 pure_steps += 1
             elif n == 0:

@@ -189,9 +189,12 @@ def _grouped_attention(q, k, v, mask, causal, scale, window):
 
 
 @register_op("rotary", nondiff=True)
-def rotary(x, positions, *, theta=10000.0, pairing="interleaved"):
+def rotary(x, positions, *, theta=10000.0, pairing="interleaved",
+           inv_freq=None):
     """Rotary position embedding over the whole head width of ``x``
-    (B, H, T, D): pair i turns by ``positions * theta ** (-2i / D)``.
+    (B, H, T, D): pair i turns by ``positions * theta ** (-2i / D)``, or,
+    where ``inv_freq`` is given (D / 2 static numbers, e.g. a YaRN blend of
+    stretched and unstretched frequencies), by ``positions * inv_freq[i]``.
     ``pairing`` says which elements are a pair: ``"interleaved"``,
     ``(x[2i], x[2i+1])``, or ``"half"``, ``(x[i], x[i + D/2])``.
     ``positions`` is (T,) or per row (B, T), any integer type. Angles, sines
@@ -207,7 +210,10 @@ def rotary(x, positions, *, theta=10000.0, pairing="interleaved"):
     D = x.shape[-1]
     pos = jnp.asarray(positions).astype(jnp.float32)
     pos = pos[None, None] if pos.ndim == 1 else pos[:, None]   # (B|1,1,T)
-    inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    if inv_freq is None:
+        inv = theta ** (-jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+    else:
+        inv = jnp.asarray(inv_freq, jnp.float32).reshape(D // 2)
     if pairing == "half":
         ang = jnp.tile(pos[..., None] * inv, 2)                # (B|1,1,T,D)
         other = jnp.concatenate([-x[..., D // 2:], x[..., :D // 2]], axis=-1)
@@ -228,6 +234,9 @@ def rotary(x, positions, *, theta=10000.0, pairing="interleaved"):
 def scaled_dot_attention(q, k, v, mask=None, *, causal=False, scale=None,
                          prefix_mask=False, window=None):
     """q,k,v: (B, H, T, D); mask broadcastable to (B, H, Tq, Tk), 1=keep.
+    ``v`` (and the result) may have a head width of its own (latent
+    attention: keys of 192, values of 128), an inference path like the two
+    below.
 
     Grouped K/V heads: ``k`` and ``v`` may carry fewer heads than ``q``
     (``H % Hkv == 0``; query head h reads K/V head ``h // (H // Hkv)``).
@@ -340,12 +349,117 @@ def cached_attention(q, k_cache, v_cache, lengths, *, scale=None):
                 and (_DECODE_ROW_PATH or k_cache.shape[3] % 128)):
             return _da.decode_attention(q, k_cache, v_cache, lengths,
                                         scale=scale)
+    return scaled_dot_attention(q, k_cache, v_cache,
+                                _live_positions(lengths, T, C), scale=scale)
+
+
+def _live_positions(lengths, T, C):
+    """(B | 1, 1, T, C) mask of the dense decode reads: query row ``t``
+    sees the positions ``[0, lengths + t)`` of a buffer of ``C``."""
     pos = jnp.arange(C, dtype=jnp.int32).reshape(1, 1, 1, C)
     rows = jnp.arange(T, dtype=jnp.int32).reshape(1, 1, T, 1)
-    limit = rows + (lengths.reshape(-1, 1, 1, 1) if lengths.ndim
-                    else lengths)
-    return scaled_dot_attention(q, k_cache, v_cache, pos < limit,
-                                scale=scale)
+    return pos < rows + (lengths.reshape(-1, 1, 1, 1) if lengths.ndim
+                         else lengths)
+
+
+# the expanded attention works this many heads at a time: q, K and V of all 64
+# heads of a 16,384-token prompt are 1.7 GB
+_EXPAND_GROUP = 16
+
+
+@register_op("expanded_latent_attention", nondiff=True)
+def expanded_latent_attention(c_q, c_kv, k_pe, w_qb, w_uk, w_uv, *, heads,
+                              scale, inv_freq):
+    """The prefill's form of latent attention: causal attention of a whole
+    sequence from position 0, with per-head K and V EXPANDED from the
+    compressed rows. ``c_q`` (B, T, Rq) are the normalised query latents,
+    ``c_kv`` (B, 1, T, R) the normalised latents and ``k_pe`` (B, 1, T, P)
+    the shared key after its rotation (the two a latent page keeps);
+    ``w_qb`` (H (N + P), Rq) gives a head's ``[q_nope | q_pe]`` (``q_pe``
+    is rotated here, interleaved pairs, by ``inv_freq``), ``w_uk`` (H N, R)
+    its ``k_nope`` and ``w_uv`` (H Dv, R) its ``v``. Scores ``(q_nope .
+    k_nope + q_pe . k_pe) * scale`` through :func:`scaled_dot_attention` at
+    key width N + P and value width Dv. Returns (B, H, T, Dv).
+
+    The heads go ``_EXPAND_GROUP`` at a time under ``lax.map``, so that q, K
+    and V of all heads never stand whole; a head count the group does not
+    divide goes at once."""
+    B, T, _ = c_q.shape
+    R, P = c_kv.shape[3], k_pe.shape[3]
+    N, Dv = w_uk.shape[0] // heads, w_uv.shape[0] // heads
+    positions = jnp.arange(T, dtype=jnp.int32)
+    c = c_kv[:, 0]
+
+    def by_head(y, g, width):
+        return jnp.transpose(y.reshape(B, T, g, width), (0, 2, 1, 3))
+
+    def some(w):
+        wq, wk, wv = w
+        g = wk.shape[0] // N
+        q = by_head(jnp.dot(c_q, wq.T), g, N + P)
+        q = jnp.concatenate(
+            [q[..., :N], rotary(q[..., N:], positions, inv_freq=inv_freq)],
+            axis=-1)
+        k = jnp.concatenate(
+            [by_head(jnp.dot(c, wk.T), g, N),
+             jnp.broadcast_to(k_pe, (B, g, T, P))], axis=-1)
+        return scaled_dot_attention(q, k, by_head(jnp.dot(c, wv.T), g, Dv),
+                                    causal=True, scale=scale)
+
+    g = _EXPAND_GROUP
+    if heads % g:
+        return some((w_qb, w_uk, w_uv))
+    out = jax.lax.map(some, (w_qb.reshape(heads // g, g * (N + P), -1),
+                             w_uk.reshape(heads // g, g * N, R),
+                             w_uv.reshape(heads // g, g * Dv, R)))
+    return jnp.transpose(out, (1, 0, 2, 3, 4)).reshape(B, heads, T, Dv)
+
+
+@register_op("latent_attention", nondiff=True)
+def latent_attention(q_lat, q_pe, c_cache, pe_cache, lengths, *, scale):
+    """The absorbed decode read of latent (compressed K/V) attention: every
+    query head against ONE row a position. ``q_lat`` (B, H, T, R) are the
+    queries already taken through the key expansion (``q_nope W_uk^T``),
+    ``q_pe`` (B, H, T, P) their rotated part; ``c_cache`` (B, 1, C, R) holds
+    the normalised latent of each position and ``pe_cache`` (B, 1, C, P)
+    the rotated key all heads share. Query row ``t`` of batch row ``b``
+    sees the positions ``[0, lengths[b] + t)``, with the scores
+    ``scale * (q_lat . c + q_pe . pe)`` (float32, softmax in float32), and
+    the result (B, H, T, R) is ``sum p c``: the value is the latent row
+    itself, to be taken through the value expansion by the caller. What it
+    lowers to:
+
+    - per-row ``lengths``, one query token a row, on a TPU, where the
+      shapes tile (``latent_attention.tiles``) and no device mesh is being
+      traced: the Pallas kernel ``latent_attention``, which walks the live
+      blocks of each slot (``decode_attention``'s work list) and fetches a
+      block of rows ONCE for the scores and the accumulation of all heads;
+      a row of length 0 is not visited and gives zeros;
+    - otherwise the mask ``position < lengths + t`` over all B x C rows,
+      densely (the CPU, meshes, T > 1, a scalar length). A row of length 0
+      then reads a uniform mean of its buffer: finite, and discarded by the
+      caller."""
+    lengths = jnp.asarray(lengths, jnp.int32)
+    T, C = q_lat.shape[2], c_cache.shape[2]
+    if (lengths.ndim == 1 and is_tpu_backend() and not under_mesh()
+            and q_lat.dtype == q_pe.dtype == c_cache.dtype
+            == pe_cache.dtype):
+        from .pallas import latent_attention as _la
+
+        if _la.tiles(q_lat.shape, q_pe.shape, c_cache.shape, pe_cache.shape,
+                     c_cache.dtype):
+            return _la.latent_attention(q_lat, q_pe, c_cache, pe_cache,
+                                        lengths, scale=scale)
+    c, pe = c_cache[:, 0], pe_cache[:, 0]
+    s = float(scale) * (
+        jnp.einsum("bhtr,bcr->bhtc", q_lat, c,
+                   preferred_element_type=jnp.float32)
+        + jnp.einsum("bhtp,bcp->bhtc", q_pe, pe,
+                     preferred_element_type=jnp.float32))
+    p = jax.nn.softmax(jnp.where(_live_positions(lengths, T, C), s, -1e30),
+                       axis=-1)
+    return jnp.einsum("bhtc,bcr->bhtr", p.astype(c.dtype), c,
+                      preferred_element_type=jnp.float32).astype(q_lat.dtype)
 
 
 def _prefix_mask_to_valid_len(mask):
@@ -389,6 +503,11 @@ def cache_write(cache, update, index, live=None):
     - per-row ``index`` otherwise: ``vmap(dynamic_update_slice)``, which
       is a ``scatter``, which XLA on the TPU expands into a serial
       ``while`` loop of B column updates.
+
+    A latent page (``serve/kv_cache.py: LatentPage``) is written by two
+    calls: its latent rows (one "head" of 512, whole lane tiles) take the
+    kernel's row path, its shared rotated key (one "head" of 64) the
+    column path.
 
     All three give the same bits, with ``live`` too: the two dense ones
     honour it by a select on the update (a row that is not live writes

@@ -31,15 +31,19 @@ def _row_tile(n_tokens, dtype):
     return 256 if n_tokens > 256 else 32 // jnp.dtype(dtype).itemsize
 
 
-def route(h, router_w, top_k):
+def route(h, router_w, top_k, scale=1.0):
     """Scores over all experts, in float32 whatever ``h`` is: sigmoid of
     the router's logits, the ``top_k`` largest, their weights normalised to
-    sum to one. Returns (weights (N, k) float32, experts (N, k) int32)."""
+    sum to one and, where the model has a ``routed_scaling_factor``, times
+    that ``scale``. Returns (weights (N, k) float32, experts (N, k)
+    int32)."""
     logits = jnp.dot(h.astype(jnp.float32), router_w.astype(jnp.float32).T,
                      precision=jax.lax.Precision.HIGHEST)
     score, expert = jax.lax.top_k(jax.nn.sigmoid(logits), top_k)
-    return score / jnp.sum(score, axis=-1, keepdims=True), \
-        expert.astype(jnp.int32)
+    weight = score / jnp.sum(score, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weight = weight * scale
+    return weight, expert.astype(jnp.int32)
 
 
 def _grouped_ffn_xla(x, tile_expert, tile_valid, w_gate, w_up, w_down, tm):
@@ -58,10 +62,11 @@ def _grouped_ffn_xla(x, tile_expert, tile_valid, w_gate, w_up, w_down, tm):
     return y.reshape(M, d).astype(x.dtype)
 
 
-def _local_part(h, live, router_w, w_gate, w_up, w_down, first_expert, top_k):
+def _local_part(h, live, router_w, w_gate, w_up, w_down, first_expert, top_k,
+                routed_scale=1.0):
     N, d = h.shape
     held, f = w_gate.shape[0], w_gate.shape[1]
-    weight, expert = route(h, router_w, top_k)
+    weight, expert = route(h, router_w, top_k, routed_scale)
     here = (expert >= first_expert) & (expert < first_expert + held) \
         & (live[:, None] > 0)
     # a pick's group: its expert's index among those held, or ``held`` for
@@ -159,7 +164,7 @@ def gated_ffn(h, w_gate, w_up, w_down):
 
 @register_op("expert_ffn", nondiff=True, n_outputs=2)
 def expert_ffn(h, router_w, w_gate, w_up, w_down, live=None, *,
-               first_expert=0, top_k=8):
+               first_expert=0, top_k=8, routed_scale=1.0):
     """One chip's part of a routed expert layer.
 
     ``h`` (N, d) tokens; ``router_w`` (experts, d), all experts of the
@@ -168,7 +173,8 @@ def expert_ffn(h, router_w, w_gate, w_up, w_down, live=None, *,
     width last: ``FFN_e(h) = (silu(h Wg[e]^T) * (h Wu[e]^T)) Wd[e]``);
     ``live`` (N,) marks the rows that are tokens (pad rows and free slots
     route nowhere and load no expert). Routing is :func:`route`, in
-    float32. Returns ``(out, load)``: ``out`` (N, d) is
+    float32, its weights times ``routed_scale``. Returns ``(out, load)``:
+    ``out`` (N, d) is
     ``sum over the chosen experts held here of w_e FFN_e(h)`` (zero for a
     token none of whose experts is here), ``load`` (held + 1,) int32 counts
     the picks that went to each expert held and, last, those that went to
@@ -178,5 +184,5 @@ def expert_ffn(h, router_w, w_gate, w_up, w_down, live=None, *,
         else jnp.asarray(live).astype(jnp.int32)
     out, load = _in_chunks(
         lambda x, l: _local_part(x, l, router_w, w_gate, w_up, w_down,
-                                 first_expert, top_k), h, live)
+                                 first_expert, top_k, routed_scale), h, live)
     return out, load if load.ndim == 1 else jnp.sum(load, axis=0)

@@ -137,11 +137,12 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
                return_lse=False, window=None):
     B, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
+    Dv = v.shape[3]          # the values' own width (and the result's)
     bq = min(bq, Tq)
     bk = min(bk, Tk)
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * Hkv, Tk, D)
-    vr = v.reshape(B * Hkv, Tk, D)
+    vr = v.reshape(B * Hkv, Tk, Dv)
     masked = kv_valid_len is not None
     grid = (B * H, Tq // bq, Tk // bk)
     if Hkv == H and window is None:
@@ -164,14 +165,14 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
         pl.BlockSpec((1, bk, D), kv_block),
-        pl.BlockSpec((1, bk, D), kv_block),
+        pl.BlockSpec((1, bk, Dv), kv_block),
     ]
     operands = [qr, kr, vr]
     if masked:
         in_specs.append(pl.BlockSpec((1, 1, LANES), lambda b, i, j: (b, 0, 0)))
         operands.append(_vl_operand(kv_valid_len, B, H))
-    out_specs = [pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype)]
+    out_specs = [pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((B * H, Tq, Dv), q.dtype)]
     if return_lse:  # inference path skips the lse output entirely — XLA
         # cannot DCE an output of an opaque pallas_call
         out_specs.append(pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, i, 0)))
@@ -188,7 +189,7 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),  # running max (lane-broadcast)
             pltpu.VMEM((bq, LANES), jnp.float32),  # running denom
-            pltpu.VMEM((bq, D), jnp.float32),      # output accumulator
+            pltpu.VMEM((bq, Dv), jnp.float32),     # output accumulator
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -197,8 +198,8 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
         out, lse = res
         # keep only one lane as the residual (saving the full 128-lane
         # broadcast would hold 128x the memory across fwd→bwd)
-        return out.reshape(B, H, Tq, D), lse[..., :1]
-    return res[0].reshape(B, H, Tq, D)
+        return out.reshape(B, H, Tq, Dv), lse[..., :1]
+    return res[0].reshape(B, H, Tq, Dv)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
@@ -545,6 +546,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     visible to query i iff ``0 <= i - j < window``; K/V blocks wholly
     outside are neither computed nor fetched) run the forward kernel alone:
     the backward kernels know neither, so these calls carry no gradient.
+    Nor do values of a width of their own (``v`` (B, Hkv, T, Dv) beside q
+    and k of width D: the result is (B, H, T, Dv)).
 
     block_q/block_k default from the seq-bucketed BLOCK_DEFAULTS table
     (where the committed hardware sweep lands its winners).
@@ -565,7 +568,8 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
         block_k = _default_blocks(Tk)[1]
     bq = _largest_divisor_block(Tq, block_q)
     bk = _largest_divisor_block(Tk, block_k)
-    if window is not None or k.shape[1] != q.shape[1]:
+    if (window is not None or k.shape[1] != q.shape[1]
+            or v.shape[3] != q.shape[3]):
         return _flash_fwd(q, k, v, kv_valid_len, float(scale),
                           bool(causal) or window is not None, bq, bk,
                           interpret=interpret,

@@ -200,7 +200,8 @@ class GenerativeServer:
         (``models.gpt.GPTModel`` is the reference implementation;
         ``models.cohere_moe.CohereMoEModel`` routes;
         ``models.brumby.BrumbyModel`` keeps a recurrent state in the place
-        of K and V). Must be initialized;
+        of K and V; ``models.latent_moe.LatentMoEModel`` keeps one
+        compressed row a position). Must be initialized;
         its parameter dtype decides the cache dtype.
 
         ``decode_state_spec()``: the cache geometry (``layers``, ``heads``,
@@ -227,7 +228,9 @@ class GenerativeServer:
         ``state`` is the cache's: one page record a layer
         (``serve/kv_cache.py``: ``PlainPage``, ``Int8Page``; ``StatePage``,
         a recurrent state of fixed size in the place of K and V, whose
-        prefill hands over (S, z) a layer), whose type says its format.
+        prefill hands over (S, z) a layer; ``LatentPage``, one compressed
+        row a position, whose prefill hands over (c_kv, k_pe) a layer),
+        whose type says its format.
         The server carries it from program to program (donated) and never
         looks inside: the model's attention layer reads
         and writes a page, the record's own methods move a prompt or a slot

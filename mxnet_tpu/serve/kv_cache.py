@@ -49,6 +49,21 @@ layer whose cache is a recurrent state of fixed size a slot (power
 retention, ``ops/retention.py``): the same pool of slots, carried by the
 same programs, with no time axis. Its prompt write and read-out move a
 snapshot of the state, which is what the prefix store then keeps.
+
+Nor does every model that keeps positions keep K and V a head.
+``LatentPage`` is the record of a layer with latent (compressed K/V)
+attention: ONE row a position whatever the head count, the normalised
+latent ``c_kv`` (512 values) and the rotated key ``k_pe`` (64) that all
+heads share, as two buffers (slots, 1, capacity, width), so that both tile
+for ``cache_write`` (``c_kv``: whole lane tiles, the kernel's row path;
+``k_pe``: under one lane tile, its column path, the capacity on the
+lanes). The prefill expands a prompt's rows to per-head K and V for its own
+attention and hands over the rows; the decode step reads them through
+``latent_attention`` (``ops/attention.py``), on a TPU a Pallas kernel that
+fetches only the blocks that hold a slot's live positions, once for all
+heads. Its prompt write, read-out and the prefix store's entries are those
+rows: 1,152 bytes a position and layer in bfloat16 where 64 heads of K (192)
+and V (128) would take 40,960.
 """
 from __future__ import annotations
 
@@ -107,12 +122,12 @@ def _pad_time(a, more):
     return jnp.pad(a, ((0, 0), (0, 0), (0, more), (0, 0))) if more else a
 
 
-# what a record with a time axis (``k`` (slots, heads, length, head_dim))
-# answers the cache's and the scheduler's questions with
+# what a record with a time axis (its first buffer (slots, heads, length,
+# width)) answers the cache's and the scheduler's questions with
 def _prompt_length(page, tp):
     """Positions a prompt of bucket ``tp`` leaves in the page: ``tp``, or
     the whole ring where the prompt is longer."""
-    return min(int(tp), page.k.shape[2])
+    return min(int(tp), page[0].shape[2])
 
 
 def _plain_bytes(page, itemsize):
@@ -128,10 +143,10 @@ def _kvread_tag(pages, contexts):
     the pool holds, which is how much of the pool the step's attention has
     to read."""
     blocks = lambda n: -(-n // 128)
-    lengths = [page.k.shape[2] for page in pages]
+    lengths = [page[0].shape[2] for page in pages]
     held = sum(blocks(min(n, length)) for n in contexts
                for length in lengths)
-    pool = pages[0].k.shape[0] * sum(blocks(length) for length in lengths)
+    pool = pages[0][0].shape[0] * sum(blocks(length) for length in lengths)
     return "kvread=%.3f" % (held / pool)
 
 
@@ -335,6 +350,55 @@ class StatePage(NamedTuple):
             page.prompt_bytes(0) for page in pages))
 
 
+class LatentPage(NamedTuple):
+    """The compressed rows of one layer with latent attention: ``c_kv``
+    (slots, 1, length, rank), the latent after its norm, and ``k_pe``
+    (slots, 1, length, rope), the key all heads share after its rotation,
+    in the model's dtype. One row a position whatever the head count; the
+    cache is told the two widths as its ``head_dim`` (``(rank, rope)``) and
+    one "head"."""
+
+    c_kv: Any
+    k_pe: Any
+
+    @classmethod
+    def zeros(cls, slots, heads, length, head_dim, dtype):
+        return cls(*(jnp.zeros((slots, 1, length, width), dtype)
+                     for width in head_dim))
+
+    def grow(self, more):
+        return LatentPage(*(_pad_time(a, more) for a in self))
+
+    def write_prompt(self, c_kv, k_pe, plen, slot):
+        """The pool with a prompt's rows (``c_kv``, ``k_pe`` (1, 1, tp,
+        width); ``plen`` live positions) written into ``slot``'s page from
+        position 0."""
+        at = _slot_start(slot)
+        return LatentPage(*(
+            jax.lax.dynamic_update_slice(a, new.astype(a.dtype), at)
+            for a, new in zip(self, (c_kv, k_pe))))
+
+    def read_prompt(self, slot, n):
+        """The first ``n`` positions of ``slot``'s page as the prefix store
+        keeps them: (c_kv, k_pe), each (1, n, width)."""
+        return tuple(jax.lax.dynamic_slice(
+            a, _slot_start(slot), (1, 1, n, a.shape[3]))[0] for a in self)
+
+    def prompt_bytes(self, n):
+        """Bytes of what :meth:`read_prompt` returns."""
+        return sum(n * a.shape[3] * a.dtype.itemsize for a in self)
+
+    def plain_bytes(self, itemsize):
+        return sum(a.size for a in self) * itemsize
+
+    def take_slot(self, slot, fresh):
+        return LatentPage(*(_take(a, slot) for a in self))
+
+    put_slot = _put
+    prompt_length = _prompt_length
+    step_tag = staticmethod(_kvread_tag)
+
+
 def write_prompt(state, kvs, plen, slot):
     """The state with a prompt's K/V (``kvs``: one (k, v) a layer, each
     (1, H, tp, D)) written into ``slot``'s page of every layer. Traced."""
@@ -387,7 +451,9 @@ class PagedKVCache:
     layers, heads, head_dim : int
         Per-layer buffer geometry (``model.decode_state_spec()``);
         ``heads`` are the K/V heads a buffer holds (fewer than the query
-        heads under grouped-query attention).
+        heads under grouped-query attention). ``head_dim`` is handed to the
+        page record as it is: a :class:`LatentPage` takes the pair of its
+        two widths.
     slots : int
         Number of request pages — the padded decode batch size.
     max_capacity : int
@@ -407,7 +473,8 @@ class PagedKVCache:
     page : None or type
         The page record of a model that keeps its own format
         (``decode_state_spec()["page"]``: :class:`StatePage`, a recurrent
-        state of fixed size, or the model's own); by default
+        state of fixed size, :class:`LatentPage`, compressed rows in the
+        place of K and V, or the model's own); by default
         :class:`Int8Page` with ``quantize``, else :class:`PlainPage`.
     """
 
@@ -423,7 +490,8 @@ class PagedKVCache:
             raise CacheError("int8 pages keep one running scale a page: "
                              "not for window rings")
         self.heads = int(heads)
-        self.head_dim = int(head_dim)
+        self.head_dim = tuple(int(d) for d in head_dim) \
+            if np.ndim(head_dim) else int(head_dim)
         self.slots = int(slots)
         self.max_capacity = int(max_capacity)
         self.dtype = np.dtype(dtype)

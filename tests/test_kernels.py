@@ -896,3 +896,107 @@ def test_power_retention_gate(monkeypatch):
     monkeypatch.setattr(R, "under_mesh", lambda: True)
     run(128)
     assert len(calls) == 1
+
+
+# --------------------------------------------- latent_attention (PR 37)
+@pytest.mark.parametrize("lengths", [(0, 1, 512, 700, 1024), (1024,) * 5,
+                                     (0,) * 5, (129, 0, 3, 0, 513)],
+                         ids=["ragged", "full", "none_live", "sparse"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_latent_attention_interpret_matches_the_dense_lowering(dtype,
+                                                               lengths):
+    """The absorbed decode read in interpret mode against the op's dense
+    masked lowering: 16 heads over a latent of 128 and a rotated part of 32,
+    a buffer of two 512-row blocks; a slot of length 0 gives zeros."""
+    from mxnet_tpu.ops import attention as A
+    from mxnet_tpu.ops.pallas import latent_attention as K
+
+    rs = np.random.RandomState(0)
+    S, H, R, P, C = 5, 16, 128, 32, 1024
+    ql = jnp.asarray(rs.normal(0, 1, (S, H, 1, R)), dtype)
+    qp = jnp.asarray(rs.normal(0, 1, (S, H, 1, P)), dtype)
+    c = jnp.asarray(rs.normal(0, 1, (S, 1, C, R)), dtype)
+    pe = jnp.asarray(rs.normal(0, 1, (S, 1, C, P)), dtype)
+    lens = jnp.asarray(lengths, jnp.int32)
+    assert K.tiles(ql.shape, qp.shape, c.shape, pe.shape, dtype)
+    got = K.latent_attention(ql, qp, c, pe, lens, 0.13, interpret=True)
+    want = A.latent_attention(ql, qp, c, pe, lens, scale=0.13)
+    assert got.shape == (S, H, 1, R) and got.dtype == dtype
+    for slot, n in enumerate(lengths):
+        if n:
+            np.testing.assert_allclose(
+                np.asarray(got[slot], np.float32),
+                np.asarray(want[slot], np.float32),
+                atol=2e-2 if dtype == jnp.bfloat16 else 2e-5)
+        else:
+            assert not np.asarray(got[slot], np.float32).any()
+
+
+def test_latent_attention_gate(monkeypatch):
+    """On a TPU per-row lengths and one token a row take the kernel where
+    the shapes tile; more tokens, a scalar length, a rotated part of a whole
+    lane tile, a mesh and the CPU take the dense lowering."""
+    from mxnet_tpu.ops import attention as A
+    from mxnet_tpu.ops.pallas import latent_attention as K
+
+    calls = []
+    monkeypatch.setattr(
+        K, "latent_attention",
+        lambda ql, qp, c, pe, lens, scale: calls.append(ql.shape)
+        or jnp.zeros_like(ql))
+
+    def run(T=1, P=16, lengths=(3, 5)):
+        A.latent_attention(jnp.ones((2, 8, T, 128)), jnp.ones((2, 8, T, P)),
+                           jnp.ones((2, 1, 256, 128)), jnp.ones((2, 1, 256, P)),
+                           jnp.asarray(lengths), scale=0.1)
+
+    run()
+    assert not calls                                  # the CPU
+    monkeypatch.setattr(A, "is_tpu_backend", lambda: True)
+    run()
+    assert calls == [(2, 8, 1, 128)]
+    run(T=2), run(P=128), run(lengths=4)
+    assert len(calls) == 1
+    monkeypatch.setattr(A, "under_mesh", lambda: True)
+    run()
+    assert len(calls) == 1
+    assert not K.tiles((2, 8, 1, 128), (2, 8, 1, 16), (2, 1, 200, 128),
+                       (2, 1, 200, 16), jnp.float32)      # not whole tiles
+    assert not K.tiles((2, 6, 1, 128), (2, 6, 1, 16), (2, 1, 256, 128),
+                       (2, 1, 256, 16), jnp.float32)      # 6 heads
+
+
+def test_flash_forward_takes_values_of_their_own_width():
+    """The forward kernel in interpret mode at key width 48 and value width
+    32 (latent attention's prefill: 192 and 128) against the dense path."""
+    from mxnet_tpu.ops import attention as A
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+
+    rs = np.random.RandomState(1)
+    q, k = (jnp.asarray(rs.normal(0, 1, (1, 2, 256, 48)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rs.normal(0, 1, (1, 2, 256, 32)), jnp.float32)
+    got = flash_attention(q, k, v, causal=True, scale=0.13, block_q=128,
+                          block_k=128, interpret=True)
+    want = A._reference_attention(q, k, v, causal=True, scale=0.13)
+    assert got.shape == (1, 2, 256, 32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_expanded_latent_attention_in_head_groups_is_the_whole(monkeypatch):
+    """The heads go a group at a time: the same result as all at once (a
+    group that does not divide them)."""
+    from mxnet_tpu.ops import attention as A
+
+    rs = np.random.RandomState(2)
+    H, T, Rq, R, P, N, Dv = 32, 12, 24, 16, 8, 8, 8
+    arr = lambda *s: jnp.asarray(rs.normal(0, 0.5, s), jnp.float32)
+    args = (arr(1, T, Rq), arr(1, 1, T, R), arr(1, 1, T, P),
+            arr(H * (N + P), Rq), arr(H * N, R), arr(H * Dv, R))
+    kw = dict(heads=H, scale=0.2, inv_freq=(1.0, 0.1, 0.01, 0.001))
+    groups = A.expanded_latent_attention(*args, **kw)
+    monkeypatch.setattr(A, "_EXPAND_GROUP", H + 1)
+    whole = A.expanded_latent_attention(*args, **kw)
+    assert whole.shape == groups.shape == (1, H, T, Dv)
+    np.testing.assert_allclose(np.asarray(groups), np.asarray(whole),
+                               atol=1e-5)

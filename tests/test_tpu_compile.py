@@ -357,3 +357,61 @@ def test_train_step_over_a_mesh_compiles_without_mosaic(topo, monkeypatch):
         on(jax.random.PRNGKey(0), P()), on(batch, P("dp"))
     ).compile().as_text()
     assert "tpu_custom_call" not in text and "all-reduce" in text
+
+
+def test_latent_attention_compiles_at_the_published_sizes(one_chip):
+    """The absorbed decode read at A.X-K1's sizes (32 slots, 64 heads, a
+    latent of 512 and a rotated part of 64, 16,384 positions): Mosaic takes
+    the walk's DMA of a 512-row block of latents and of the same positions'
+    64 x 512 window of the rotated keys (the capacity on the lanes: the
+    ``swapaxes`` is a bitcast, no copy of a buffer), the two contractions on
+    the matrix unit, and the queries of every slot whole in VMEM."""
+    from mxnet_tpu.ops.pallas import latent_attention as K
+
+    S, H, R, P, C = 32, 64, 512, 64, 16384
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    shapes = ((S, H, 1, R), (S, H, 1, P), (S, 1, C, R), (S, 1, C, P))
+    assert K.tiles(*shapes, jnp.bfloat16)
+    compiled = jax.jit(
+        lambda ql, qp, c, pe, n: K.latent_attention(ql, qp, c, pe, n,
+                                                    0.130861)).lower(
+        *(sd(s) for s in shapes), sd((S,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "latent_attention" in text and "tpu_custom_call" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+@pytest.mark.parametrize("width", [512, 64], ids=["c_kv", "k_pe"])
+def test_latent_page_write_compiles_in_place(one_chip, width):
+    """The step's write of a latent page's two buffers through
+    ``kv_cache_write``: the latent rows (one "head" of 512) on the row
+    path, the shared rotated key (one "head" of 64) on the column path,
+    each aliased to its result with nothing of its size beside it."""
+    from mxnet_tpu.ops.pallas import kv_write
+
+    S, C = 32, 16384
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    assert kv_write.tiles((S, 1, C, width), (S, 1, 1, width), jnp.bfloat16)
+    compiled = jax.jit(kv_write.kv_cache_write, donate_argnums=(0,)).lower(
+        sd((S, 1, C, width)), sd((S, 1, 1, width)), sd((S,), jnp.int32),
+        sd((S,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == S * C * width * 2
+    assert mem.temp_size_in_bytes < 1 << 20
+
+
+def test_flash_forward_compiles_at_two_widths(one_chip):
+    """The prefill's expanded attention of a group of 16 heads at 4,096
+    tokens: keys of 192 (128 + 64), values of 128."""
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+
+    sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                            sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, scale=0.130861, block_q=512,
+        block_k=512)).lower(
+        sd((1, 16, 4096, 192)), sd((1, 16, 4096, 192)),
+        sd((1, 16, 4096, 128))).compile()
+    assert "flash_fwd" in compiled.as_text()

@@ -4,7 +4,10 @@ Replaces the reference's unfused softmax(QK^T)V chain (three HBM round trips
 for the T×T score matrix) with blockwise kernels: Q blocks stay resident in
 VMEM while K/V blocks stream through, online-softmax accumulating in fp32
 scratch — O(T) HBM traffic instead of O(T^2), forward AND backward. The
-forward also emits the per-row logsumexp (lane-broadcast, matching the
+forward's grid walks a list of the (Q block, K block) pairs it computes (the
+lower triangle when causal, the band under a window), so a K/V block above
+the diagonal or outside the window is neither fetched nor stepped over. It
+also emits the per-row logsumexp (lane-broadcast, matching the
 (bq, 128) scratch layout Mosaic likes); the backward is the flash-attention-2
 recompute scheme as two kernels — dq over (q-block, k-inner) and dk/dv over
 (k-block, q-inner) — so no T×T tensor ever materializes in either pass.
@@ -20,6 +23,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -55,16 +59,35 @@ def _scores(q_ref, k_ref, q_idx, kv_idx, *, scale, causal, bq, bk, vl=None,
 
 def _kv_block_range(q_idx, bq, bk, causal, window):
     """First and last K/V block that a Q block of a causal (windowed)
-    attention reads: the one definition the kernel's skip and the index
-    maps' clamp share."""
+    attention reads: the one definition the kernel's skip and the forward's
+    pair list share. Plain integer arithmetic, so that it takes a traced
+    block index and a Python one alike; ``lo`` is negative where the window
+    reaches past the first key (every block from 0 on is read)."""
     hi = (q_idx * bq + bq - 1) // bk if causal else None
-    lo = jnp.maximum(q_idx * bq - (window - 1), 0) // bk \
-        if window is not None else None
+    lo = (q_idx * bq - (window - 1)) // bk if window is not None else None
     return lo, hi
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk,
-                emit_lse, masked, window=None):
+def _fwd_pairs(nq, nk, bq, bk, causal, window):
+    """The (Q block, K block) pairs the forward walks, as two int32 arrays:
+    for each Q block in turn the K blocks ``_kv_block_range`` says it reads,
+    ascending (the lower triangle when causal, the band under a window, the
+    whole rectangle otherwise), so that each row folds the same blocks in
+    the same order as a walk of the whole rectangle would. A Q block that
+    reads no block (a window wholly past the keys) keeps one pair, which
+    the kernel skips, so that its zeros are still written."""
+    q_of, k_of = [], []
+    for i in range(nq):
+        lo, hi = _kv_block_range(i, bq, bk, causal, window)
+        hi = nk - 1 if hi is None else min(hi, nk - 1)
+        lo = 0 if lo is None else min(max(lo, 0), hi)
+        q_of += [i] * (hi - lo + 1)
+        k_of += range(lo, hi + 1)
+    return np.asarray(q_of, np.int32), np.asarray(k_of, np.int32)
+
+
+def _fwd_kernel(q_of, k_of, q_ref, k_ref, v_ref, *rest, scale, causal, bq,
+                bk, emit_lse, masked, window=None):
     if masked:
         vl_ref, rest = rest[0], rest[1:]
         vl = vl_ref[0, 0, 0]
@@ -75,10 +98,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk,
         lse_ref, m_ref, l_ref, acc_ref = rest
     else:
         (m_ref, l_ref, acc_ref), lse_ref = rest, None
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
+    t = pl.program_id(1)
+    n = pl.num_programs(1)
+    q_idx = q_of[t]
+    kv_idx = k_of[t]
+    # a Q block's pairs lie side by side in the list: its first pair is the
+    # one after another Q block's, its last the one before the next's
+    first = jnp.logical_or(t == 0, q_of[jnp.maximum(t - 1, 0)] != q_idx)
+    last = jnp.logical_or(t == n - 1, q_of[jnp.minimum(t + 1, n - 1)] != q_idx)
 
-    @pl.when(kv_idx == 0)
+    @pl.when(first)
     def _init():
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
@@ -86,10 +115,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk,
 
     run = True
     if causal:
-        # skip fully-masked K blocks: first query row of this Q block is
-        # q_idx*bq; block contributes iff kv_idx*bk <= q_idx*bq + bq - 1
-        # (and, under a window, iff its last column is still in the first
-        # row's window)
+        # the list holds only blocks this Q block reads, but for the pair
+        # kept for a Q block that reads none, which this skips: the first
+        # query row of this Q block is q_idx*bq; a block contributes iff
+        # kv_idx*bk <= q_idx*bq + bq - 1 (and, under a window, iff its last
+        # column is still in the first row's window)
         lo, hi = _kv_block_range(q_idx, bq, bk, causal, window)
         run = kv_idx <= hi
         if lo is not None:
@@ -116,13 +146,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, bq, bk,
             preferred_element_type=jnp.float32)
         m_ref[:] = m_new
 
-    @pl.when(kv_idx == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _finish():
         # max guard: a vl=0 example has an all-masked row (l == 0)
         o_ref[0] = (acc_ref[:] / jnp.maximum(l_ref[:, :1], 1e-30)).astype(
             o_ref.dtype)
         if emit_lse:
             lse_ref[0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
+
+
+def _count_pairs(walked, grid):
+    """How far the pair list engages, counted once a forward is traced: the
+    (row, Q block, K block) steps walked against those of the whole
+    rectangle. Lazy import: the kernels stay importable before the
+    observability package is."""
+    from ...observability import registry
+
+    registry.counter("flash_fwd_pairs_walked").inc(walked)
+    registry.counter("flash_fwd_pairs_grid").inc(grid)
 
 
 def _vl_operand(kv_valid_len, B, H):
@@ -144,56 +185,52 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
     kr = k.reshape(B * Hkv, Tk, D)
     vr = v.reshape(B * Hkv, Tk, Dv)
     masked = kv_valid_len is not None
-    grid = (B * H, Tq // bq, Tk // bk)
-    if Hkv == H and window is None:
-        kv_block = lambda b, i, j: (b, j, 0)
-    else:
-        group = H // Hkv
-
-        def kv_block(b, i, j):
-            # grouped heads: grid row b = batch * H + head reads K/V row
-            # batch * Hkv + head // group. A block the kernel skips is
-            # clamped onto the nearest one it reads, so that the pipeline
-            # fetches nothing for it
-            lo, hi = _kv_block_range(i, bq, bk, causal, window)
-            if hi is not None:
-                j = jnp.minimum(j, hi)
-            if lo is not None:
-                j = jnp.maximum(j, lo)
-            return ((b // H) * Hkv + (b % H) // group, j, 0)
-
+    nq, nk = Tq // bq, Tk // bk
+    q_of, k_of = _fwd_pairs(nq, nk, bq, bk, causal, window)
+    _count_pairs(B * H * len(q_of), B * H * nq * nk)
+    group = H // Hkv
+    # the grid walks the pair list: step t of row b = batch * H + head
+    # computes Q block q_of[t] against K/V block k_of[t] of K/V row
+    # batch * Hkv + head // group, so that no block the kernel would skip
+    # is fetched and no step is spent on it
+    q_block = lambda b, t, q_of, k_of: (b, q_of[t], 0)
+    kv_block = lambda b, t, q_of, k_of: (
+        (b // H) * Hkv + (b % H) // group, k_of[t], 0)
     in_specs = [
-        pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, bq, D), q_block),
         pl.BlockSpec((1, bk, D), kv_block),
         pl.BlockSpec((1, bk, Dv), kv_block),
     ]
     operands = [qr, kr, vr]
     if masked:
-        in_specs.append(pl.BlockSpec((1, 1, LANES), lambda b, i, j: (b, 0, 0)))
+        in_specs.append(pl.BlockSpec((1, 1, LANES), lambda b, *_: (b, 0, 0)))
         operands.append(_vl_operand(kv_valid_len, B, H))
-    out_specs = [pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0))]
+    out_specs = [pl.BlockSpec((1, bq, Dv), q_block)]
     out_shape = [jax.ShapeDtypeStruct((B * H, Tq, Dv), q.dtype)]
     if return_lse:  # inference path skips the lse output entirely — XLA
         # cannot DCE an output of an opaque pallas_call
-        out_specs.append(pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, i, 0)))
+        out_specs.append(pl.BlockSpec((1, bq, LANES), q_block))
         out_shape.append(jax.ShapeDtypeStruct((B * H, Tq, LANES), jnp.float32))
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
                           emit_lse=return_lse, masked=masked, window=window),
         name="flash_fwd",
         interpret=interpret,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B * H, len(q_of)),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((bq, LANES), jnp.float32),  # running max (lanes)
+                pltpu.VMEM((bq, LANES), jnp.float32),  # running denom
+                pltpu.VMEM((bq, Dv), jnp.float32),     # output accumulator
+            ],
+        ),
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, LANES), jnp.float32),  # running max (lane-broadcast)
-            pltpu.VMEM((bq, LANES), jnp.float32),  # running denom
-            pltpu.VMEM((bq, Dv), jnp.float32),     # output accumulator
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(*operands)
+            dimension_semantics=("parallel", "arbitrary")),
+    )(jnp.asarray(q_of), jnp.asarray(k_of), *operands)
     if return_lse:
         out, lse = res
         # keep only one lane as the residual (saving the full 128-lane
@@ -543,9 +580,9 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     Grouped K/V heads (``k``, ``v`` of ``Hkv`` heads, ``H % Hkv == 0``:
     query head h reads K/V head ``h // (H // Hkv)`` through the K/V index
     map, nothing is repeated in memory) and ``window`` (static int: key j
-    visible to query i iff ``0 <= i - j < window``; K/V blocks wholly
-    outside are neither computed nor fetched) run the forward kernel alone:
-    the backward kernels know neither, so these calls carry no gradient.
+    visible to query i iff ``0 <= i - j < window``) run the forward kernel
+    alone: the backward kernels know neither, so these calls carry no
+    gradient.
     Nor do values of a width of their own (``v`` (B, Hkv, T, Dv) beside q
     and k of width D: the result is (B, H, T, Dv)).
 

@@ -415,3 +415,20 @@ def test_flash_forward_compiles_at_two_widths(one_chip):
         sd((1, 16, 4096, 192)), sd((1, 16, 4096, 192)),
         sd((1, 16, 4096, 128))).compile()
     assert "flash_fwd" in compiled.as_text()
+
+
+def test_flash_forward_walks_its_pair_list_at_16384(one_chip):
+    """ax-k1's largest prefill bucket at the shipped blocks: 16 heads of
+    keys 192 and values 128, causal, over 16,384 tokens. Mosaic takes the
+    scalar-prefetched list of 1,056 pairs a head and the index maps that
+    read it (the grouped, windowed forward walks its list in
+    ``test_flash_grouped_window_compiles``)."""
+    from mxnet_tpu.ops.pallas.flash_attention import flash_attention
+
+    sd = lambda shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                            sharding=one_chip)
+    compiled = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, scale=0.130861)).lower(
+        sd((1, 16, 16384, 192)), sd((1, 16, 16384, 192)),
+        sd((1, 16, 16384, 128))).compile()
+    assert "flash_fwd" in compiled.as_text()

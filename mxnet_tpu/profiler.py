@@ -16,6 +16,7 @@ device-side CUPTI counters report.
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import threading
@@ -27,7 +28,9 @@ _config = {"profile_all": False, "profile_imperative": True,
            "filename": "profile.json", "aggregate_stats": False}
 _running = False
 _records = []          # {"name", "ts_us", "dur_ms", "cat"}
-_lock = threading.Lock()
+# re-entrant: a collection that starts while this thread holds the lock (a
+# dump allocates) records its own span from inside (``_gc_span``)
+_lock = threading.RLock()
 _epoch = time.perf_counter()
 
 # the record buffer is BOUNDED (the GL006 unbounded-growth concern applied
@@ -100,18 +103,51 @@ def start(profile_process="worker"):
         jax.profiler.start_trace(logdir)
     except Exception:
         pass
+    gc.callbacks.append(_gc_span)
 
 
 def stop(profile_process="worker"):
-    global _running
+    global _running, _gc_open
     if not _running:
         return
     _running = False
     _sync_imperative()
+    if _gc_span in gc.callbacks:
+        gc.callbacks.remove(_gc_span)
+    _gc_open = None
     try:
         jax.profiler.stop_trace()
     except Exception:
         pass
+
+
+# the collection under way while the profiler runs: (annotation, name,
+# start); a collection never nests in another
+_gc_open = None
+
+
+def _gc_span(phase, info):
+    """The ``gc.callbacks`` hook that ``start()`` installs and ``stop()``
+    removes: every collection is a ``decode[gc<generation>]`` span on the
+    thread that ran it (a collection halts the decode loop like every other
+    thread, so the device's idle time under it gets a name), with
+    ``collected`` and ``uncollectable`` in its record's ``args``."""
+    global _gc_open
+    if phase == "start":
+        name = "decode[gc%d]" % info["generation"]
+        ann = jax.profiler.TraceAnnotation(name)
+        ann.__enter__()
+        _gc_open = (ann, name, time.perf_counter())
+        return
+    opened, _gc_open = _gc_open, None
+    if opened is None:          # installed while the collection ran
+        return
+    ann, name, t0 = opened
+    ann.__exit__(None, None, None)
+    t1 = time.perf_counter()
+    _record(name, (t0 - _epoch) * 1e6, (t1 - t0) * 1e3, cat="gc",
+            args={"collected": info["collected"],
+                  "uncollectable": info["uncollectable"]})
 
 
 def pause(profile_process="worker"):
@@ -292,8 +328,15 @@ def decode_scope(kind, slots, n_active, args=None, tag=None):
                      Its self time is key making, pad, prefix lookup, the
                      first-token read and bookkeeping.
     ``prefill<tp>``  the prefill (or prefix-inject) dispatch
-    ``readout<tp>``  the page read-out into the prefix store: the extract
-                     dispatch and the device-to-host copies (``mb``)
+    ``readout<tp>``  the page read-out into the prefix store (``mb``):
+                     the wait for the prefill and the extract program, then
+                     its one child
+    ``copy<tp>``     the device-to-host copy of the page into the store
+                     alone (``mb``): the outputs it copies are waited for
+                     before it opens. The join's own read of the switch
+                     decides, or where the join started untraced, one more
+                     read at the copy's entry: a copy that follows a prefill
+                     across the profiler's start is still named
     ``chunk<tc>``    one chunked-prefill dispatch
     ``ctl``          upload of the per-slot sampling controls after a
                      join or retire
@@ -306,10 +349,23 @@ def decode_scope(kind, slots, n_active, args=None, tag=None):
                      out while another step was in flight, ``ahead=0``: not
                      (``verify<k>``: the speculative round, from its dispatch
                      to the read of its own tokens, carries no such field)
+    ``wait``         inside ``step`` and ``verify<k>``: the host's read of
+                     a step's tokens, where the loop waits for the device;
+                     the host's own part of a tick is the tick less its
+                     waits
     ``deliver``      bookkeeping after the read: the walk over the active
                      slots handing tokens to streams, retiring requests
     ``idle``         one span for a whole stretch in which no tick
                      progressed anything
+    ``gc<gen>``      not written here: one collection of generation
+                     ``gen`` on whatever thread ran it, ``decode[gc2]``
+                     bare, from the ``gc.callbacks`` hook that ``start()``
+                     installs and ``stop()`` removes (``_gc_span``)
+
+    A tick reads the profiler's switch once, and once more for each request
+    it joins (a join that starts after the profiler does is named even when
+    its tick started before); ``step``, ``deliver``, ``ctl`` and ``chunk``
+    keep the tick's reading.
 
     ``args`` goes to the TraceAnnotation (stats of the event in the
     ``.xplane.pb``) and to the Chrome record; the dict yielded is the

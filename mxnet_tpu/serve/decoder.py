@@ -621,9 +621,10 @@ class GenerativeServer:
         stretch sends two steps). Returns the number of slots progressed
         (0 = idle). The background loop calls this continuously; tests call
         it directly for counter-exact assertions."""
-        # the one read of the profiler's switch a tick makes: while it runs
-        # the loop's time lies under decode[...] spans end to end
-        # (profiler.decode_scope lists the kinds), off it enters none
+        # the tick's read of the profiler's switch (each join reads its own,
+        # _admit_pending): while it runs the loop's time lies under
+        # decode[...] spans end to end (profiler.decode_scope lists the
+        # kinds), off it enters none
         tracing = profiler.is_running()
         # a tick with something to do is one decode[tick], the parent of
         # all it does; the others are gathered into decode[idle]
@@ -632,7 +633,7 @@ class GenerativeServer:
         if self._idle is not None and (busy or not tracing):
             self._end_idle()
         with self._span(busy, "tick", self.cache.num_active):
-            self._admit_pending(tracing)
+            self._admit_pending()
             chunked = self._chunk_once(tracing)
             n = self._decode_once(tracing) + chunked
         if n:
@@ -662,7 +663,11 @@ class GenerativeServer:
             self._end_idle()   # on the thread that opened it
 
     # ------------------------------------------------------------- joining
-    def _admit_pending(self, tracing=False):
+    def _admit_pending(self):
+        """Joins queued requests while a slot is free. Each join reads the
+        profiler's switch for itself: a tick that started before the
+        profiler did can hold joins of whole seconds, and those that start
+        after it are named."""
         while self.cache._free:
             with self._join_cond:
                 req = self._join_q.popleft() if self._join_q else None
@@ -681,7 +686,7 @@ class GenerativeServer:
                     self.metrics.record_timeout()
                 continue
             try:
-                self._join(req, stream, tracing)
+                self._join(req, stream, profiler.is_running())
             except Exception as e:   # cache exhaustion, model error
                 self.metrics.record_error()
                 if req.finish(error=e):
@@ -791,8 +796,8 @@ class GenerativeServer:
                                 copied):
                     ks, vs = self._extract_fn(tp, c.capacity)(
                         c.state, jnp.int32(slot))
-                    self.prefix.put(stream.prompt, ks, vs, t0_len,
-                                    np.asarray(last))
+                    self._copy_out(stream, ks, vs, t0_len, last, tp,
+                                   tracing)
                 self._count_snapshot(self._snapshots_out, tp)
         first = int(np.asarray(self._tok)[slot])
         now = time.perf_counter()
@@ -821,6 +826,21 @@ class GenerativeServer:
         self._ctl_dirty = True
         self.metrics.record_first_token((now - req.t_submit) * 1e3, t0_len)
         self._deliver(slot, first)
+
+    def _copy_out(self, stream, ks, vs, t0_len, last, tp, tracing):
+        """The read-out's copy of the page into the prefix store. Named
+        ``decode[copy<tp>]`` where the join was traced or the profiler runs
+        by now (one more read of the switch: a join whose prefill straddles
+        the profiler's start still names its copy); the copy's inputs are
+        waited for first, so that the span holds the device-to-host copy
+        alone and its parent the wait for the prefill and the extract."""
+        copying = tracing or profiler.is_running()
+        if copying:
+            jax.block_until_ready((ks, vs, last))
+        with self._span(copying, "copy%d" % tp, self.cache.num_active,
+                        {"mb": round(1e-6 * self.cache.page_bytes(tp), 3)}
+                        if copying else None):
+            self.prefix.put(stream.prompt, ks, vs, t0_len, np.asarray(last))
 
     def _count_snapshot(self, counter, tp):
         if self.cache.snapshots:
@@ -867,7 +887,8 @@ class GenerativeServer:
             self._flight = sent
             # ONE host gather per tick: the tokens, then what the model's
             # step returned behind the logits (a routing model's load)
-            host = np.asarray(flight.host)
+            with self._span(tracing, "wait", len(flight.streams)):
+                host = np.asarray(flight.host)
         with self._span(tracing, "deliver", len(flight.streams)):
             now = time.perf_counter()
             # a step's time as a stream sees it: from the read before it
@@ -1003,7 +1024,8 @@ class GenerativeServer:
             state, valid, nxt, emit, n_emit = fn(*args)
             # ONE batched host gather for both outputs (two np.asarray
             # calls would sync the device twice per round)
-            emit_h, n_emit_h = jax.device_get((emit, n_emit))
+            with self._span(tracing, "wait", n_active):
+                emit_h, n_emit_h = jax.device_get((emit, n_emit))
         with self._span(tracing, "deliver", n_active):
             self.cache.update(state, valid)
             self._tok = nxt

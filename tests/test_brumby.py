@@ -6,6 +6,7 @@ nothing of the program): d 64, 10 query / 2 K/V heads of width 16 (5 queries
 a K/V head, as published), inner width 96, 2 layers, seeded float32 weights.
 """
 import importlib.util
+import json
 import os
 import re
 
@@ -338,12 +339,14 @@ def test_the_state_page_has_no_time_axis():
 def test_step_spans_carry_state_in_the_place_of_kvread(served):
     _cfg, _model, _params, srv = served
     profiler.set_config(filename=os.devnull)
+    n0 = profiler.num_records()      # the records before are other tests'
     profiler.set_state("run")
     try:
         srv.submit([1, 2, 3], max_new_tokens=4).result(timeout_s=120)
     finally:
         profiler.set_state("stop")
-    steps = re.findall(r"decode\[step [^\]]*\]", profiler.dumps())
+    steps = [r["name"] for r in json.loads(profiler.dumps())[n0:]
+             if r["name"].startswith("decode[step ")]
     assert steps and all(" state=0.0" in s and "kvread" not in s
                          for s in steps), steps
 

@@ -9,6 +9,7 @@ top-2, experts 2..5 held, one shared, scale 2.5), YaRN factor 4 over 32
 original positions, seeded float32 weights.
 """
 import importlib.util
+import json
 import math
 import os
 import re
@@ -405,12 +406,14 @@ def test_a_slot_taken_again_reads_none_of_the_stream_before():
 def test_step_spans_carry_kvread_and_the_experts_fields(served):
     _cfg, _model, _params, srv = served
     profiler.set_config(filename=os.devnull)
+    n0 = profiler.num_records()      # the records before are other tests'
     profiler.set_state("run")
     try:
         srv.submit([1, 2, 3], max_new_tokens=5).result(timeout_s=120)
     finally:
         profiler.set_state("stop")
-    steps = re.findall(r"decode\[step [^\]]*\]", profiler.dumps())
+    steps = [r["name"] for r in json.loads(profiler.dumps())[n0:]
+             if r["name"].startswith("decode[step ")]
     # the experts' fields are of the step before: the first span has none
     assert steps and all(re.search(
         r"( xmax=[\d.]+ xhit=\d+)? kvread=0\.\d+ ahead=[01]\]", s)

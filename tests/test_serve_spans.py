@@ -3,14 +3,18 @@
 While the profiler runs, every stretch of a ``GenerativeServer`` tick lies
 under a ``decode[<kind> fill=... b<slots>]`` span (``profiler.decode_scope``
 lists the kinds), so that a device trace can put each idle gap of the device
-down to what the loop thread was doing; with the profiler off a tick reads
-the switch once and enters no span. Counts and structure only: no time is
-compared. The serving programs carry one name per kind
-(``jit_pure_step_c64``, ``jit_pure_prefill_t8c64``, ...), which is what a
-trace reader finds them by.
+down to what the loop thread was doing: the host's read of a step's tokens
+is a ``wait``, a read-out's copy into the prefix store a ``copy<tp>``, and
+every collection of the garbage collector a ``decode[gc<generation>]``. With
+the profiler off a tick reads the switch once, and once more a join, and
+enters no span. Counts and structure only: no time is compared. The serving
+programs carry one name per kind (``jit_pure_step_c64``,
+``jit_pure_prefill_t8c64``, ...), which is what a trace reader finds them by.
 """
+import gc
 import json
 import re
+import sys
 import time
 
 import numpy as np
@@ -50,6 +54,20 @@ def _pump(srv, streams, ticks=200):
         if all(s.done() for s in streams):
             return i + 1
     raise AssertionError("streams did not finish in %d ticks" % ticks)
+
+
+def _server_switch(reads, on_from=None):
+    """A stand-in for ``profiler.is_running`` that notes each read the
+    server makes (those of the request traces and metrics are not the
+    server's) and says on from the server's read number ``on_from``."""
+    def is_running():
+        caller = sys._getframe(1).f_globals.get("__name__")
+        if caller != "mxnet_tpu.serve.decoder":
+            return bool(reads) and on_from is not None \
+                and len(reads) >= on_from
+        reads.append(1)
+        return on_from is not None and len(reads) >= on_from
+    return is_running
 
 
 def _kind(rec):
@@ -103,9 +121,10 @@ def capture(model, tmp_path_factory):
     spec.step()
     recs = [r for r in json.loads(profiler.dumps())[n0:]
             if r["name"].startswith("decode[")]
-    # the two servers' records are told apart by their number of slots
-    out["plain"] = [r for r in recs if r["args"]["slots"] == SLOTS]
-    out["spec"] = [r for r in recs if r["args"]["slots"] == SLOTS // 2]
+    # the two servers' records are told apart by their number of slots (a
+    # collection's record carries none)
+    out["plain"] = [r for r in recs if r["args"].get("slots") == SLOTS]
+    out["spec"] = [r for r in recs if r["args"].get("slots") == SLOTS // 2]
     out.update(srv=srv, streams={"a": a, "b": b, "c": c})
     yield out
     srv.stop()
@@ -128,8 +147,8 @@ def test_every_kind_of_span_appears_in_the_given_format(capture):
             assert float(kvread) == float(fill)
     kinds = {re.match(r"decode\[(\w+)", r["name"]).group(1) for r in recs}
     assert kinds == {"idle", "tick", "join8", "join16", "prefill8",
-                     "prefill16", "readout8", "readout16", "ctl", "step",
-                     "deliver"}
+                     "prefill16", "readout8", "readout16", "copy8", "copy16",
+                     "ctl", "step", "wait", "deliver"}
 
 
 def test_one_join_record_per_request_carries_its_trace_id(capture):
@@ -169,6 +188,43 @@ def test_readout_counts_the_bytes_the_store_keeps(capture):
         m = re.match(r"decode\[readout(\d+)", r["name"])
         if m:
             assert r["args"]["mb"] == round(stored[int(m.group(1))] / 1e6, 3)
+
+
+def test_each_readout_holds_one_copy_of_the_bytes_the_store_keeps(capture):
+    """The read-out's copy alone is its one child ``copy<tp>``, carrying the
+    bytes the store keeps; an injected join reads nothing out and copies
+    nothing."""
+    recs = capture["plain"]
+    stored = {k_stack.shape[2]: k_stack.nbytes + v_stack.nbytes
+              for k_stack, v_stack, _n, _last in
+              capture["srv"].prefix._store.values()}
+    readouts = [r for r in recs if _kind(r) == "readout"]
+    copies = [r for r in recs if _kind(r) == "copy"]
+    assert len(copies) == len(readouts) == 2
+    for r in readouts:
+        mine = [c for c in copies if _inside(c, r)]
+        assert len(mine) == 1, r["name"]
+        tp = int(re.match(r"decode\[readout(\d+)", r["name"]).group(1))
+        assert mine[0]["name"].startswith("decode[copy%d " % tp)
+        assert mine[0]["args"]["mb"] == round(stored[tp] / 1e6, 3)
+    inject = [j for j in recs if _kind(j) == "join"
+              and j["args"]["kind"] == "inject"]
+    assert not any(_inside(c, inject[0]) for c in copies)
+
+
+@pytest.mark.parametrize("which,step_kind", [
+    ("plain", "step"), ("spec", "verify")])
+def test_every_step_holds_one_wait_for_its_tokens(capture, which,
+                                                   step_kind):
+    """``decode[wait]`` is the host's read of a step's tokens: one inside
+    every ``step`` (and every speculative ``verify<k>``), none elsewhere;
+    the host's own part of a tick is the tick less its waits."""
+    recs = capture[which]
+    steps = [r for r in recs if _kind(r) == step_kind]
+    waits = [r for r in recs if _kind(r) == "wait"]
+    assert steps and len(waits) == len(steps)
+    for r in steps:
+        assert sum(_inside(w, r) for w in waits) == 1, r["name"]
 
 
 @pytest.mark.parametrize("which,step_kind,counted", [
@@ -225,21 +281,28 @@ def test_a_busy_tick_is_one_span_and_an_idle_stretch_is_one_span(capture):
     assert not any(_inside(t, i) for t in ticks for i in idle)
 
 
-def test_profiler_off_one_switch_read_a_tick_and_no_span(model, monkeypatch):
-    srv = mx.serve.GenerativeServer(model, slots=SLOTS, timeout_ms=60000.0)
+def _profiler_off(model, monkeypatch, prefix_cache):
+    """Ticks a server with the profiler off through one join and three
+    decode steps alone; returns the server's reads of the switch on the
+    tick that joins. No span may be entered, no record written, and the
+    collector's hooks are left as they were."""
+    srv = mx.serve.GenerativeServer(model, slots=SLOTS, timeout_ms=60000.0,
+                                    prefix_cache=prefix_cache)
     srv.warmup(prompt_buckets=[5], max_tokens=40)
 
     def never(*a, **k):
         raise AssertionError("decode_scope entered with the profiler off")
 
     reads = []
+    hooks = list(gc.callbacks)
     monkeypatch.setattr(profiler, "decode_scope", never)
-    monkeypatch.setattr(profiler, "is_running",
-                        lambda: reads.append(1) is not None and False)
+    monkeypatch.setattr(profiler, "is_running", _server_switch(reads))
     n0 = profiler.num_records()
     assert srv.step() == 0 and len(reads) == 1
     s = _submit(srv, np.arange(1, 6, dtype=np.int32), 8)
+    del reads[:]
     assert srv.step() == 1                 # the join and the first step
+    join_reads = len(reads)
     del reads[:]
     for _ in range(3):                     # decode steps alone
         assert srv.step() == 1
@@ -247,7 +310,86 @@ def test_profiler_off_one_switch_read_a_tick_and_no_span(model, monkeypatch):
     _pump(srv, [s])
     assert len(s.result(5)) == 8
     assert profiler.num_records() == n0
+    assert gc.callbacks == hooks
     srv.stop()
+    return join_reads
+
+
+def test_profiler_off_one_switch_read_a_tick_and_no_span(model, monkeypatch):
+    """Off, a tick reads the switch once and once more a join; a join's
+    read-out into the prefix store reads it once more at its copy (a copy
+    that follows a prefill across the profiler's start is named)."""
+    assert _profiler_off(model, monkeypatch, prefix_cache=True) == 3
+
+
+def test_profiler_off_a_join_without_a_readout_reads_the_switch_once(
+        model, monkeypatch):
+    """The tick that joins reads twice: its own read and the join's."""
+    assert _profiler_off(model, monkeypatch, prefix_cache=False) == 2
+
+
+def test_a_join_reads_the_switch_for_itself(model, monkeypatch):
+    """A tick that starts with the profiler off and finds it on by its
+    second join writes that join's ``join``/``prefill``/``readout``/``copy``
+    spans, and none of the first join's, nor a tick or a step."""
+    srv = mx.serve.GenerativeServer(model, slots=SLOTS, timeout_ms=60000.0)
+    srv.warmup(prompt_buckets=[5], max_tokens=40)
+    rng = np.random.RandomState(40)
+    first = _submit(srv, rng.randint(1, 256, (5,)).astype(np.int32), 3)
+    second = _submit(srv, rng.randint(1, 256, (5,)).astype(np.int32), 3)
+    reads = []
+    # the tick's read, the first join's and its copy's: off; then on
+    monkeypatch.setattr(profiler, "is_running",
+                        _server_switch(reads, on_from=4))
+    n0 = profiler.num_records()
+    assert srv.step() == 2
+    assert len(reads) == 4                 # the second join's copy reads none
+    monkeypatch.setattr(profiler, "is_running", lambda: False)
+    recs = [r for r in json.loads(profiler.dumps())[n0:]
+            if r["name"].startswith("decode[") and "slots" in r["args"]]
+    assert sorted(_kind(r) for r in recs) == ["copy", "join", "prefill",
+                                              "readout"]
+    join = next(r for r in recs if _kind(r) == "join")
+    assert join["args"]["trace_id"] == second.trace_id
+    assert all(_inside(r, join) for r in recs)
+    _pump(srv, [first, second])
+    srv.stop()
+
+
+@pytest.fixture(scope="module")
+def collections(tmp_path_factory):
+    """The profiler's records of one collection of each generation, and
+    ``gc.callbacks`` before ``start()`` and after ``stop()``."""
+    before = list(gc.callbacks)
+    profiler.set_config(filename=str(
+        tmp_path_factory.mktemp("gc") / "p.json"))
+    n0 = profiler.num_records()
+    profiler.set_state("run")
+    try:
+        running = list(gc.callbacks)
+        for gen in (0, 1, 2):
+            gc.collect(gen)
+    finally:
+        profiler.set_state("stop")
+    recs = [r for r in json.loads(profiler.dumps())[n0:]
+            if r["name"].startswith("decode[gc")]
+    return {"recs": recs, "before": before, "running": running,
+            "after": list(gc.callbacks)}
+
+
+@pytest.mark.parametrize("gen", [0, 1, 2])
+def test_a_collection_is_a_span_while_the_profiler_runs(collections, gen):
+    mine = [r for r in collections["recs"]
+            if r["name"] == "decode[gc%d]" % gen]
+    assert mine, collections["recs"]
+    assert all(r["ph"] == "X" and r["dur_ms"] >= 0.0 and r["cat"] == "gc"
+               and {"collected", "uncollectable"} <= set(r["args"])
+               for r in mine)
+
+
+def test_the_collector_hook_goes_with_the_profiler(collections):
+    assert len(collections["running"]) == len(collections["before"]) + 1
+    assert collections["after"] == collections["before"]
 
 
 @pytest.mark.parametrize("quantize", [None, "int8"])

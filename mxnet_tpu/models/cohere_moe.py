@@ -94,13 +94,15 @@ class _GroupedAttention(HybridBlock):
         return self.o(F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                                 shape=(B, T, H * D)))
 
-    def forward_kv(self, F, h):
-        """Whole-sequence attention; also returns K (rotated where the layer
-        rotates) and V, (B, kv_heads, T, D), as the cache holds them."""
+    def forward_kv(self, F, h, plen=None):
+        """Whole-sequence attention (rows at and past ``plen``, where given,
+        attend to nothing and are zeros); also returns K (rotated where the
+        layer rotates) and V, (B, kv_heads, T, D), as the cache holds
+        them."""
         T = h.shape[1]
         q, k, v = self._qkv(F, h, F.arange(0, T, dtype="int32"))
         out = F.scaled_dot_attention(q, k, v, causal=True,
-                                     window=self._window)
+                                     window=self._window, q_valid_len=plen)
         return self._merge(F, out), k, v
 
     def step_cached(self, F, h, page, position, active):
@@ -182,9 +184,9 @@ class _MoEBlock(HybridBlock):
              + shared.astype("float32") * self._shared_scale)
         return y.astype(x.dtype)
 
-    def forward_kv(self, F, x, live):
+    def forward_kv(self, F, x, live, plen=None):
         h = self.ln(x)
-        a, k, v = self.attn.forward_kv(F, h)
+        a, k, v = self.attn.forward_kv(F, h, plen)
         routed, shared, load = self._moe(F, h, live)
         return self._sum(F, x, a, routed, shared), k, v, load
 
@@ -271,9 +273,11 @@ class CohereMoEModel(HybridBlock):
     def forward_collect_kv(self, F, tokens, plen=None):
         """The prefill primitive. Without ``plen``: logits of every row.
         With ``plen`` (a traced scalar: the prompt's length inside its
-        padded bucket): rows at or past it route to no expert, and only row
-        ``plen - 1`` goes through the head (logits (B, 1, V)). Returns
-        (logits, [(K, V) a layer], load (layers, held + 1))."""
+        padded bucket): rows at or past it attend to nothing (their
+        attention is zeros, and the flash kernel does not compute it) and
+        route to no expert, and only row ``plen - 1`` goes through the head
+        (logits (B, 1, V)). Returns (logits, [(K, V) a layer], load
+        (layers, held + 1))."""
         B, T = tokens.shape
         self._check_len(T)
         x = self.word_embed(tokens)
@@ -281,7 +285,7 @@ class CohereMoEModel(HybridBlock):
         live = F.ones_like(rows) if plen is None else rows < plen
         kvs, loads = [], []
         for blk in self.blocks:
-            x, k, v, load = blk.forward_kv(F, x, live)
+            x, k, v, load = blk.forward_kv(F, x, live, plen)
             kvs.append((k, v))
             loads.append(load)
         if plen is not None:

@@ -143,16 +143,17 @@ class _LatentAttention(HybridBlock):
         return self.o(F.reshape(F.transpose(out, axes=(0, 2, 1, 3)),
                                 shape=(B, T, H * D)))
 
-    def forward_kv(self, F, h):
+    def forward_kv(self, F, h, plen=None):
         """Whole-sequence attention, EXPANDED: K and V of every head built
-        from the rows (``F.expanded_latent_attention``); also returns the
-        rows, as the page keeps them."""
+        from the rows (``F.expanded_latent_attention``; rows at and past
+        ``plen``, where given, attend to nothing and are zeros); also
+        returns the rows, as the page keeps them."""
         c_kv, k_pe = self._rows(F, h, F.arange(0, h.shape[1], dtype="int32"))
         out = F.expanded_latent_attention(
             self.q_a_norm(self.q_a(h)), c_kv, k_pe,
             param_value(self.q_b.weight), param_value(self.kv_b_k),
             param_value(self.kv_b_v), heads=self._heads, scale=self._scale,
-            inv_freq=self._inv_freq)
+            inv_freq=self._inv_freq, q_valid_len=plen)
         return self._merge(F, out), c_kv, k_pe
 
     def step_cached(self, F, h, page, position, active):
@@ -222,8 +223,8 @@ class _Block(HybridBlock):
             y = routed + y
         return x + F.reshape(y, shape=(B, T, C)), load
 
-    def forward_kv(self, F, x, live):
-        a, c_kv, k_pe = self.attn.forward_kv(F, self.ln1(x))
+    def forward_kv(self, F, x, live, plen=None):
+        a, c_kv, k_pe = self.attn.forward_kv(F, self.ln1(x), plen)
         x, load = self._ffn(F, x + a, live)
         return x, c_kv, k_pe, load
 
@@ -316,10 +317,12 @@ class LatentMoEModel(HybridBlock):
     def forward_collect_kv(self, F, tokens, plen=None):
         """The prefill primitive, the EXPANDED path. Without ``plen``:
         logits of every row. With ``plen`` (a traced scalar: the prompt's
-        length inside its padded bucket): rows at or past it route to no
-        expert, and only row ``plen - 1`` goes through the head (logits
-        (B, 1, V)). Returns (logits, [(c_kv, k_pe) a layer: the rows the
-        page keeps], load (expert layers, held + 1))."""
+        length inside its padded bucket): rows at or past it attend to
+        nothing (their attention is zeros, and the flash kernel does not
+        compute it) and route to no expert, and only row ``plen - 1`` goes
+        through the head (logits (B, 1, V)). Returns (logits, [(c_kv, k_pe)
+        a layer: the rows the page keeps], load (expert layers, held +
+        1))."""
         B, T = tokens.shape
         if T > self._max_len:
             raise ValueError("sequence length %d exceeds max_length=%d"
@@ -329,7 +332,7 @@ class LatentMoEModel(HybridBlock):
         live = F.ones_like(rows) if plen is None else rows < plen
         kept, loads = [], []
         for blk in self.blocks:
-            x, c_kv, k_pe, load = blk.forward_kv(F, x, live)
+            x, c_kv, k_pe, load = blk.forward_kv(F, x, live, plen)
             kept.append((c_kv, k_pe))
             if load is not None:
                 loads.append(load)

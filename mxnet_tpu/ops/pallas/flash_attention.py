@@ -6,10 +6,12 @@ VMEM while K/V blocks stream through, online-softmax accumulating in fp32
 scratch — O(T) HBM traffic instead of O(T^2), forward AND backward. The
 forward's grid walks a list of the (Q block, K block) pairs it computes (the
 lower triangle when causal, the band under a window), so a K/V block above
-the diagonal or outside the window is neither fetched nor stepped over. It
-also emits the per-row logsumexp (lane-broadcast, matching the
-(bq, 128) scratch layout Mosaic likes); the backward is the flash-attention-2
-recompute scheme as two kernels — dq over (q-block, k-inner) and dk/dv over
+the diagonal or outside the window is neither fetched nor stepped over; given
+each example's query length (a prompt inside its padded bucket) a row's walk
+stops after the last Q block that holds a live query row. It also emits the
+per-row logsumexp (lane-broadcast, matching the (bq, 128) scratch layout
+Mosaic likes); the backward is the flash-attention-2 recompute scheme as two
+kernels — dq over (q-block, k-inner) and dk/dv over
 (k-block, q-inner) — so no T×T tensor ever materializes in either pass.
 
 Pattern source: /opt/skills/guides/pallas_guide.md (double-buffered matmul,
@@ -86,8 +88,55 @@ def _fwd_pairs(nq, nk, bq, bk, causal, window):
     return np.asarray(q_of, np.int32), np.asarray(k_of, np.int32)
 
 
-def _fwd_kernel(q_of, k_of, q_ref, k_ref, v_ref, *rest, scale, causal, bq,
-                bk, emit_lse, masked, window=None):
+def _last_live_steps(q_valid_len, q_of, nq, bq, heads):
+    """Each grid row's last live step (B * heads,) int32: the last pair of
+    the last Q block that holds a query row under its example's length (Q
+    block 0's at a length of 0, whose rows are all zeroed after the call).
+    Worked out once a call, so that the index maps and the kernel read one
+    word a step from it."""
+    starts = np.searchsorted(q_of, np.arange(1, nq + 1)).astype(np.int32)
+    n = jnp.clip((jnp.asarray(q_valid_len, jnp.int32) + bq - 1) // bq, 1, nq)
+    return jnp.repeat(jnp.asarray(starts)[n - 1] - 1, heads)
+
+
+def _fwd_step(b, t, last):
+    """The pair step ``t`` of row ``b`` reads: itself, or with query lengths
+    (``last``: the prefetched last live steps) on a step past the row's last
+    live pair that pair, so that the index maps fetch nothing more."""
+    return jnp.minimum(t, last[0][b]) if last else t
+
+
+def _fwd_index_maps(H, Hkv):
+    """The forward's Q (and output) and K/V index maps over grid (row, pair
+    step): step t of row b = batch * H + head computes Q block q_of[t]
+    against K/V block k_of[t] of K/V row batch * Hkv + head // group. The
+    prefetched refs follow the grid indices: ``q_of, k_of`` and, with query
+    lengths, each row's last live step."""
+    group = H // Hkv
+
+    def q_block(b, t, q_of, k_of, *last):
+        return (b, q_of[_fwd_step(b, t, last)], 0)
+
+    def kv_block(b, t, q_of, k_of, *last):
+        return ((b // H) * Hkv + (b % H) // group,
+                k_of[_fwd_step(b, t, last)], 0)
+
+    return q_block, kv_block
+
+
+def _and(a, b):
+    return b if a is True else jnp.logical_and(a, b)
+
+
+def _fwd_kernel(q_of, k_of, *refs, scale, causal, bq, bk, emit_lse, masked,
+                window=None, bounded=False):
+    # ``bounded``: query lengths came in (each row's last live step, one
+    # more prefetched ref), and a step past it does nothing at all
+    live = True
+    if bounded:
+        last_ref, *refs = refs
+        live = pl.program_id(1) <= last_ref[pl.program_id(0)]
+    q_ref, k_ref, v_ref, *rest = refs
     if masked:
         vl_ref, rest = rest[0], rest[1:]
         vl = vl_ref[0, 0, 0]
@@ -104,8 +153,10 @@ def _fwd_kernel(q_of, k_of, q_ref, k_ref, v_ref, *rest, scale, causal, bq,
     kv_idx = k_of[t]
     # a Q block's pairs lie side by side in the list: its first pair is the
     # one after another Q block's, its last the one before the next's
-    first = jnp.logical_or(t == 0, q_of[jnp.maximum(t - 1, 0)] != q_idx)
-    last = jnp.logical_or(t == n - 1, q_of[jnp.minimum(t + 1, n - 1)] != q_idx)
+    first = _and(live, jnp.logical_or(
+        t == 0, q_of[jnp.maximum(t - 1, 0)] != q_idx))
+    last = _and(live, jnp.logical_or(
+        t == n - 1, q_of[jnp.minimum(t + 1, n - 1)] != q_idx))
 
     @pl.when(first)
     def _init():
@@ -113,7 +164,7 @@ def _fwd_kernel(q_of, k_of, q_ref, k_ref, v_ref, *rest, scale, causal, bq,
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    run = True
+    run = live
     if causal:
         # the list holds only blocks this Q block reads, but for the pair
         # kept for a Q block that reads none, which this skips: the first
@@ -121,7 +172,7 @@ def _fwd_kernel(q_of, k_of, q_ref, k_ref, v_ref, *rest, scale, causal, bq,
         # kv_idx*bk <= q_idx*bq + bq - 1 (and, under a window, iff its last
         # column is still in the first row's window)
         lo, hi = _kv_block_range(q_idx, bq, bk, causal, window)
-        run = kv_idx <= hi
+        run = _and(run, kv_idx <= hi)
         if lo is not None:
             run = jnp.logical_and(run, kv_idx >= lo)
     if masked:
@@ -158,8 +209,10 @@ def _fwd_kernel(q_of, k_of, q_ref, k_ref, v_ref, *rest, scale, causal, bq,
 def _count_pairs(walked, grid):
     """How far the pair list engages, counted once a forward is traced: the
     (row, Q block, K block) steps walked against those of the whole
-    rectangle. Lazy import: the kernels stay importable before the
-    observability package is."""
+    rectangle. For a call with query lengths, which are known only when it
+    runs, ``walked`` is an upper bound: the steps past a row's last live
+    pair are counted, and do nothing. Lazy import: the kernels stay
+    importable before the observability package is."""
     from ...observability import registry
 
     registry.counter("flash_fwd_pairs_walked").inc(walked)
@@ -175,7 +228,7 @@ def _vl_operand(kv_valid_len, B, H):
 
 
 def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
-               return_lse=False, window=None):
+               return_lse=False, window=None, q_valid_len=None):
     B, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     Dv = v.shape[3]          # the values' own width (and the result's)
@@ -188,14 +241,18 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
     nq, nk = Tq // bq, Tk // bk
     q_of, k_of = _fwd_pairs(nq, nk, bq, bk, causal, window)
     _count_pairs(B * H * len(q_of), B * H * nq * nk)
-    group = H // Hkv
-    # the grid walks the pair list: step t of row b = batch * H + head
-    # computes Q block q_of[t] against K/V block k_of[t] of K/V row
-    # batch * Hkv + head // group, so that no block the kernel would skip
-    # is fetched and no step is spent on it
-    q_block = lambda b, t, q_of, k_of: (b, q_of[t], 0)
-    kv_block = lambda b, t, q_of, k_of: (
-        (b // H) * Hkv + (b % H) // group, k_of[t], 0)
+    # the grid walks the pair list, so that no block the kernel would skip
+    # is fetched and no step is spent on it; with query lengths a row's
+    # walk ends at the last pair of the last Q block that holds a live
+    # query row (the steps after it fetch nothing and do nothing)
+    q_block, kv_block = _fwd_index_maps(H, Hkv)
+    prefetch = [jnp.asarray(q_of), jnp.asarray(k_of)]
+    bounded = {}
+    if q_valid_len is not None:
+        q_valid_len = jnp.broadcast_to(
+            jnp.asarray(q_valid_len, jnp.int32), (B,))
+        prefetch.append(_last_live_steps(q_valid_len, q_of, nq, bq, H))
+        bounded = dict(bounded=True)
     in_specs = [
         pl.BlockSpec((1, bq, D), q_block),
         pl.BlockSpec((1, bk, D), kv_block),
@@ -213,11 +270,12 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
         out_shape.append(jax.ShapeDtypeStruct((B * H, Tq, LANES), jnp.float32))
     res = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal, bq=bq, bk=bk,
-                          emit_lse=return_lse, masked=masked, window=window),
+                          emit_lse=return_lse, masked=masked, window=window,
+                          **bounded),
         name="flash_fwd",
         interpret=interpret,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=len(prefetch),
             grid=(B * H, len(q_of)),
             in_specs=in_specs,
             out_specs=out_specs,
@@ -230,13 +288,28 @@ def _flash_fwd(q, k, v, kv_valid_len, scale, causal, bq, bk, interpret=False,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-    )(jnp.asarray(q_of), jnp.asarray(k_of), *operands)
+    )(*prefetch, *operands)
     if return_lse:
         out, lse = res
         # keep only one lane as the residual (saving the full 128-lane
         # broadcast would hold 128x the memory across fwd→bwd)
         return out.reshape(B, H, Tq, Dv), lse[..., :1]
-    return res[0].reshape(B, H, Tq, Dv)
+    out = res[0].reshape(B, H, Tq, Dv)
+    if q_valid_len is not None:
+        # the Q blocks past the last live one are never visited: whatever
+        # their buffer held becomes zeros, as do the live block's rows at
+        # and past the length
+        out = rows_before(out, q_valid_len)
+    return out
+
+
+def rows_before(x, q_valid_len):
+    """``x`` (B, H, T, D) with the rows at and past each example's query
+    length ``q_valid_len`` (B,) (or one for all) set to zeros: a select,
+    so that nothing the rows held, not even a NaN, reaches the result."""
+    rows = jnp.arange(x.shape[2], dtype=jnp.int32)[None, None, :, None]
+    n = jnp.asarray(q_valid_len, jnp.int32).reshape(-1, 1, 1, 1)
+    return jnp.where(rows < n, x, jnp.zeros((), x.dtype))
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
@@ -573,7 +646,7 @@ def _default_blocks(seq):
 
 def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
                     block_k=None, interpret=False, kv_valid_len=None,
-                    window=None):
+                    window=None, q_valid_len=None):
     """q,k,v: (B, H, T, D). D should be a multiple of 128 lanes ideally;
     T must be divisible by the chosen blocks (callers pad).
 
@@ -591,7 +664,13 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
 
     kv_valid_len: optional (B,) int — BERT-style key-padding: each example
     attends only to K/V positions < its valid length (columns beyond are
-    masked AND their blocks skipped entirely, forward and backward)."""
+    masked AND their blocks skipped entirely, forward and backward).
+
+    q_valid_len: optional (B,) int (or one for all) — the query rows that
+    count, a prompt's length inside its padded bucket: each example's walk
+    ends at the last Q block that holds a row under its length, the rows
+    before it are the call's without a length bit for bit, and the rows at
+    and past it are zeros. The forward alone: no gradient."""
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
     if block_q is None or block_k is None:
@@ -606,11 +685,12 @@ def flash_attention(q, k, v, causal=False, scale=None, block_q=None,
     bq = _largest_divisor_block(Tq, block_q)
     bk = _largest_divisor_block(Tk, block_k)
     if (window is not None or k.shape[1] != q.shape[1]
-            or v.shape[3] != q.shape[3]):
+            or v.shape[3] != q.shape[3] or q_valid_len is not None):
         return _flash_fwd(q, k, v, kv_valid_len, float(scale),
                           bool(causal) or window is not None, bq, bk,
                           interpret=interpret,
-                          window=None if window is None else int(window))
+                          window=None if window is None else int(window),
+                          q_valid_len=q_valid_len)
     return _flash(q, k, v, kv_valid_len, float(scale), bool(causal), bq, bk,
                   interpret)
 

@@ -781,7 +781,7 @@ class GenerativeServer:
         c.update(state, valid)
         self._tok = toks
         if hit is None:
-            self.metrics.record_prefill()
+            self.metrics.record_prefill(t0_len, tp)
             if self.prefix is not None:
                 # one page read-out per UNIQUE prompt; repeats skip the
                 # whole forward from then on. The store keeps host copies:

@@ -231,6 +231,8 @@ class GenerativeMetrics(ServeMetrics):
         self.tokens = 0                     # generated tokens, all requests
         self.steps = 0                      # decode dispatches
         self.prefills = 0                   # whole-prompt forward dispatches
+        self.prefill_rows = 0               # their prompts' tokens
+        self.prefill_bucket_rows = 0        # their padded buckets' rows
         self.prefill_chunks = 0             # chunked-prefill dispatches
         self._decode_s = 0.0                # decode-active wall time
         self._active_slot_steps = 0         # live slots summed over steps
@@ -277,9 +279,15 @@ class GenerativeMetrics(ServeMetrics):
                 ent[0][ent[1] % self._window] = float(ms)
                 ent[1] += 1
 
-    def record_prefill(self, n=1):
+    def record_prefill(self, rows=0, bucket=0):
+        """One prefill; a whole-prompt one gives its prompt's ``rows`` and
+        the ``bucket`` it was padded to: ``1 - prefill_rows /
+        prefill_bucket_rows`` is the share of the rows computed whose
+        attention the flash forward skips."""
         with self._lock:
-            self.prefills += n
+            self.prefills += 1
+            self.prefill_rows += int(rows)
+            self.prefill_bucket_rows += int(bucket)
 
     def record_chunk(self, n=1):
         with self._lock:
@@ -344,6 +352,8 @@ class GenerativeMetrics(ServeMetrics):
                 "tokens": self.tokens,
                 "decode_steps": self.steps,
                 "prefills": self.prefills,
+                "prefill_rows": self.prefill_rows,
+                "prefill_bucket_rows": self.prefill_bucket_rows,
                 "prefill_chunks": self.prefill_chunks,
                 "tokens_per_s": (round(self.tokens / self._decode_s, 1)
                                  if self._decode_s > 0 else None),

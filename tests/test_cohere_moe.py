@@ -124,6 +124,46 @@ def test_the_server_counts_the_experts_load(served):
         == "xmax=3.00 xhit=8 kvread=0.000"
 
 
+@pytest.mark.parametrize("plen", [1, 20, 47, 64])
+def test_rows_past_the_prompt_attend_to_nothing(monkeypatch, plen):
+    """The prefill with the prompt's length handed to its grouped and
+    windowed attention against the same prefill without it: the last
+    row's logits, the experts' load and every kept K/V row before the
+    length are the same bits; those at and past it stay finite."""
+    from mxnet_tpu import _trace
+    from mxnet_tpu.models import cohere_moe as C
+
+    _cfg, model, _params = _seeded(seed=13)
+    tokens = jnp.asarray(np.random.RandomState(13).randint(0, 256, (1, 64)),
+                         jnp.int32)
+    plist = list(model.collect_params().values())
+
+    def prefill():
+        with _trace.trace_scope(jax.random.PRNGKey(0), False) as t:
+            t.param_store = {id(p): p.data()._data for p in plist}
+            return model.forward_collect_kv(_trace.F, tokens, jnp.int32(plen))
+
+    logits, kvs, load = prefill()
+    whole = C._GroupedAttention.forward_kv
+    monkeypatch.setattr(C._GroupedAttention, "forward_kv",
+                        lambda self, F, h, plen=None: whole(self, F, h))
+    logits0, kvs0, load0 = prefill()
+    assert logits.shape == (1, 1, 256)
+    assert np.asarray(logits).tobytes() == np.asarray(logits0).tobytes()
+    assert np.array_equal(np.asarray(load), np.asarray(load0))
+    for kv, kv0 in zip(kvs, kvs0):
+        for a, a0 in zip(kv, kv0):
+            a, a0 = np.asarray(a), np.asarray(a0)
+            assert a[:, :, :plen].tobytes() == a0[:, :, :plen].tobytes()
+            assert np.isfinite(a).all()
+    # the length reached the attention: from the second layer on, the rows
+    # past the prompt are made from zeros where they were made from scores
+    late = [np.asarray(a)[:, :, plen:] for kv in kvs[1:] for a in kv]
+    late0 = [np.asarray(a)[:, :, plen:] for kv in kvs0[1:] for a in kv]
+    assert (plen == 64) == all(np.array_equal(a, a0)
+                               for a, a0 in zip(late, late0))
+
+
 @pytest.mark.parametrize("option", [
     {"quantize": "int8"}, {"draft": serve.NGramDraft()},
     {"prefill_chunk": 16}])

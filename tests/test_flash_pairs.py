@@ -251,3 +251,164 @@ def test_counters_read_the_share_walked_at_16384_tokens():
     dg = registry.counter("flash_fwd_pairs_grid").value - grid
     assert (dw, dg) == (16 * 1056, 16 * 2048)
     assert round(dw / dg, 3) == 0.516
+
+
+# ----------------------------------------- the walk that stops at a length
+def _capture_calls(monkeypatch):
+    """Records what each ``pallas_call`` of the forward is built from: the
+    grid spec and the kernel's keywords, then the arguments it is called
+    with (the prefetched arrays first)."""
+    calls = []
+    real = FA.pl.pallas_call
+
+    def spy(kernel, **kw):
+        call = real(kernel, **kw)
+
+        def run(*args):
+            calls.append(dict(spec=kw["grid_spec"], kernel=kernel.keywords,
+                              args=args))
+            return call(*args)
+
+        return run
+
+    monkeypatch.setattr(FA.pl, "pallas_call", spy)
+    return calls
+
+
+_LEN_CASES = {
+    # B, H, Hkv, T, D, Dv, bq, bk, window
+    "causal": (1, 2, 2, 256, 32, 32, 32, 64, None),
+    "window": (1, 2, 2, 256, 32, 32, 32, 32, 72),
+    "grouped_4_over_1": (1, 4, 1, 128, 16, 16, 16, 32, None),
+    "two_widths_192_128": (1, 2, 2, 128, 192, 128, 32, 64, None),
+}
+
+
+def _lengths(T, bq, bk, window):
+    """1, bq - 1, bq, bq + 1, T - 1, T, and a length whose last live Q block
+    holds one pair (there the first pair is the last: a step after it must
+    not start the block again)."""
+    q_of, _ = FA._fwd_pairs(T // bq, T // bk, bq, bk, True, window)
+    single = [i for i in range(T // bq) if (q_of == i).sum() == 1]
+    assert single, "no Q block of one pair at these blocks"
+    one_pair = single[-1] * bq + bq // 2
+    return sorted({1, bq - 1, bq, bq + 1, one_pair, T - 1, T})
+
+
+@pytest.mark.parametrize("case", sorted(_LEN_CASES))
+def test_a_query_length_keeps_the_live_rows_and_zeroes_the_rest(case):
+    """Rows under the length equal the call without one bit for bit (the
+    same K/V blocks fold in the same order); the rows at and past it are
+    zeros."""
+    B, H, Hkv, T, D, Dv, bq, bk, window = _LEN_CASES[case]
+    q, k, v = _arrays(B, H, Hkv, T, T, D, Dv)
+    full = np.asarray(FA._flash_fwd(q, k, v, None, 0.13, True, bq, bk,
+                                    interpret=True, window=window))
+    for n in _lengths(T, bq, bk, window):
+        got = np.asarray(FA._flash_fwd(
+            q, k, v, None, 0.13, True, bq, bk, interpret=True, window=window,
+            q_valid_len=jnp.asarray([n], jnp.int32)))
+        assert got.shape == full.shape
+        assert got[:, :, :n].tobytes() == full[:, :, :n].tobytes(), n
+        np.testing.assert_array_equal(got[:, :, n:], 0.0)
+
+
+def test_each_example_stops_at_its_own_length():
+    B, H, T, bq, bk = 2, 2, 256, 32, 64
+    q, k, v = _arrays(B, H, H, T, T, 32, 32)
+    full = np.asarray(FA._flash_fwd(q, k, v, None, 0.13, True, bq, bk,
+                                    interpret=True))
+    for lens in ([1, 256], [100, 33], [0, 64]):
+        got = np.asarray(FA.flash_attention(
+            q, k, v, causal=True, scale=0.13, block_q=bq, block_k=bk,
+            interpret=True, q_valid_len=jnp.asarray(lens, jnp.int32)))
+        for b, n in enumerate(lens):
+            assert got[b, :, :n].tobytes() == full[b, :, :n].tobytes()
+            np.testing.assert_array_equal(got[b, :, n:], 0.0)
+
+
+@pytest.mark.parametrize("case", sorted(_LEN_CASES))
+def test_nothing_is_fetched_past_the_last_live_pair(monkeypatch, case):
+    """The index maps, evaluated over every grid step: up to a row's last
+    live pair they are the pair list's; from there on they return that
+    pair's blocks, so the block indices change on live steps alone and
+    nothing is fetched after them."""
+    B, H, Hkv, T, D, Dv, bq, bk, window = _LEN_CASES[case]
+    calls = _capture_calls(monkeypatch)
+    q, k, v = _arrays(2, H, Hkv, T, T, D, Dv)
+    lens = np.asarray([37, T], np.int32)
+    FA._flash_fwd(q, k, v, None, 0.13, True, bq, bk, interpret=True,
+                  window=window, q_valid_len=jnp.asarray(lens))
+    (call,) = calls
+    spec = call["spec"]
+    q_of, k_of, last = (np.asarray(a) for a in call["args"][:3])
+    assert spec.num_scalar_prefetch == 3 and last.shape == (2 * H,)
+    q_map = spec.in_specs[0].index_map
+    kv_map = spec.in_specs[1].index_map
+    out_map = spec.out_specs[0].index_map
+    for b in range(2 * H):
+        # the pairs of the Q blocks that hold a row under the length
+        end = int((q_of < -(-lens[b // H] // bq)).sum())
+        assert last[b] == end - 1
+        seen = []
+        for t in range(len(q_of)):
+            at = (b, t, q_of, k_of, last)
+            qi = tuple(int(x) for x in q_map(*at))
+            ki = tuple(int(x) for x in kv_map(*at))
+            tt = min(t, end - 1)
+            assert qi == (b, q_of[tt], 0)
+            assert tuple(int(x) for x in out_map(*at)) == qi
+            assert ki == ((b // H) * Hkv + (b % H) // (H // Hkv), k_of[tt], 0)
+            seen.append((qi, ki))
+        changes = [t for t in range(1, len(seen)) if seen[t] != seen[t - 1]]
+        assert all(t < end for t in changes)
+        assert (end == len(q_of)) == (lens[b // H] == T)
+
+
+def test_without_a_length_the_call_is_built_as_before(monkeypatch):
+    """No length: the grid, the two prefetched pair arrays and the operands
+    of the pair walk, and the kernel's keywords, as they were; a length
+    adds each grid row's last live step to the prefetch and nothing
+    else."""
+    calls = _capture_calls(monkeypatch)
+    B, H, Hkv, T, D, Dv, bq, bk = 1, 4, 2, 128, 16, 8, 16, 32
+    q, k, v = _arrays(B, H, Hkv, T, T, D, Dv)
+    FA.flash_attention(q, k, v, causal=True, scale=0.13, block_q=bq,
+                       block_k=bk, interpret=True)
+    FA.flash_attention(q, k, v, causal=True, scale=0.13, block_q=bq,
+                       block_k=bk, interpret=True,
+                       q_valid_len=jnp.asarray([50], jnp.int32))
+    plain, bounded = calls
+    q_of, k_of = FA._fwd_pairs(T // bq, T // bk, bq, bk, True, None)
+    for call in (plain, bounded):
+        assert call["spec"].grid == (B * H, len(q_of))
+        assert np.array_equal(np.asarray(call["args"][0]), q_of)
+        assert np.array_equal(np.asarray(call["args"][1]), k_of)
+    assert plain["spec"].num_scalar_prefetch == 2
+    assert [a.shape for a in plain["args"][2:]] == [
+        (B * H, T, D), (B * Hkv, T, D), (B * Hkv, T, Dv)]
+    assert set(plain["kernel"]) == {"scale", "causal", "bq", "bk",
+                                    "emit_lse", "masked", "window"}
+    assert bounded["spec"].num_scalar_prefetch == 3
+    assert [a.shape for a in bounded["args"][3:]] == [
+        a.shape for a in plain["args"][2:]]
+    assert bounded["kernel"] == dict(plain["kernel"], bounded=True)
+
+
+def test_the_dense_lowerings_keep_the_same_contract():
+    """Off the chip the seam's dense paths (plain, grouped, windowed) give
+    the rows before the length as without it and zeros from it on."""
+    from mxnet_tpu.ops import attention as A
+
+    q, k, v = _arrays(2, 4, 2, 48, 48, 16, 16)
+    lens = jnp.asarray([5, 48], jnp.int32)
+    for kk, vv, window in ((k, v, None), (k, v, 12),
+                           (jnp.repeat(k, 2, 1), jnp.repeat(v, 2, 1), None)):
+        full = np.asarray(A.scaled_dot_attention(q, kk, vv, causal=True,
+                                                 window=window))
+        got = np.asarray(A.scaled_dot_attention(q, kk, vv, causal=True,
+                                                window=window,
+                                                q_valid_len=lens))
+        assert got[0, :, :5].tobytes() == full[0, :, :5].tobytes()
+        np.testing.assert_array_equal(got[0, :, 5:], 0.0)
+        assert got[1].tobytes() == full[1].tobytes()

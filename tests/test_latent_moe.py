@@ -320,6 +320,61 @@ def test_attention_takes_values_of_their_own_width():
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+@pytest.mark.parametrize("plen", [1, 37, 63, 64])
+def test_rows_past_the_prompt_attend_to_nothing(monkeypatch, plen):
+    """The prefill with the prompt's length handed to its attention against
+    the same prefill without it (the length went no further than the
+    experts before): the last row's logits, the experts' load and every
+    kept row before the length are the same bits; the kept rows at and past
+    it stay finite (the decode reads their blocks and masks them)."""
+    cfg, model, _params = _seeded(seed=12)
+    tokens = jnp.asarray(np.random.RandomState(12).randint(0, 256, (1, 64)),
+                         jnp.int32)
+    at = jnp.int32(plen)
+    logits, kept, load = _trace_call(model, model.forward_collect_kv, tokens,
+                                     at)
+    whole = L._LatentAttention.forward_kv
+    monkeypatch.setattr(L._LatentAttention, "forward_kv",
+                        lambda self, F, h, plen=None: whole(self, F, h))
+    logits0, kept0, load0 = _trace_call(model, model.forward_collect_kv,
+                                        tokens, at)
+    assert logits.shape == (1, 1, 256)
+    assert np.asarray(logits).tobytes() == np.asarray(logits0).tobytes()
+    assert np.array_equal(np.asarray(load), np.asarray(load0))
+    for rows, rows0 in zip(kept, kept0):
+        for a, a0 in zip(rows, rows0):
+            a, a0 = np.asarray(a), np.asarray(a0)
+            assert a[:, :, :plen].tobytes() == a0[:, :, :plen].tobytes()
+            assert np.isfinite(a).all()
+    # the length reached the attention: from the second layer on, the rows
+    # past the prompt are made from zeros where they were made from scores
+    late = [np.asarray(a)[:, :, plen:] for kv in kept[1:] for a in kv]
+    late0 = [np.asarray(a)[:, :, plen:] for kv in kept0[1:] for a in kv]
+    assert (plen == 64) == all(np.array_equal(a, a0)
+                               for a, a0 in zip(late, late0))
+
+
+def test_the_server_counts_a_prefills_rows_and_its_bucket(served):
+    """``prefill_rows`` / ``prefill_bucket_rows``: a prompt's tokens and
+    the pow2 bucket it was padded to, once a prefill; an inject from the
+    prefix store adds to neither."""
+    _cfg, _model, _params, srv = served
+    prompt = np.random.RandomState(21).randint(0, 256, 45)
+    s0 = srv.stats()
+    srv.submit(prompt, max_new_tokens=3).result(timeout_s=120)
+    s1 = srv.stats()
+    srv.submit(prompt, max_new_tokens=3).result(timeout_s=120)
+    s2 = srv.stats()
+    assert s1["prefills"] - s0["prefills"] == 1
+    assert s1["prefill_rows"] - s0["prefill_rows"] == 45
+    assert s1["prefill_bucket_rows"] - s0["prefill_bucket_rows"] == 64
+    assert s2["prefix_hits"] - s1["prefix_hits"] == 1
+    assert [s2[k] - s1[k] for k in ("prefills", "prefill_rows",
+                                    "prefill_bucket_rows")] == [0, 0, 0]
+    assert serve.stats()["servers"][srv.name]["prefill_rows"] \
+        == s2["prefill_rows"]
+
+
 # ------------------------------------------------------ the latent page
 def test_the_latent_page_is_one_row_a_position():
     c = PagedKVCache(3, 1, (32, 8), slots=4, max_capacity=128,

@@ -432,3 +432,34 @@ def test_flash_forward_walks_its_pair_list_at_16384(one_chip):
         sd((1, 16, 16384, 192)), sd((1, 16, 16384, 192)),
         sd((1, 16, 16384, 128))).compile()
     assert "flash_fwd" in compiled.as_text()
+
+
+def test_expanded_attention_compiles_with_the_prompts_length(one_chip,
+                                                              monkeypatch):
+    """ax-k1's prefill attention at the 8,192 bucket (64 heads in groups of
+    16 under ``lax.map``, a latent of 512, a query rank of 1,536, keys of
+    128 + 64, values of 128) with the prompt's length: Mosaic takes the
+    forward's two more prefetched arrays (each Q block's first pair and the
+    length) and the index maps that stop at the last live pair, and the
+    zeros written past the length cost no temporaries of their own."""
+    from mxnet_tpu.ops import attention as A
+
+    monkeypatch.setattr(A, "is_tpu_backend", lambda: True)
+    T, H, Rq, R, N, P, Dv = 8192, 64, 1536, 512, 128, 64, 128
+    sd = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    inv = tuple(float(f) for f in 10000.0 ** (-np.arange(0, P, 2) / P))
+
+    def attend(c_q, c_kv, k_pe, wq, wk, wv, *n):
+        return A.expanded_latent_attention(
+            c_q, c_kv, k_pe, wq, wk, wv, heads=H, scale=0.130861,
+            inv_freq=inv, q_valid_len=n[0] if n else None)
+
+    args = (sd((1, T, Rq)), sd((1, 1, T, R)), sd((1, 1, T, P)),
+            sd((H * (N + P), Rq)), sd((H * N, R)), sd((H * Dv, R)))
+    plain = jax.jit(attend).lower(*args).compile()
+    bounded = jax.jit(attend).lower(*args, sd((), jnp.int32)).compile()
+    assert "flash_fwd" in bounded.as_text()
+    temp = lambda c: c.memory_analysis().temp_size_in_bytes
+    assert temp(bounded) <= temp(plain) + (1 << 20), (temp(bounded),
+                                                      temp(plain))

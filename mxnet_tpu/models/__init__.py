@@ -6,7 +6,10 @@ grouped K/V heads, window rings beside full pages, routed experts), a
 Brumby style decoder (``brumby``: power retention, whose cache is a
 recurrent state of fixed size a slot) and a DeepSeek-V3 lineage share
 (``latent_moe``: latent attention, whose cache is one compressed row a
-position, a dense layer and then routed experts with a scaled sum)."""
+position, a dense layer and then routed experts with a scaled sum) and a
+Nemotron-H style hybrid share (``nemotron_h``: Mamba-2 mixers, whose cache
+is a snapshot of state, beside an attention layer with K/V pages, and
+latent experts)."""
 from . import bert  # noqa: F401
 from . import lstm_lm  # noqa: F401
 from . import transformer  # noqa: F401
@@ -16,6 +19,7 @@ from . import gpt  # noqa: F401
 from . import cohere_moe  # noqa: F401
 from . import brumby  # noqa: F401
 from . import latent_moe  # noqa: F401
+from . import nemotron_h  # noqa: F401
 from . import yolo  # noqa: F401
 from . import fcn  # noqa: F401
 from . import pose  # noqa: F401

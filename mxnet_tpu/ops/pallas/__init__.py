@@ -1,6 +1,6 @@
 """Pallas TPU kernels for the hot ops (flash attention, fused layernorm,
 fused softmax cross-entropy, the decode step's K/V write and read, the grouped
-expert FFN, power retention's decode step).
+expert FFN, power retention's decode step, the Mamba-2 mixer's decode step).
 
 The ops that own a kernel gate into it at trace time, from what they can
 see then: the default backend is a TPU, the static shapes tile, and the

@@ -64,6 +64,15 @@ fetches only the blocks that hold a slot's live positions, once for all
 heads. Its prompt write, read-out and the prefix store's entries are those
 rows: 1,152 bytes a position and layer in bfloat16 where 64 heads of K (192)
 and V (128) would take 40,960.
+
+Nor does every model keep one kind of record in all its layers. A hybrid
+(``models/nemotron_h.py``: Mamba-2 mixers beside an attention layer) hands
+the cache a LAYOUT, one ``(record, sizes)`` a cache layer, each with its
+own geometry: ``SSMPage`` snapshots (a selective state-space
+layer's conv window and state, ``ops/ssm.py``) beside a ``PlainPage`` of
+K/V rows. Every operation of the cache works layer by layer, so a prefix
+hit restores the snapshots and the rows together, and a traced step's tag
+gives each kind's field (``step_tag``).
 """
 from __future__ import annotations
 
@@ -101,21 +110,20 @@ def _zero():
         return jnp.int32(0)
 
 
-def _slot_start(slot):
-    zero = _zero()
-    return (slot, zero, zero, zero)
+def _slot_start(slot, ndim=4):
+    return (slot,) + (_zero(),) * (ndim - 1)
 
 
 def _take(a, slot):
-    return jax.lax.dynamic_slice(a, _slot_start(slot), (1,) + a.shape[1:])
+    return jax.lax.dynamic_slice(a, _slot_start(slot, a.ndim),
+                                 (1,) + a.shape[1:])
 
 
 def _put(pool, slot, page):
     """``pool`` with ``slot``'s page replaced by ``page`` (a pool of one
     slot, as ``take_slot`` gave it), leaf by leaf."""
-    at = _slot_start(slot)
-    return type(pool)(*(jax.lax.dynamic_update_slice(a, p, at)
-                        for a, p in zip(pool, page)))
+    return type(pool)(*(jax.lax.dynamic_update_slice(
+        a, p, _slot_start(slot, a.ndim)) for a, p in zip(pool, page)))
 
 
 def _pad_time(a, more):
@@ -313,15 +321,15 @@ class StatePage(NamedTuple):
     def grow(self, more):
         return self
 
-    def write_prompt(self, S, z, plen, slot):
-        """The pool with the state after a prompt (``S``, ``z`` of one
-        slot, as the model's prefill or the prefix store gives them) in
-        ``slot``'s place."""
-        return _put(self, slot, StatePage(S, z))
+    def write_prompt(self, a, b, plen, slot):
+        """The pool with the state after a prompt (its two buffers, ``S``
+        and ``z``, of one slot, as the model's prefill or the prefix store
+        gives them) in ``slot``'s place."""
+        return _put(self, slot, type(self)(a, b))
 
     def read_prompt(self, slot, n):
-        """``slot``'s state as the prefix store keeps it: (S, z) without
-        the slot axis."""
+        """``slot``'s state as the prefix store keeps it: both buffers
+        without the slot axis."""
         return tuple(_take(a, slot)[0] for a in self)
 
     def prompt_length(self, tp):
@@ -336,8 +344,8 @@ class StatePage(NamedTuple):
 
     def take_slot(self, slot, fresh):
         # a fresh stream starts from nothing
-        return StatePage(*(jnp.where(fresh, 0.0, _take(a, slot))
-                           for a in self))
+        return type(self)(*(jnp.where(fresh, 0.0, _take(a, slot))
+                            for a in self))
 
     put_slot = _put
 
@@ -348,6 +356,38 @@ class StatePage(NamedTuple):
         contexts."""
         return "state=%.1f" % (1e-6 * len(contexts) * sum(
             page.prompt_bytes(0) for page in pages))
+
+
+class SSMPage(NamedTuple):
+    """The recurrent state of one selective state-space (Mamba-2) layer, one
+    a slot, of the same size whatever the context: ``conv`` (slots, width -
+    1, channels) in the model's dtype, the last inputs of the layer's causal
+    conv, and ``S`` (slots, heads, head_dim, state) in float32, the SSM's
+    state (``ops/ssm.py``). Like a :class:`StatePage` it has no time axis,
+    and what moves in and out of the pool is a SNAPSHOT of both buffers
+    after a prompt's last token."""
+
+    conv: Any
+    S: Any
+
+    snapshot = True
+
+    @classmethod
+    def zeros(cls, slots, heads, length, head_dim, dtype, state, channels,
+              width):
+        return cls(jnp.zeros((slots, width - 1, channels), dtype),
+                   jnp.zeros((slots, heads, head_dim, state), jnp.float32))
+
+    # the snapshot's way in and out of the pool is a StatePage's
+    grow = StatePage.grow
+    write_prompt = StatePage.write_prompt
+    read_prompt = StatePage.read_prompt
+    prompt_length = StatePage.prompt_length
+    prompt_bytes = StatePage.prompt_bytes
+    plain_bytes = StatePage.plain_bytes
+    take_slot = StatePage.take_slot
+    put_slot = _put
+    step_tag = staticmethod(StatePage.step_tag)
 
 
 class LatentPage(NamedTuple):
@@ -399,6 +439,18 @@ class LatentPage(NamedTuple):
     step_tag = staticmethod(_kvread_tag)
 
 
+def step_tag(state, contexts):
+    """The fields of a traced step's span from every kind of record in
+    ``state``, in the order the kinds first appear: each kind's own
+    ``step_tag`` over its own layers (``state=<MB> kvread=<share>`` of a
+    hybrid's snapshots and rows)."""
+    kinds = {}
+    for page in state:
+        kinds.setdefault(type(page), []).append(page)
+    return " ".join(kind.step_tag(pages, contexts)
+                    for kind, pages in kinds.items())
+
+
 def write_prompt(state, kvs, plen, slot):
     """The state with a prompt's K/V (``kvs``: one (k, v) a layer, each
     (1, H, tp, D)) written into ``slot``'s page of every layer. Traced."""
@@ -408,13 +460,15 @@ def write_prompt(state, kvs, plen, slot):
 
 def read_prompt(state, slot, lengths):
     """``slot``'s page read out as the prefix store keeps it: (k_stack,
-    v_stack), each one stacked array where every layer's page has the same
-    length, else (window rings beside full pages) one array a layer. The
-    two stacks are whatever the record keeps two of: K and V, or a
-    :class:`StatePage`'s ``S`` and ``z``.
+    v_stack), each one stacked array where every layer's page is of one
+    kind and has the same length, else (window rings beside full pages,
+    snapshots beside rows) one array a layer. The two stacks are whatever
+    the record keeps two of: K and V, a :class:`StatePage`'s ``S`` and
+    ``z``, an :class:`SSMPage`'s ``conv`` and ``S``.
     ``lengths``: ``PagedKVCache.page_lengths`` of the prompt's bucket.
     Traced."""
-    pack = jnp.stack if len(set(lengths)) == 1 else tuple
+    pack = jnp.stack if len(set(lengths)) == 1 \
+        and len({type(page) for page in state}) == 1 else tuple
     ks, vs = zip(*(page.read_prompt(slot, n)
                    for page, n in zip(state, lengths)))
     return pack(ks), pack(vs)
@@ -470,12 +524,17 @@ class PagedKVCache:
         layer, whose time axis is ``min(capacity, window)``: the model
         writes position p at ``p % length``, so a ring never holds more
         than its window and never grows past it. Not with ``quantize``.
-    page : None or type
+    page : None, type or list
         The page record of a model that keeps its own format
         (``decode_state_spec()["page"]``: :class:`StatePage`, a recurrent
         state of fixed size, :class:`LatentPage`, compressed rows in the
         place of K and V, or the model's own); by default
-        :class:`Int8Page` with ``quantize``, else :class:`PlainPage`.
+        :class:`Int8Page` with ``quantize``, else :class:`PlainPage`. Or a
+        LAYOUT: one ``(record, sizes)`` a layer, each layer with its own
+        record and the keyword sizes its ``zeros`` takes besides ``slots``,
+        ``length`` and ``dtype`` (a hybrid's :class:`SSMPage` snapshots
+        beside a :class:`PlainPage`), in the place of ``heads`` and
+        ``head_dim``.
     """
 
     def __init__(self, layers, heads, head_dim, slots, max_capacity,
@@ -495,9 +554,22 @@ class PagedKVCache:
         self.slots = int(slots)
         self.max_capacity = int(max_capacity)
         self.dtype = np.dtype(dtype)
-        self.page = page or (Int8Page if quantize else PlainPage)
-        # the record's word on what its read-out is (StatePage: the state)
-        self.snapshots = bool(getattr(self.page, "snapshot", False))
+        if isinstance(page, (list, tuple)):
+            if len(page) != self.layers or quantize:
+                raise CacheError("a layout of %d records for %d layers%s"
+                                 % (len(page), self.layers,
+                                    " takes no int8 pages" if quantize
+                                    else ""))
+            self.layout = [(rec, dict(sizes)) for rec, sizes in page]
+        else:
+            rec = page or (Int8Page if quantize else PlainPage)
+            self.layout = [(rec, dict(heads=self.heads,
+                                      head_dim=self.head_dim))] * self.layers
+        kinds = {rec for rec, _sizes in self.layout}
+        # the records' word on what their read-out is (StatePage,
+        # SSMPage: the state): a cache that holds any snapshot counts them
+        self.snapshots = any(getattr(rec, "snapshot", False)
+                             for rec in kinds)
         self.capacity = 0
         self.state = None     # list[L] of page records, once allocated
         self.valid = jnp.zeros((self.slots,), jnp.int32)
@@ -534,6 +606,17 @@ class PagedKVCache:
         return sum(page.prompt_bytes(n)
                    for page, n in zip(self.state, self.page_lengths(tp)))
 
+    def snapshot_bytes(self):
+        """Bytes of one slot's snapshots (the records whose read-out is a
+        state), as the prefix store keeps them: 0 for pages of rows."""
+        return sum(page.prompt_bytes(0) for page in self.state or ()
+                   if getattr(page, "snapshot", False))
+
+    def state_nbytes(self):
+        """Bytes of the pools of snapshot records, all slots."""
+        return sum(int(a.nbytes) for page in self.state or ()
+                   if getattr(page, "snapshot", False) for a in page)
+
     def ensure_capacity(self, need):
         """Grow the buffers to the bucket that fits ``need`` (zero-padding
         the time axis — one migration dispatch per layer, then the decode
@@ -545,9 +628,9 @@ class PagedKVCache:
             return False
         if self.state is None:
             self.state = [
-                self.page.zeros(self.slots, self.heads,
-                                self.layer_length(i, cap), self.head_dim,
-                                self.dtype) for i in range(self.layers)]
+                rec.zeros(self.slots, length=self.layer_length(i, cap),
+                          dtype=self.dtype, **sizes)
+                for i, (rec, sizes) in enumerate(self.layout)]
         else:
             # a ring that has reached its window stays as it is: until then
             # no position has wrapped, and padding keeps p % length == p

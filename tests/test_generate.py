@@ -505,7 +505,7 @@ def test_one_decode_step_serves_step_verify_and_chunk(quantize, rng):
                                rtol=0, atol=2e-6)
     assert float(jnp.abs(one).max()) > 0.1
     assert [type(p) for p in state1] == [type(p) for p in state2] \
-        == [cache.page] * 2
+        == [rec for rec, _sizes in cache.layout]
 
     prompt = rng.randint(0, 256, (21,)).tolist()
     kwargs = dict(prefix_cache=False, quantize=quantize)

@@ -12,9 +12,11 @@ up and down matrices, computes ``silu(x Wg^T) * (x Wu^T)`` for the block and
 adds its product with the down block into a float32 accumulator that lives
 across the inner blocks. All three matrices are stored ``(experts, f, d)``,
 so a block is ``bf`` whole rows: contiguous in HBM, and read once a tile.
-Tiles past the last routed one (the static grid is the worst case) name the
-last real tile's expert and its last block: the pipeline fetches nothing for
-them, and they write zeros.
+Where an expert's matrices fit the VMEM budget whole, the block is the whole
+inner width (one step a tile). Tiles past the last routed one (the static
+grid is the worst case) name the last real tile's expert and its last block:
+the pipeline fetches nothing for them, and they write zeros; each still
+costs its steps, which is why a tile walks as few blocks as fit.
 
 In a decode step a tile is 16 rows and the kernel's time is the bytes of the
 experts the live tokens chose; in a prefill a tile is 256 rows and an
@@ -35,11 +37,15 @@ _VMEM_LIMIT = 96 << 20
 
 
 def _block_f(f, d, itemsize, mats=3, prefer=512):
-    """Rows of the inner width a step loads: the largest multiple of 128 up
-    to ``prefer`` that divides ``f`` (2688 = 21 x 128: 384), where ``mats``
-    double-buffered blocks stay within half the limit; an ``f`` that no
-    multiple of 128 divides halves from ``prefer`` instead."""
+    """Rows of the inner width a step loads, where ``mats`` double-buffered
+    blocks stay within half the limit: the whole of an ``f`` of whole lane
+    tiles where that fits (2688 x 1024 relu^2: one block), else the largest
+    multiple of 128 up to ``prefer`` that divides ``f`` (4096 x 4096 and
+    2048 x 7168 SwiGLU: 512); an ``f`` that no multiple of 128 divides
+    halves from ``prefer`` instead."""
     fits = lambda bf: 2 * mats * bf * d * itemsize <= _VMEM_LIMIT // 2
+    if f % 128 == 0 and fits(f):
+        return f
     whole = [bf for bf in range(128, min(prefer, f) + 1, 128)
              if f % bf == 0 and fits(bf)]
     if whole:
@@ -61,6 +67,17 @@ def tiles(rows, d, f, dtype, act="swiglu"):
     return (itemsize in (2, 4) and rows % (32 // itemsize) == 0
             and d % 128 == 0
             and _block_f(f, d, itemsize, _mats(act)) % 128 == 0)
+
+
+def _count_grid(tiles, steps):
+    """How far the whole-width block engages, counted once a call is traced:
+    the grid's row tiles and its steps, whose ratio is the blocks a tile
+    walks. Lazy import: the kernels stay importable before the
+    observability package is."""
+    from ...observability import registry
+
+    registry.counter("moe_ffn_grid_tiles").inc(tiles)
+    registry.counter("moe_ffn_grid_steps").inc(steps)
 
 
 def _moe_ffn_kernel(act, te_ref, tv_ref, x_ref, *refs):
@@ -106,6 +123,7 @@ def moe_ffn(x, tile_expert, tile_valid, w_gate, w_up, w_down, tm,
     f = w_up.shape[1]
     bf = _block_f(f, d, x.dtype.itemsize, _mats(act))
     nj = f // bf
+    _count_grid(M // tm, M // tm * nj)
 
     def w_block(t, j, te, tv):
         return (te[t], jnp.where(tv[t] > 0, j, nj - 1), 0)

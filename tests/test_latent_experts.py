@@ -102,11 +102,17 @@ def test_the_selection_bias_picks_and_never_weighs():
     np.testing.assert_allclose(np.asarray(r_w), np.asarray(bw), rtol=1e-6)
 
 
+@pytest.mark.parametrize("split", [False, True], ids=["whole", "blocks"])
 @pytest.mark.parametrize("tm,f", [(16, 256), (16, 384)])
-def test_relu2_is_its_three_line_form(tm, f):
+def test_relu2_is_its_three_line_form(tm, f, split, monkeypatch):
     """The grouped relu^2 FFN, kernel (interpret mode) and XLA, against
     ``relu(x Wu^T)^2 Wd`` written out for every tile: two matrices an
-    expert, no gate; a tile that is not valid is zeros."""
+    expert, no gate; a tile that is not valid is zeros. The kernel runs
+    with the whole inner width as one block, and (a VMEM budget lowered
+    to two double-buffered blocks of 128) in several."""
+    if split:
+        monkeypatch.setattr(K, "_VMEM_LIMIT", 2 * (2 * 2 * 128 * 128 * 4))
+    assert K._block_f(f, 128, 4, mats=2) == (128 if split else f)
     rs = np.random.RandomState(f)
     E, d, tiles = 3, 128, 4
     x = jnp.asarray(rs.normal(0, 1, (tiles * tm, d)), jnp.float32)
@@ -131,15 +137,47 @@ def test_relu2_is_its_three_line_form(tm, f):
 
 
 def test_the_inner_blocks_divide_the_published_widths():
-    """Nemotron-3-Super's 2688 = 21 x 128 takes blocks of 384 (a multiple of
-    128 that divides it; the old halving from 512 gave 128); the SwiGLU
-    widths of command-a-plus (4096) and ax-k1 (2048) keep 512, and a width
-    no multiple of 128 divides keeps the halving."""
-    assert K._block_f(2688, 1024, 2, mats=2) == 384
-    assert K._block_f(2688, 1024, 2, mats=3) == 384
-    assert 2688 % 384 == 0
+    """Nemotron-3-Super's 2688 x 1024 relu^2 experts fit the VMEM budget
+    whole (2 x 2 x 2688 x 1024 x 2 B = 22 MB of the 48): one block of
+    2688, so a tile is one grid step (the old rule took blocks of 384, 7 a
+    tile); the SwiGLU widths of command-a-plus (4096 x 4096: 201 MB
+    whole) and ax-k1 (2048 x 7168: 176 MB) keep 512, and a width no
+    multiple of 128 divides keeps the halving."""
+    assert K._block_f(2688, 1024, 2, mats=2) == 2688
+    assert K._block_f(2688, 1024, 2, mats=3) == 2688
     assert K._block_f(4096, 4096, 2) == 512
     assert K._block_f(2048, 7168, 2) == 512
     assert K._block_f(32, 64, 4) == 32 and K._block_f(96, 64, 4) == 96
+    assert K._block_f(1000, 64, 4) == 8
+    assert K._block_f(1000, 64, 4, mats=2) == 8
     assert K.tiles(16, 1024, 2688, jnp.bfloat16, "relu2")
     assert K.tiles(256, 1024, 2688, jnp.bfloat16, "relu2")
+
+
+@pytest.mark.parametrize("f,d,act,held,top_k,n_tiles,blocks", [
+    (2688, 1024, "relu2", 128, 22, 172, 1),     # nemotron-3-super
+    (4096, 4096, "swiglu", 16, 8, 32, 8),       # command-a-plus
+    (2048, 7168, "swiglu", 12, 8, 28, 4)])      # ax-k1
+def test_the_grid_counters_read_the_blocks_a_tile_walks(
+        f, d, act, held, top_k, n_tiles, blocks):
+    """Tracing a decode step's call (32 slots, 16-row tiles: the static
+    grid of ``ops/moe.py``) bumps ``moe_ffn_grid_tiles`` by its tiles and
+    ``moe_ffn_grid_steps`` by its grid steps: their ratio is the blocks a
+    tile walks."""
+    from mxnet_tpu.observability import registry
+
+    assert n_tiles == -(-32 * top_k // 16) + held
+    tiles = registry.counter("moe_ffn_grid_tiles").value
+    steps = registry.counter("moe_ffn_grid_steps").value
+    sd = jax.ShapeDtypeStruct
+    w = sd((held, f, d), jnp.bfloat16)
+    out = jax.eval_shape(
+        lambda x, te, tv, wu, wd, *wg: K.moe_ffn(
+            x, te, tv, wg[0] if wg else None, wu, wd, 16, interpret=True,
+            act=act),
+        sd((n_tiles * 16, d), jnp.bfloat16), sd((n_tiles,), jnp.int32),
+        sd((n_tiles,), jnp.int32), w, w, *([w] if act == "swiglu" else []))
+    assert out.shape == (n_tiles * 16, d)
+    dt = registry.counter("moe_ffn_grid_tiles").value - tiles
+    ds = registry.counter("moe_ffn_grid_steps").value - steps
+    assert (dt, ds) == (n_tiles, n_tiles * blocks)

@@ -276,11 +276,13 @@ def test_moe_ffn_compiles_at_the_served_widths(one_chip, rows, tile):
 def test_moe_ffn_relu2_compiles_in_the_latent(one_chip, rows, tile):
     """The grouped FFN's relu^2 form at Nemotron-3-Super's widths: 128
     experts held of 2688 inner rows in a latent of 1024, two matrices an
-    expert in blocks of 384; a decode step's 16-row tiles and a prefill
-    chunk's 256-row tiles."""
+    expert, each taken whole as one block (22 MB double-buffered, under
+    the kernel's VMEM limit beside the tile's float32 inner activations);
+    a decode step's 16-row tiles and a prefill chunk's 256-row tiles."""
     from mxnet_tpu.ops.pallas import moe_ffn as K
 
     assert K.tiles(tile, 1024, 2688, jnp.bfloat16, "relu2")
+    assert K._block_f(2688, 1024, 2, mats=2) == 2688
     on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                    sharding=one_chip)
     w = on((128, 2688, 1024), jnp.bfloat16)
